@@ -206,9 +206,29 @@ def _join_config_channel(node, cfg, ch):
     return chan
 
 
+def _claim_device(who: str) -> None:
+    """A device-owning daemon says what it runs on before it serves,
+    and refuses a CPU backend it did not ask for."""
+    from fabric_tpu.utils import xla_env
+
+    dev = xla_env.claim_device(who)
+    print(f"{who} device: {dev['platform']} {dev['kind']} "
+          f"x{dev['count']}", flush=True)
+
+
 async def _run_peer(cfg):
     from fabric_tpu.discovery import PeerInfo
+    from fabric_tpu.utils import xla_env
 
+    # one device-owning process per chip (utils/xla_env.py): a peer
+    # with its own device lane claims the chip; a peer attached to a
+    # sidecar stays on the CPU backend so its MVCC jit and local
+    # fallback never contend with the sidecar for it
+    xla_env.enable_compile_cache()
+    if cfg.sidecar_endpoint:
+        xla_env.pin_cpu_backend()
+    else:
+        _claim_device(f"peer {cfg.id}")
     node = _build_peer(cfg)
     await node.start(operations_port=cfg.operations_port)
     print(f"peer {node.id} serving on :{node.port}", flush=True)
@@ -264,9 +284,10 @@ async def _run_sidecar(args):
     from fabric_tpu.sidecar.server import SidecarServer
     from fabric_tpu.sidecar.client import parse_endpoint
 
-    from fabric_tpu.utils.xla_env import enable_compile_cache
+    from fabric_tpu.utils import xla_env
 
-    enable_compile_cache()
+    xla_env.enable_compile_cache()
+    _claim_device("validation sidecar")
 
     if args.slos:
         from fabric_tpu.observe import slo as slo_mod

@@ -56,6 +56,12 @@ class BlockStore:
         # (ensure_synced — the durability fence); uncontended cost is
         # one futex op per block
         self._io_lock = threading.Lock()
+        # serializes the index connection between the committer, the
+        # validator's dup-txid lookups and the gateway's status reads:
+        # two threads running the SAME statement text on one sqlite3
+        # connection share its cached prepared statement, and a bind
+        # racing a step fails with "bad parameter or other API misuse"
+        self._idx_lock = threading.Lock()
         os.makedirs(dirpath, exist_ok=True)
         self._idx = sqlite3.connect(
             os.path.join(dirpath, "index.db"), check_same_thread=False
@@ -223,14 +229,17 @@ class BlockStore:
             )
         ctr.add(1, trigger=trigger)
 
+    def _idx_row(self, sql: str, args: tuple = ()):
+        """One index row (or None) under the connection lock."""
+        with self._idx_lock:
+            return self._idx.execute(sql, args).fetchone()
+
     @property
     def height(self) -> int:
-        row = self._idx.execute("SELECT MAX(num) FROM blocks").fetchone()
+        row = self._idx_row("SELECT MAX(num) FROM blocks")
         if row[0] is not None:
             return row[0] + 1
-        boot = self._idx.execute(
-            "SELECT first_block FROM bootstrap WHERE id=0"
-        ).fetchone()
+        boot = self._idx_row("SELECT first_block FROM bootstrap WHERE id=0")
         return boot[0] if boot else 0
 
     def bootstrap_from_snapshot(self, first_block: int, prev_hash: bytes,
@@ -255,9 +264,9 @@ class BlockStore:
 
     def bootstrap_info(self):
         """→ (first_block, prev_hash, commit_hash) or None."""
-        boot = self._idx.execute(
+        boot = self._idx_row(
             "SELECT first_block, prev_hash, commit_hash FROM bootstrap WHERE id=0"
-        ).fetchone()
+        )
         return tuple(boot) if boot else None
 
     def iter_txids(self):
@@ -280,11 +289,11 @@ class BlockStore:
         cached = getattr(self, "_last_hash", None)
         if cached is not None:
             return cached
-        row = self._idx.execute("SELECT MAX(num) FROM blocks").fetchone()
+        row = self._idx_row("SELECT MAX(num) FROM blocks")
         if row[0] is not None:
-            self._last_hash = self._idx.execute(
+            self._last_hash = self._idx_row(
                 "SELECT hash FROM blocks WHERE num=?", (row[0],)
-            ).fetchone()[0]
+            )[0]
             return self._last_hash
         boot = self.bootstrap_info()
         return boot[1] if boot else None
@@ -343,8 +352,9 @@ class BlockStore:
                     "group" if self._unsynced >= self.group_commit
                     else "lag"
                 )
-        self._index_block(block, self._seg, off, txids=txids)
-        self._idx.commit()
+        with self._idx_lock:
+            self._index_block(block, self._seg, off, txids=txids)
+            self._idx.commit()
         self._last_hash = protoutil.block_header_hash(block.header)
 
     def _read_at(self, seg: int, off: int) -> common_pb2.Block | None:
@@ -359,23 +369,23 @@ class BlockStore:
             return None
 
     def get_block(self, number: int) -> common_pb2.Block | None:
-        row = self._idx.execute(
+        row = self._idx_row(
             "SELECT seg, off FROM blocks WHERE num=?", (number,)
-        ).fetchone()
+        )
         return self._read_at(*row) if row else None
 
     def get_block_by_hash(self, h: bytes) -> common_pb2.Block | None:
-        row = self._idx.execute(
+        row = self._idx_row(
             "SELECT seg, off FROM blocks WHERE hash=?", (h,)
-        ).fetchone()
+        )
         return self._read_at(*row) if row else None
 
     def get_tx_loc(self, txid: str):
         """→ (block_num, tx_num, validation_code) or None (dup-txid
         check + qscc GetTransactionByID)."""
-        row = self._idx.execute(
+        row = self._idx_row(
             "SELECT num, txnum, code FROM txids WHERE txid=?", (txid,)
-        ).fetchone()
+        )
         return tuple(row) if row else None
 
     def tx_exists(self, txid: str) -> bool:

@@ -102,13 +102,9 @@ def ecprep_lib():
     """→ ctypes CDLL with ec_prepare (batch u1/u2 window recoding +
     admission flags) and ec_prepare_pack (strided int16 digits/limbs
     straight into the packed launch frame), or None (Python
-    fallback).  ``ec_prepare_pack`` may be absent from a stale cached
-    .so — callers hasattr-guard it and fall back to ec_prepare."""
+    fallback)."""
     lib = _load("ecprep")
     if lib is not None:
         lib.ec_prepare.restype = None
-        try:
-            lib.ec_prepare_pack.restype = None
-        except AttributeError:  # stale artifact predating the symbol
-            pass
+        lib.ec_prepare_pack.restype = None
     return lib

@@ -419,10 +419,18 @@ class GatewayClient:
         ))
         if not wait:
             return tx_id, None
+        return tx_id, await self.commit_status(channel, tx_id)
+
+    async def commit_status(self, channel: str, tx_id: str,
+                            timeout: float = 120.0) -> dict:
+        """The tx's commit status once it is in a block (408 after
+        ``timeout`` seconds); ``applied`` says whether its writes are
+        readable yet."""
+        cli = await self._client()
         raw = self._unwrap(await cli.unary(
             "GwCommitStatus",
             json.dumps({"channel": channel, "tx_id": tx_id,
-                        "timeout": 120.0}).encode(),
-            timeout=130.0,
+                        "timeout": timeout}).encode(),
+            timeout=timeout + 10.0,
         ))
-        return tx_id, json.loads(raw)
+        return json.loads(raw)

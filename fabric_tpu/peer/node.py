@@ -1036,7 +1036,11 @@ class PeerChannel:
                     idle_probes += 1
             finally:
                 if not t.done():
+                    # wait the cancelled stream out: its handler closes
+                    # the CommitPipeline and the feeder thread, which a
+                    # loop torn down first would leave running
                     t.cancel()
+                    await asyncio.gather(t, return_exceptions=True)
 
         from fabric_tpu.ops_metrics import global_registry
         from fabric_tpu.utils.backoff import Backoff
@@ -1906,6 +1910,14 @@ class PeerNode:
 
             if global_autopilot() is self.autopilot_ctl:
                 set_global(None)
+        # deliver drivers first, awaited: each drops its in-flight
+        # pipeline window and stops its worker threads before the
+        # ledger under it closes
+        deliver = [ch._deliver_task for ch in self.channels.values()
+                   if ch._deliver_task is not None]
+        for t in deliver:
+            t.cancel()
+        await asyncio.gather(*deliver, return_exceptions=True)
         for ch in self.channels.values():
             ch.stop()
         if getattr(self, "gossip_service", None) is not None:

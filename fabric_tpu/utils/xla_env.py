@@ -1,24 +1,38 @@
-"""Process-level XLA environment knobs.
+"""Process-level XLA environment: flags, the compile cache, and the
+device a process owns.
 
-Import-light on purpose (no jax import): callers must apply these
-BEFORE jax initializes its backends (tests/conftest.py,
+The flag helpers are import-light on purpose (no jax import): callers
+must apply them BEFORE jax initializes its backends (tests/conftest.py,
 __graft_entry__.py).
+
+One device-owning process per chip: a TPU belongs to the first process
+that initializes the backend, so exactly one process per chip calls
+:func:`claim_device` (``chip_smoke.py``, the CLI's ``peer`` without a
+``sidecar_endpoint``, ``sidecar-serve``); every other peer on the host
+attaches through ``sidecar_endpoint`` and pins itself to the CPU
+backend with :func:`pin_cpu_backend`.
 """
 
 from __future__ import annotations
 
+import logging
 import os
 
+_log = logging.getLogger("fabric_tpu.xla_env")
 
-def _jaxlib_version() -> tuple:
-    try:
-        import jaxlib.version  # import-light: version module only
+#: fabric_tpu/utils/xla_env.py → the checkout root, normalised: the
+#: cache directory is part of every cache key, so each caller must
+#: spell it identically
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
-        return tuple(
-            int(p) for p in jaxlib.version.__version__.split(".")[:2]
-        )
-    except Exception:
-        return (0, 0)
+
+def _cpu_named_explicitly() -> bool:
+    """True when ``JAX_PLATFORMS`` asks for the CPU backend by name —
+    the tests and the CPU recipes do; a process that merely FOUND no
+    accelerator does not."""
+    first = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
+    return first.strip().lower() == "cpu"
 
 
 def ensure_cpu_compile_workaround() -> None:
@@ -26,16 +40,14 @@ def ensure_cpu_compile_workaround() -> None:
 
     They blow up superlinearly on the deep uint32 dependency chains of
     the crypto kernels (a 64-round SHA-256 compression never finishes
-    compiling on a 1-core host); the legacy emitter compiles it in ~2s.
-    Harmless for the TPU backend.
+    compiling on a small host); the legacy emitter compiles it in ~2s.
 
-    Version-gated: XLA ABORTS the whole process on an unknown flag at
-    backend init, and ``--xla_cpu_use_fusion_emitters`` does not exist
-    on the 0.4.x jaxlibs — setting it there turns every test run into
-    a collection-time SIGABRT.  Older jaxlibs still run the legacy
-    emitter by default, so skipping the flag loses nothing.
+    Applied only where ``JAX_PLATFORMS`` names ``cpu``: XLA ABORTS the
+    whole process on a flag it does not know at backend init, and a
+    process that opens the TPU hands ``XLA_FLAGS`` to the TPU runtime
+    as well — a CPU-compiler flag has no business there.
     """
-    if _jaxlib_version() < (0, 5):
+    if not _cpu_named_explicitly():
         return
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_cpu_use_fusion_emitters" not in flags:
@@ -54,33 +66,57 @@ def ensure_host_device_count(n: int) -> None:
         ).strip()
 
 
-def enable_compile_cache(root: str | None = None) -> bool:
-    """Point jax's persistent compile cache at the repo's shared
-    ``.jax_cache`` so every process that validates (bench rounds, the
-    sidecar server, CLI daemons) reuses one set of compiled verify
-    graphs — a sidecar restart must re-attach in seconds, not
-    re-compile for minutes while every tenant rides its CPU fallback.
-    Returns False (after logging) when jax is absent or the config
-    knobs are unavailable; the cache is an optimization, serving works
-    without it."""
-    if root is None:
-        root = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-    try:
-        import jax
+def enable_compile_cache() -> str:
+    """Arm jax's persistent compile cache so every process that
+    validates (bench rounds, the peer daemon, the sidecar server, the
+    tests) reuses one set of compiled verify graphs — a restart must
+    re-attach in seconds, not re-compile the ladder on its first block.
 
-        jax.config.update(
-            "jax_compilation_cache_dir", os.path.join(root, ".jax_cache")
-        )
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 2.0
-        )
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        return True
-    except Exception as e:
-        import logging
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, the operator placed the
+    cache: jax reads the variable itself and NO directory is set in
+    code.  Otherwise the cache lives at ``<checkout>/.jax_cache``.
+    Returns the directory in use."""
+    import jax
 
-        logging.getLogger("fabric_tpu.xla_env").warning(
-            "persistent compile cache unavailable (%s)", e
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            os.path.join(_CHECKOUT, ".jax_cache"),
         )
-        return False
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return jax.config.jax_compilation_cache_dir
+
+
+def pin_cpu_backend() -> None:
+    """Keep this process off the accelerator: a peer attached to a
+    validation sidecar still jits its MVCC kernel and its local
+    fallback, and on a chip host those must not contend with the
+    sidecar for the one chip.  Call before any backend initializes."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
+def claim_device(who: str) -> dict:
+    """Initialize the backend for a device-owning process and say what
+    it runs on: → ``{"platform", "kind", "count"}`` as jax reports
+    them.  Raises when the backend is ``cpu`` and ``JAX_PLATFORMS`` did
+    not name ``cpu`` — a process that found no accelerator must not
+    serve device metric names off the CPU backend."""
+    import jax
+
+    devs = jax.devices()
+    info = {
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "count": len(devs),
+    }
+    _log.info("%s: platform=%s device_kind=%s devices=%d", who,
+              info["platform"], info["kind"], info["count"])
+    if info["platform"] == "cpu" and not _cpu_named_explicitly():
+        raise RuntimeError(
+            f"{who}: jax found no accelerator and fell back to the cpu "
+            "backend; set JAX_PLATFORMS=cpu to run on the CPU on purpose"
+        )
+    return info

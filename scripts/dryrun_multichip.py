@@ -44,12 +44,10 @@ if _REPO not in sys.path:
 
 
 def run() -> dict:
+    import jax.numpy as jnp
     import numpy as np
 
     import __graft_entry__ as graft
-
-    graft._force_host_mesh_platform()
-    import jax.numpy as jnp
 
     from fabric_tpu.ledger.statedb import MemVersionedDB, UpdateBatch
     from fabric_tpu.parallel import mesh as pmesh

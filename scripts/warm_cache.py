@@ -8,7 +8,8 @@ closes the loop: feed it a ledger report (the ``/launches`` operations
 endpoint, or a ``BENCH_*.json`` line's ``extras.device_ledger``), and
 it re-dispatches every compile-missed verify/sign shape with dummy
 lanes AFTER arming the repo's persistent compile cache
-(utils/xla_env.enable_compile_cache → ``.jax_cache``), so the next
+(utils/xla_env.enable_compile_cache: ``JAX_COMPILATION_CACHE_DIR``
+where set, else ``<checkout>/.jax_cache``), so the next
 process to hit those shapes loads the compiled program from disk
 instead of tracing it on the serving path.
 
@@ -125,10 +126,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     # arm the persistent cache BEFORE any kernel builds — this is the
-    # entire point: the warm dispatches below populate .jax_cache
+    # entire point: the warm dispatches below populate it
     from fabric_tpu.utils.xla_env import enable_compile_cache
 
-    armed = enable_compile_cache()
+    cache_dir = enable_compile_cache()
     shapes, skipped = miss_shapes(load_report(args.report))
 
     warmed, failed = [], []
@@ -144,7 +145,7 @@ def main(argv=None) -> int:
                 failed.append({"kernel": kernel, "lanes": lanes,
                                "error": str(e)})
     print(json.dumps({
-        "cache_armed": armed,
+        "cache_dir": cache_dir,
         "warmed": warmed,
         "skipped": skipped,
         "failed": failed,

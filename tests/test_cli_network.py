@@ -30,7 +30,6 @@ def _free_port():
 def _cli_env():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(REPO, ".jax_cache")
     env["PYTHONPATH"] = REPO
     return env
 

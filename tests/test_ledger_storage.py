@@ -94,6 +94,52 @@ def test_blockstore_append_get_and_txids(tmp_path):
     bs.close()
 
 
+def test_blockstore_index_reads_survive_concurrent_threads(tmp_path):
+    """The validator's dup-txid lookups, the gateway's status reads and
+    the committer share one index connection; threads running the same
+    statement used to fail with sqlite3.InterfaceError."""
+    import sys
+    import threading
+    import time
+
+    bs = BlockStore(str(tmp_path / "chains"))
+    errors, stop = [], threading.Event()
+
+    def reader():
+        i = 0
+        while not stop.is_set():
+            try:
+                bs.get_tx_loc(f"tx{i % 64}-0")
+                bs.height
+            except Exception as e:  # the invariant under test
+                errors.append(repr(e))
+                return
+            i += 1
+
+    threads = [threading.Thread(target=reader)
+               for _ in range(2 * (os.cpu_count() or 4))]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        prev, n, deadline = b"", 0, time.monotonic() + 1.5
+        while time.monotonic() < deadline and not errors:
+            blk = _block(n, prev, [b"a"])
+            bs.add_block(blk)
+            prev = pu.block_header_hash(blk.header)
+            n += 1
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(10)
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert errors == []
+    assert bs.height == n and n > 0
+    bs.close()
+
+
 def test_blockstore_reopen_and_torn_write_recovery(tmp_path):
     path = str(tmp_path / "chains")
     bs = BlockStore(path)
