@@ -1,0 +1,8 @@
+"""``apply_write_ms`` in the paced cell, where it should move the tx latency."""
+
+from benchmark import manifest
+
+_base = manifest.load_module("layer_metrics", "apply_write_ms")
+LAYER, UNIT, SOURCE = _base.LAYER, _base.UNIT, _base.SOURCE
+MOVES = "tx_commit_p50_ms"
+read = _base.read

@@ -16,11 +16,13 @@ import os
 import sqlite3
 import struct
 import threading
+import time
 
 from google.protobuf.message import DecodeError
 
 from fabric_tpu import faults as _faults
 from fabric_tpu import protoutil
+from fabric_tpu.observe.tracer import global_tracer
 from fabric_tpu.protos import common_pb2
 
 _SEGMENT_MAX = 64 * 1024 * 1024
@@ -62,6 +64,14 @@ class BlockStore:
         # connection share its cached prepared statement, and a bind
         # racing a step fails with "bad parameter or other API misuse"
         self._idx_lock = threading.Lock()
+        # seconds spent WAITING for _idx_lock, by the side that waited:
+        # readers (_idx_row: the dup-txid lookups, height, status
+        # reads) and the writer (add_block's index insert + commit).
+        # Only a contended acquire reads the clock; each float is
+        # updated while holding the lock.  The ``dup_txid`` and
+        # ``commit.index`` spans carry the delta over their extent.
+        self.idx_wait_reader_s = 0.0
+        self.idx_wait_writer_s = 0.0
         os.makedirs(dirpath, exist_ok=True)
         self._idx = sqlite3.connect(
             os.path.join(dirpath, "index.db"), check_same_thread=False
@@ -231,8 +241,15 @@ class BlockStore:
 
     def _idx_row(self, sql: str, args: tuple = ()):
         """One index row (or None) under the connection lock."""
-        with self._idx_lock:
+        lock = self._idx_lock
+        if not lock.acquire(False):
+            t0 = time.perf_counter()
+            lock.acquire()
+            self.idx_wait_reader_s += time.perf_counter() - t0
+        try:
             return self._idx.execute(sql, args).fetchone()
+        finally:
+            lock.release()
 
     @property
     def height(self) -> int:
@@ -318,8 +335,6 @@ class BlockStore:
             data = protoutil.append_block_metadata(hd_bytes, block)
         else:
             data = block.SerializeToString()
-        import time as _time
-
         with self._io_lock:
             if (self._fh.tell() + len(data) > _SEGMENT_MAX
                     and self._fh.tell() > 0):
@@ -337,10 +352,10 @@ class BlockStore:
             # (see __init__ for the replay-safety argument)
             self._unsynced += 1
             if self._oldest_unsynced is None:
-                self._oldest_unsynced = _time.monotonic()
+                self._oldest_unsynced = time.monotonic()
             if (
                 self._unsynced >= self.group_commit
-                or _time.monotonic() - self._oldest_unsynced
+                or time.monotonic() - self._oldest_unsynced
                 >= self.group_max_lag_s
             ):
                 # crash-consistency hooks: the kill-mid-fsync chaos
@@ -352,9 +367,24 @@ class BlockStore:
                     "group" if self._unsynced >= self.group_commit
                     else "lag"
                 )
-        with self._idx_lock:
-            self._index_block(block, self._seg, off, txids=txids)
-            self._idx.commit()
+        tracer = global_tracer()
+        # a span only under a traced commit (no-op without a current span)
+        with tracer.span("commit.index") as isp:
+            waited0, lock = self.idx_wait_writer_s, self._idx_lock
+            if not lock.acquire(False):
+                t0 = time.perf_counter()
+                lock.acquire()
+                self.idx_wait_writer_s += time.perf_counter() - t0
+            try:
+                self._index_block(block, self._seg, off, txids=txids)
+                self._idx.commit()
+            finally:
+                lock.release()
+            if isp is not None:
+                isp.attrs.update(
+                    idx_wait_ms=(self.idx_wait_writer_s - waited0) * 1000.0,
+                    txids=len(block.data.data if txids is None else txids),
+                )
         self._last_hash = protoutil.block_header_hash(block.header)
 
     def _read_at(self, seg: int, off: int) -> common_pb2.Block | None:
@@ -404,10 +434,14 @@ class BlockStore:
         # caller holds self._io_lock
         if self._unsynced:
             self._count_fsync(trigger)
-            self._fh.flush()
-            _faults.fire("ledger.fsync.before")
-            os.fsync(self._fh.fileno())
-            _faults.fire("ledger.fsync.after")
+            # under whichever span the syncing thread is in: the
+            # committer's ``commit``, the applier's ``apply.fence``
+            with global_tracer().span("fsync", trigger=trigger,
+                                      blocks=self._unsynced):
+                self._fh.flush()
+                _faults.fire("ledger.fsync.before")
+                os.fsync(self._fh.fileno())
+                _faults.fire("ledger.fsync.after")
             self._unsynced = 0
             self._oldest_unsynced = None
         self._synced_num = self._last_appended

@@ -53,6 +53,7 @@ from collections import deque
 from fabric_tpu import faults as _faults
 from fabric_tpu.ledger.statedb import VersionedDB
 from fabric_tpu.observe import txflow as _txflow
+from fabric_tpu.observe.tracer import global_tracer
 
 _log = logging.getLogger("fabric_tpu.ledger.committer")
 
@@ -60,7 +61,8 @@ _log = logging.getLogger("fabric_tpu.ledger.committer")
 class _Pending:
     """One queued block apply."""
 
-    __slots__ = ("num", "batch", "sp", "post_apply", "enqueued_at")
+    __slots__ = ("num", "batch", "sp", "post_apply", "enqueued_at",
+                 "root", "traced_at")
 
     def __init__(self, num, batch, sp, post_apply, enqueued_at):
         self.num = num
@@ -68,6 +70,23 @@ class _Pending:
         self.sp = sp
         self.post_apply = post_apply
         self.enqueued_at = enqueued_at
+        # the tracer root of the block whose commit enqueued this (None
+        # when disarmed or enqueued outside a traced commit), and the
+        # enqueue time on the tracer's clock: the applier records its
+        # ``apply`` span under that root
+        self.root = None
+        self.traced_at = 0.0
+
+
+def _n_writes(batch) -> int:
+    """Rows ``batch`` writes.  A columnar batch is counted by its slab
+    rows: ``len(batch.updates)`` would build its lazy dict, which the
+    sqlite fast path never needs (6 ms a 1000-tx block, on the applier
+    thread: ``rw_paced`` showed it with the tracer disarmed, PR 25)."""
+    rows = getattr(batch, "row_uid", None)
+    if rows is None:
+        return len(batch.updates)
+    return len(rows) + len(batch._extra)
 
 
 def _merge_overlay(inner_iter, ov: dict):
@@ -122,6 +141,9 @@ class AsyncApplyEngine(VersionedDB):
         self._applies_total = 0
         self._apply_s_total = 0.0
         self._backpressure_total = 0
+        # seconds submit spent parked at a full queue, all calls: the
+        # ledger's ``commit.enqueue`` span carries the delta
+        self.backpressure_s = 0.0
         self._metrics = None  # lazy (gauge, hist, counter) bundle
         # mirrored so KVLedger's getattr(state, "durable") keeps working
         self.durable = getattr(inner, "durable", True)
@@ -134,18 +156,26 @@ class AsyncApplyEngine(VersionedDB):
         (the backpressure latch).  ``post_apply`` (optional, no-arg)
         runs on the applier thread after the batch lands — the
         history-DB commit rides here."""
+        # on the committer thread, inside the pipeline's ``commit``
+        # span: its root is the block's
         entry = _Pending(num, batch, savepoint, post_apply,
                          time.monotonic())
+        tracer = global_tracer()
+        cur = tracer.current()
+        if cur is not None:
+            entry.root, entry.traced_at = cur.root, tracer.clock()
         with self._cond:
             self._raise_if_failed()
             waited = False
             while (len(self._queue) >= self._capacity
                    and self._error is None and not self._closing):
-                waited = True
+                if not waited:
+                    waited, t_parked = True, time.perf_counter()
                 self._cond.wait()
             self._raise_if_failed()
             if waited:
                 self._backpressure_total += 1
+                self.backpressure_s += time.perf_counter() - t_parked
             self._queue.append(entry)
             if self._thread is None:
                 t = threading.Thread(target=self._apply_loop,
@@ -198,22 +228,34 @@ class AsyncApplyEngine(VersionedDB):
             self._observe(dur)
 
     def _apply_one(self, entry: _Pending) -> float:
-        _faults.fire("ledger.apply.before", block=entry.num)
-        if self._blocks is not None and getattr(self._inner, "durable",
-                                                True):
-            # a DURABLE savepoint must never get ahead of the block
-            # files (see module docstring) — fence before the apply
-            self._blocks.ensure_synced(entry.num)
-            _txflow.block_durable(entry.num)
-        t0 = time.perf_counter()
-        self._inner.apply_updates(entry.batch, entry.sp)
-        if entry.post_apply is not None:
-            entry.post_apply()
-        dur = time.perf_counter() - t0
-        # the decoupled path's visibility edge: the block's writes
-        # (and history) became readable HERE, on the applier thread
-        _txflow.block_applied(entry.num)
-        _faults.fire("ledger.apply.after", block=entry.num)
+        # the block's tree was finished when its commit returned: these
+        # spans arrive late (Tracer.finish_block states the contract).
+        # With no root every span below is a no-op on a None parent.
+        tracer = global_tracer()
+        with tracer.span("apply", parent=entry.root) as asp:
+            if asp is not None:
+                asp.attrs["queued_ms"] = (asp.t0 - entry.traced_at) * 1000.0
+            _faults.fire("ledger.apply.before", block=entry.num)
+            if self._blocks is not None and getattr(self._inner, "durable",
+                                                    True):
+                # a DURABLE savepoint must never get ahead of the block
+                # files (see module docstring) — fence before the apply
+                with tracer.span("apply.fence"):
+                    self._blocks.ensure_synced(entry.num)
+                _txflow.block_durable(entry.num)
+            t0 = time.perf_counter()
+            with tracer.span("apply.write") as wsp:
+                self._inner.apply_updates(entry.batch, entry.sp)
+            if wsp is not None:
+                wsp.attrs["writes"] = _n_writes(entry.batch)
+            if entry.post_apply is not None:
+                with tracer.span("apply.history"):
+                    entry.post_apply()
+            dur = time.perf_counter() - t0
+            # the decoupled path's visibility edge: the block's writes
+            # (and history) became readable HERE, on the applier thread
+            _txflow.block_applied(entry.num)
+            _faults.fire("ledger.apply.after", block=entry.num)
         return dur
 
     # -- read side: pending overlay in front of the inner DB ---------------
@@ -229,39 +271,54 @@ class AsyncApplyEngine(VersionedDB):
                 return None if vv.value is None else vv
         return self._inner.get_state(ns, key)
 
+    # The two version gathers record ``sf.gather`` (the inner DB's
+    # query) and ``sf.pending`` (the overlay walk) under the calling
+    # thread's current span, the validator's ``launch``: two spans a
+    # call, nothing per key, no-ops off a traced path.
+
     def get_versions_bulk(self, keys):
-        pend = self._pending()
-        if not pend:
-            return self._inner.get_versions_bulk(keys)
+        tracer = global_tracer()
         out, rest = {}, []
-        for k in keys:
-            for entry in reversed(pend):
-                vv = entry.batch.updates.get(k)
-                if vv is not None:
-                    if vv.value is not None:
-                        out[k] = vv.version
-                    break
-            else:
-                rest.append(k)
+        with tracer.span("sf.pending") as psp:
+            pend = self._pending()
+            tracer.set_attrs(psp, pending=len(pend))
+            if pend:
+                for k in keys:
+                    for entry in reversed(pend):
+                        vv = entry.batch.updates.get(k)
+                        if vv is not None:
+                            if vv.value is not None:
+                                out[k] = vv.version
+                            break
+                    else:
+                        rest.append(k)
+        if not pend:
+            with tracer.span("sf.gather", keys=len(keys)):
+                return self._inner.get_versions_bulk(keys)
         if rest:
-            out.update(self._inner.get_versions_bulk(rest))
+            with tracer.span("sf.gather", keys=len(rest)):
+                out.update(self._inner.get_versions_bulk(rest))
         return out
 
     def get_versions_cols(self, keys):
-        present, vers = self._inner.get_versions_cols(keys)
-        pend = self._pending()
-        if pend:
-            for i, k in enumerate(keys):
-                for entry in reversed(pend):
-                    vv = entry.batch.updates.get(k)
-                    if vv is not None:
-                        if vv.value is None:
-                            present[i] = False
-                            vers[i] = 0
-                        else:
-                            present[i] = True
-                            vers[i] = vv.version
-                        break
+        tracer = global_tracer()
+        with tracer.span("sf.gather", keys=len(keys)):
+            present, vers = self._inner.get_versions_cols(keys)
+        with tracer.span("sf.pending") as psp:
+            pend = self._pending()
+            tracer.set_attrs(psp, pending=len(pend))
+            if pend:
+                for i, k in enumerate(keys):
+                    for entry in reversed(pend):
+                        vv = entry.batch.updates.get(k)
+                        if vv is not None:
+                            if vv.value is None:
+                                present[i] = False
+                                vers[i] = 0
+                            else:
+                                present[i] = True
+                                vers[i] = vv.version
+                            break
         return present, vers
 
     def _overlay_for(self, ns, pend, keep):

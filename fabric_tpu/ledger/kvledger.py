@@ -33,6 +33,7 @@ from fabric_tpu.ledger.history import HistoryDB
 from fabric_tpu.ledger.pvtdata import PvtDataStore
 from fabric_tpu.ledger.statedb import SqliteVersionedDB, UpdateBatch, VersionedDB
 from fabric_tpu.observe import txflow as _txflow
+from fabric_tpu.observe.tracer import global_tracer
 from fabric_tpu.protos import common_pb2
 
 _log = logging.getLogger("fabric_tpu.ledger")
@@ -155,6 +156,8 @@ class KVLedger:
         if pvt_data:
             self.pvtdata.commit_block(num, pvt_data)
         t1 = _time.perf_counter()
+        # seconds parked at a full apply queue so far (0.0 with no engine)
+        parked0 = getattr(self.engine, "backpressure_s", 0.0)
         if self.engine is not None:
             # decoupled committer: the block is committed (appended);
             # state apply trails on the applier thread, which also
@@ -193,6 +196,12 @@ class KVLedger:
             "ledger_append": t1 - t0,
             "state_apply": t2 - t1,
         }
+        # the same three clock reads as spans, under the committing
+        # thread's ``commit`` (no-ops off a traced commit)
+        tracer = global_tracer()
+        tracer.add("commit.append", t0, t1)
+        tracer.add("commit.enqueue", t1, t2, backpressure_ms=(
+            getattr(self.engine, "backpressure_s", 0.0) - parked0) * 1000.0)
         hists = self._commit_hists
         if hists is None:
             from fabric_tpu.ops_metrics import global_registry

@@ -42,7 +42,7 @@ from __future__ import annotations
 #: worker tasks, verify_chunk staging — counts toward coverage.
 NON_HOST = {
     "block", "finish", "device_wait", "commit_wait", "prefetch_wait",
-    "queue_wait",
+    "queue_wait", "feed_wait",
 }
 
 #: default neighbor window (blocks either side): ±2 matches depth-3

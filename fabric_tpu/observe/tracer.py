@@ -314,6 +314,21 @@ class Tracer:
     # -- finalize: ring + watchdog (the one lock per block) ----------------
 
     def finish_block(self, root) -> None:
+        """Close the block's root (``t1``), file the tree in its ring,
+        run the slow-block watchdog and the listeners.
+
+        Finished is not frozen: spans may still ARRIVE under a finished
+        root.  The async state applier records ``apply`` (with
+        ``apply.fence`` / ``apply.write`` / ``apply.history``) under
+        the root of the block whose commit enqueued it, after that
+        commit returned and this method ran.  Every reader tolerates
+        late children: listeners and ``recent_roots`` hold the live
+        ``Span`` objects, ``/trace`` and the Chrome export serialize at
+        request time, and a child append is GIL-atomic.  A reader that
+        wants the apply reads the tree once the block is applied.  The
+        root's ``t1`` (the watchdog's duration) stays where the commit
+        left it: a slow apply is not a slow block.  Likewise a root's
+        ``feed_wait`` child ends where the root begins."""
         if root is None:
             return
         if root.t1 is None:

@@ -321,6 +321,10 @@ class CommitPipeline:
         # vector.  GIL-atomic attribute writes; no lock needed.
         self._pending_depth: int | None = None
         self._pending_coalesce: int | None = None
+        # when the last submit*/flush handed control back to the feeder
+        # (tracer armed only): the next block's ``feed_wait`` starts here
+        self._fed_at: float | None = None
+        self._fed_by_flush = False
         self._closed = False
 
     # -- runtime re-knobbing (autopilot actuators) -------------------------
@@ -402,6 +406,7 @@ class CommitPipeline:
         # superseded by the failure that got us here
         self._commits.clear()
         self._stale_prefetch = False
+        self._fed_at = None
         self._prefetch.shutdown(wait=True)
         self._committer.shutdown(wait=True)
         self._inflight_gauge.set(0, channel=self.channel)
@@ -473,6 +478,33 @@ class CommitPipeline:
             set().union(*(set(r.txids) for r in recs)),
         )
 
+    # -- feed_wait: the pipeline idle, waiting for its feeder ----------------
+
+    def _mark_fed(self, by_flush: bool = False) -> None:
+        """Last act of submit*/flush: from here until the next call the
+        pipeline waits for its feeder.  One clock read per call, and
+        only while the tracer is armed."""
+        self._fed_at = (time.perf_counter() if self.tracer.enabled
+                        else None)
+        self._fed_by_flush = by_flush
+
+    def _add_feed_wait(self, root, t_in: float, **attrs) -> None:
+        """``feed_wait``: the caller thread was outside the pipeline,
+        waiting for the deliver stream or the generator, from the last
+        submit*/flush return (:meth:`_mark_fed`) to this call's entry
+        ``t_in``.  A retro span: on the NEW block's root at a submit,
+        where it ends as the root begins (``after_flush`` when the
+        call before was a flush, so the wait starts at that flush's
+        return and holds none of its work); on the block in hand at a
+        flush (``before_flush``: the idle timeout that led to it).
+        The first block of a pipeline, and the first after a failure,
+        have none."""
+        t_fed, self._fed_at = self._fed_at, None
+        if t_fed is not None:
+            if self._fed_by_flush:
+                attrs["after_flush"] = True
+            self.tracer.add("feed_wait", t_fed, t_in, parent=root, **attrs)
+
     # -- the pipeline ------------------------------------------------------
 
     def submit(self, block):
@@ -490,9 +522,11 @@ class CommitPipeline:
             raise RuntimeError("pipeline is closed")
         self._apply_pending_knobs()
         try:
-            if self.depth == 1:
-                return self._submit_serial(block)
             t_sub = time.perf_counter()
+            if self.depth == 1:
+                out = self._submit_serial(block, t_sub)
+                self._mark_fed()
+                return out
             # stage the new block on the prefetch thread FIRST: its
             # host parse + device verify launch overlap the
             # predecessor's device sync below
@@ -501,6 +535,7 @@ class CommitPipeline:
             )
             root = self.tracer.begin_block(block.header.number,
                                            channel=self.channel)
+            self._add_feed_wait(root, t_sub)
             self._pre = (
                 block,
                 self._prefetch.submit(self._prefetch_traced, block, root),
@@ -514,6 +549,7 @@ class CommitPipeline:
             self._launch_next(
                 out.stage_s if out is not None else {}, t_sub
             )
+            self._mark_fed()
             return out
         except BaseException:
             self._fail_closed()
@@ -596,12 +632,15 @@ class CommitPipeline:
         if self._closed:
             raise RuntimeError("pipeline is closed")
         try:
-            return self._submit_many_coalesced(blocks, k)
+            out = self._submit_many_coalesced(blocks, k,
+                                              time.perf_counter())
+            self._mark_fed()
+            return out
         except BaseException:
             self._fail_closed()
             raise
 
-    def _submit_many_coalesced(self, blocks, k) -> list:
+    def _submit_many_coalesced(self, blocks, k, t_in: float) -> list:
         out = []
         i = 0
         while i < len(blocks):
@@ -627,6 +666,9 @@ class CommitPipeline:
                 self.tracer.set_attrs(r, coalesce_group=int(lead),
                                       coalesce_size=len(group))
                 roots.append(r)
+            # the call's one wait lands on its first block's root:
+            # taking it clears the mark, so later groups add nothing
+            self._add_feed_wait(roots[0], t_in)
             fut = self._prefetch.submit(
                 self._prefetch_many_traced, group, roots[0], len(group)
             )
@@ -668,7 +710,15 @@ class CommitPipeline:
         or commit exception fails the pipe closed and surfaces ONCE
         (see ``submit``)."""
         try:
-            return self._flush_inner()
+            # a flush of nothing leaves the mark where it was
+            busy = self._launched is not None
+            if busy and self._fed_at is not None:
+                self._add_feed_wait(self._launched_root,
+                                    time.perf_counter(), before_flush=True)
+            out = self._flush_inner()
+            if busy:
+                self._mark_fed(by_flush=True)
+            return out
         except BaseException:
             self._fail_closed()
             raise
@@ -726,10 +776,11 @@ class CommitPipeline:
         self._inflight_gauge.set(0, channel=self.channel)
         return out
 
-    def _submit_serial(self, block) -> CommittedBlock:
+    def _submit_serial(self, block, t_sub: float) -> CommittedBlock:
         tr = self.tracer
         root = tr.begin_block(block.header.number, channel=self.channel,
                               mode="serial")
+        self._add_feed_wait(root, t_sub)
         t0 = time.perf_counter()
         stage = "launch"  # failure label tracks the stage under way
         try:

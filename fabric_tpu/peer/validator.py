@@ -1408,15 +1408,36 @@ class BlockValidator:
         # (deferred from preprocess).  fb.codes is kept in sync — the
         # vectorized state_fill reads it as the live verdict array.
         if self.blocks is not None or extra_txids:
-            for ptx in txs:
-                if ptx.undetermined and not ptx.is_config and (
-                    (extra_txids is not None and ptx.txid in extra_txids)
-                    or (self.blocks is not None
-                        and self.blocks.tx_exists(ptx.txid))
-                ):
-                    ptx.code = C.DUPLICATE_TXID
-                    if fb is not None:
-                        fb.codes[ptx.idx] = int(C.DUPLICATE_TXID)
+            # ``dup_txid``: a child of the pipeline's ``launch`` (no-op
+            # off a traced launch).  What it carries is counted outside
+            # the loop, so a lookup costs what it did: ``lookups`` is
+            # the txs checked (each calls tx_exists unless an in-flight
+            # predecessor's txid set already answered), ``idx_wait_ms``
+            # what readers of the block store's index waited for its
+            # lock meanwhile
+            with self._tracer.span("dup_txid") as dsp:
+                if dsp is not None:
+                    lookups = sum(1 for ptx in txs if ptx.undetermined
+                                  and not ptx.is_config)
+                    waited0 = getattr(self.blocks, "idx_wait_reader_s", 0.0)
+                hits = 0
+                for ptx in txs:
+                    if ptx.undetermined and not ptx.is_config and (
+                        (extra_txids is not None
+                         and ptx.txid in extra_txids)
+                        or (self.blocks is not None
+                            and self.blocks.tx_exists(ptx.txid))
+                    ):
+                        ptx.code = C.DUPLICATE_TXID
+                        hits += 1
+                        if fb is not None:
+                            fb.codes[ptx.idx] = int(C.DUPLICATE_TXID)
+                if dsp is not None:
+                    dsp.attrs.update(
+                        lookups=lookups, hits=hits,
+                        idx_wait_ms=(getattr(
+                            self.blocks, "idx_wait_reader_s", 0.0)
+                            - waited0) * 1000.0)
 
         pending = PendingBlock(
             block=block, txs=txs, items=items, fetch=fetch, dpre=dpre,

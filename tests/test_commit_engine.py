@@ -423,3 +423,192 @@ def test_pipeline_depth3_differential_async_vs_serial(tmp_path):
     assert any(
         c != 0 for _n, flt in fa for c in flt
     ) and any(c == 0 for _n, flt in fa for c in flt)
+
+
+# ---------------------------------------------------------------------------
+# 5. the applier on the block's span tree
+
+
+class _GatedSqlite(SqliteVersionedDB):
+    """The durable backend with its applies parked on a gate: every
+    block's tree is finished before its apply can end."""
+
+    def __init__(self, path):
+        super().__init__(path)
+        self.gate = threading.Event()
+
+    def apply_updates(self, batch, savepoint):
+        assert self.gate.wait(30.0), "apply gate never opened"
+        super().apply_updates(batch, savepoint)
+
+
+def _traced_pipeline_run(tmp_path, ring_blocks, n_blocks=4):
+    """``n_blocks`` toy blocks through a depth-2 pipeline over a sqlite
+    ledger with the async engine, the process tracer at ``ring_blocks``.
+    → (roots a listener collected, what the gate saw before it opened:
+    the queued entries' roots and the ``apply`` spans that had ended,
+    each block's write count)."""
+    from test_commit_pipeline import ToyValidator, _stream
+
+    from fabric_tpu import observe
+    from fabric_tpu.peer.pipeline import CommitPipeline
+
+    tracer = observe.global_tracer()
+    was, roots, writes = tracer.ring_blocks, [], {}
+    observe.configure(ring_blocks=ring_blocks)
+    tracer.add_listener(roots.append)
+    inner = _GatedSqlite(str(tmp_path / "state.db"))
+    lg = KVLedger(str(tmp_path / "lg"), state_db=inner, async_commit=True,
+                  apply_queue_blocks=2 * n_blocks)
+    try:
+        def commit_fn(res):
+            writes[res.block.header.number] = len(res.batch.updates)
+            lg.commit_block(res.block, res.tx_filter, res.batch,
+                            res.history, None, res.txids)
+
+        with CommitPipeline(ToyValidator(lg.state), commit_fn,
+                            depth=2) as pipe:
+            for b in _stream(n_blocks, 4):
+                pipe.submit(b)
+            pipe.flush()
+            # every commit has returned and every tree is finished;
+            # no apply can have ended
+            parked = {
+                "entry_roots": [e.root for e in lg.engine._pending()],
+                "ended": [sp.name for r in roots for sp in r.children
+                          if sp.name == "apply" and sp.t1 is not None],
+                "finished": [r.t1 is not None for r in roots],
+            }
+            inner.gate.set()
+            lg.drain_state()
+    finally:
+        inner.gate.set()
+        lg.close()
+        tracer.remove_listener(roots.append)
+        observe.configure(ring_blocks=was)
+    return roots, parked, writes
+
+
+def test_applier_spans_arrive_under_the_finished_block(tmp_path):
+    roots, parked, writes = _traced_pipeline_run(tmp_path, 16)
+    assert [r.attrs["block"] for r in roots] == [0, 1, 2, 3]
+    # attached after finish_block: the trees were finished, their
+    # entries still queued under their own roots, and no apply done
+    assert parked["finished"] == [True] * 4 and parked["ended"] == []
+    assert parked["entry_roots"] == roots
+    for r in roots:
+        applies = [c for c in r.children if c.name == "apply"]
+        assert len(applies) == 1, [c.name for c in r.children]
+        ap = applies[0]
+        assert ap.thread == "fabtpu-state-applier" and ap.root is r
+        assert ap.t1 is not None and ap.t1 > r.t1  # late, and t1 kept
+        assert ap.attrs["queued_ms"] >= 0.0
+        kids = {c.name: c for c in ap.children}
+        assert list(kids) == ["apply.fence", "apply.write"]
+        for c in kids.values():
+            assert c.thread == ap.thread
+            assert ap.t0 <= c.t0 <= c.t1 <= ap.t1
+        assert kids["apply.write"].attrs["writes"] == writes[
+            r.attrs["block"]]
+    # the fence of the first apply closed the blockstore's window
+    fsyncs = [c for r in roots for ap in r.children if ap.name == "apply"
+              for f in ap.children if f.name == "apply.fence"
+              for c in f.children]
+    assert fsyncs and {c.name for c in fsyncs} == {"fsync"}
+    assert {c.attrs["trigger"] for c in fsyncs} == {"apply"}
+
+
+def test_disarmed_tracer_leaves_the_applier_untraced(tmp_path):
+    roots, parked, _writes = _traced_pipeline_run(tmp_path, 0)
+    assert roots == []
+    assert parked["entry_roots"] == [None] * 4
+
+
+def test_apply_history_span_and_backpressure_on_commit_enqueue(tmp_path):
+    """``commit_block`` under a span: ``commit.append`` and
+    ``commit.enqueue`` share its clock reads, ``commit.index`` sits
+    inside the append, a parked enqueue says for how long, and the
+    history commit is the apply's third child."""
+    from fabric_tpu.observe import global_tracer
+
+    tracer = global_tracer()
+    was = tracer.ring_blocks
+    tracer.configure(ring_blocks=8)
+    inner = _GatedSqlite(str(tmp_path / "state.db"))
+    lg = KVLedger(str(tmp_path / "lg"), state_db=inner, async_commit=True,
+                  apply_queue_blocks=1)
+    roots, prev = [], b""
+    try:
+        opener = threading.Timer(0.15, inner.gate.set)
+        for num in range(2):
+            blk = _block(num, prev, [b"data%d" % num])
+            prev = pu.block_header_hash(blk.header)
+            batch = UpdateBatch()
+            batch.put("ns", f"k{num}", b"v", (num, 0))
+            root = tracer.begin_block(num)
+            roots.append(root)
+            if num == 1:
+                opener.start()  # block 0 holds the one queue slot
+            with tracer.span("commit", parent=root):
+                lg.commit_block(blk, bytes([0]), batch,
+                                [("ns", f"k{num}", 0)])
+            tracer.finish_block(root)
+        lg.drain_state()
+    finally:
+        inner.gate.set()
+        lg.close()
+        tracer.configure(ring_blocks=was)
+    for num, root in enumerate(roots):
+        commit = next(c for c in root.children if c.name == "commit")
+        by = {c.name: c for c in commit.children}
+        assert list(by) == ["commit.index", "commit.append",
+                            "commit.enqueue"]
+        app, enq, idx = (by["commit.append"], by["commit.enqueue"],
+                         by["commit.index"])
+        assert app.t1 == enq.t0  # one clock read for the boundary
+        assert app.t0 <= idx.t0 <= idx.t1 <= app.t1
+        assert idx.attrs == {"idx_wait_ms": 0.0, "txids": 1}
+        assert lg.last_commit_timings["state_apply"] > 0
+        if num == 0:
+            assert enq.attrs["backpressure_ms"] == 0.0
+        else:  # parked until the gate opened
+            assert 50.0 < enq.attrs["backpressure_ms"] <= (
+                enq.t1 - enq.t0) * 1000.0
+        ap = next(c for c in root.children if c.name == "apply")
+        assert [c.name for c in ap.children] == [
+            "apply.fence", "apply.write", "apply.history"]
+
+
+@pytest.mark.parametrize("armed", [False, True])
+def test_apply_never_builds_a_columnar_batchs_dict(tmp_path, armed):
+    """The sqlite fast path applies a columnar batch from its slabs; the
+    ``apply.write`` span counts its rows the same way.  Building the
+    lazy dict to count them cost 6 ms a 1000-tx block (``rw_paced`` on
+    the chip, PR 25), traced or not."""
+    from fabric_tpu.observe import global_tracer
+
+    tracer = global_tracer()
+    was = tracer.ring_blocks
+    tracer.configure(ring_blocks=4 if armed else 0)
+    inner = SqliteVersionedDB(str(tmp_path / "state.db"))
+    inner.open()
+    eng = AsyncApplyEngine(inner)
+    cb = _columnar()
+    cb.put("ns", "d", b"late", (5, 3))   # one post-build override
+    try:
+        root = tracer.begin_block(5)
+        with tracer.span("commit", parent=root):
+            eng.submit(5, cb, (5, 0))
+        tracer.finish_block(root)
+        eng.drain()
+        assert cb._updates is None
+        assert inner.get_state("ns", "a").value == b"CCCC"
+    finally:
+        eng.close()
+        tracer.configure(ring_blocks=was)
+    if armed:
+        ap = next(c for c in root.children if c.name == "apply")
+        write = next(c for c in ap.children if c.name == "apply.write")
+        assert write.attrs == {"writes": 4 + 1}   # slab rows + override
+    else:
+        assert root is None

@@ -3,6 +3,8 @@ history, kvledger commit-hash chain + crash recovery (scenarios
 modeled on the reference's blkstorage/kvledger test coverage)."""
 
 import os
+import threading
+import time
 
 import pytest
 
@@ -138,6 +140,104 @@ def test_blockstore_index_reads_survive_concurrent_threads(tmp_path):
     assert errors == []
     assert bs.height == n and n > 0
     bs.close()
+
+
+def _hold(lock, seconds):
+    """A thread that takes ``lock`` now and keeps it for ``seconds``."""
+    held = threading.Event()
+
+    def run():
+        with lock:
+            held.set()
+            time.sleep(seconds)
+
+    t = threading.Thread(target=run)
+    t.start()
+    assert held.wait(10)
+    return t
+
+
+@pytest.mark.parametrize("side", ["reader", "writer"])
+def test_blockstore_idx_lock_wait_is_counted_by_side(tmp_path, side):
+    """A lookup (``tx_exists``) that finds the index lock held adds its
+    wait to ``idx_wait_reader_s``, an ``add_block`` to
+    ``idx_wait_writer_s``; a free lock reads no clock and leaves both
+    as they were."""
+    bs = BlockStore(str(tmp_path / "chains"))
+    b0 = _block(0, b"", [b"a", b"b"])
+    bs.add_block(b0)
+    assert bs.tx_exists("tx0-1") and not bs.tx_exists("nope")
+    assert bs.height == 1
+    assert (bs.idx_wait_reader_s, bs.idx_wait_writer_s) == (0.0, 0.0)
+    hold_s = 0.2
+    if side == "reader":
+        t = _hold(bs._idx_lock, hold_s)
+        assert bs.tx_exists("tx0-0")
+        got, other = bs.idx_wait_reader_s, bs.idx_wait_writer_s
+    else:
+        b1 = _block(1, pu.block_header_hash(b0.header), [b"c"])
+        index_block, started = bs._index_block, []
+
+        def index_block_late(*a, **kw):
+            started.append(bs.idx_wait_writer_s)
+            return index_block(*a, **kw)
+
+        bs._index_block = index_block_late
+        # add_block takes the file lock before the index lock: park it
+        # there, past its height lookup, until the index lock is held
+        with bs._io_lock:
+            adder = threading.Thread(target=bs.add_block, args=(b1,))
+            adder.start()
+            time.sleep(0.05)
+            t = _hold(bs._idx_lock, hold_s)
+        adder.join(10)
+        assert not adder.is_alive() and bs.get_tx_loc("tx1-0") == (1, 0, 254)
+        # counted while holding the lock, before the index insert
+        assert started == [bs.idx_wait_writer_s]
+        got, other = bs.idx_wait_writer_s, bs.idx_wait_reader_s
+    t.join(10)
+    assert 0.5 * hold_s < got < 10 * hold_s
+    assert other == 0.0
+    bs.close()
+
+
+@pytest.mark.parametrize("trigger", ["group", "lag", "forced", "apply"])
+def test_blockstore_fsync_span_carries_its_trigger(tmp_path, trigger):
+    """Each real fsync is one ``fsync`` span under the syncing thread's
+    current span, with what closed the window and how many blocks it
+    held; a sync with nothing to sync records none."""
+    from fabric_tpu.observe import global_tracer
+
+    tracer = global_tracer()
+    was = tracer.ring_blocks
+    tracer.configure(ring_blocks=4)
+    bs = BlockStore(str(tmp_path / "chains"),
+                    group_commit=2 if trigger == "group" else 8,
+                    group_max_lag_s=0.0 if trigger == "lag" else 60.0)
+    try:
+        root = tracer.begin_block(0)
+        with tracer.span("commit", parent=root) as commit:
+            prev = b""
+            for n in range(2):
+                blk = _block(n, prev, [b"a"])
+                bs.add_block(blk)
+                prev = pu.block_header_hash(blk.header)
+            if trigger == "forced":
+                bs.sync()
+            elif trigger == "apply":
+                bs.ensure_synced(0)
+            bs.sync()           # nothing left: no second span
+            bs.ensure_synced(1)
+        tracer.finish_block(root)
+    finally:
+        bs.close()
+        tracer.configure(ring_blocks=was)
+    got = [(c.attrs["trigger"], c.attrs["blocks"])
+           for c in commit.children if c.name == "fsync"]
+    want = {"group": [("group", 2)], "lag": [("lag", 1), ("lag", 1)],
+            "forced": [("forced", 2)], "apply": [("apply", 2)]}[trigger]
+    assert got == want
+    assert [c.name for c in commit.children].count("commit.index") == 2
 
 
 def test_blockstore_reopen_and_torn_write_recovery(tmp_path):

@@ -192,7 +192,17 @@ def test_pipeline_span_tree_shape(toy_run):
             assert names.count(want) == 1, (r.attrs, want, names)
         for c in r.children:
             assert c.t1 is not None, (r.attrs, c.name)
+            if c.name == "feed_wait" and "before_flush" not in c.attrs:
+                # the wait for the feeder ends where the root begins
+                assert c.t0 <= c.t1 <= r.t0 + 1e-6
+                continue
             assert c.t0 >= r.t0 - 1e-6 and c.t1 <= r.t1 + 1e-6
+        # every block but the first was submitted straight after the
+        # one before (a feed_wait each, none of them after a flush);
+        # the last waited, launched, for the flush that closed the pipe
+        waits = [c.attrs for c in r.children if c.name == "feed_wait"]
+        assert waits == ([{}] * (r.attrs["block"] > 0)
+                         + [{"before_flush": True}] * ("tail" in r.attrs))
         by = {c.name: c for c in r.children}
         # prefetch ran on the prefetch thread; pipelined commits on the
         # committer thread (the tail flushes inline on the caller)
@@ -269,6 +279,63 @@ def test_traceview_renders_trace_dump(toy_run):
     assert "block 4" in text and "commit" in text
     single = traceview.render(tr.block(3))
     assert single.startswith("block 3") and "finish" in single
+
+
+def _late_tree():
+    """A finished block whose applier spans arrived after
+    ``finish_block`` and whose ``feed_wait`` ends where it begins."""
+    clk = _Clock()
+    tr = Tracer(ring_blocks=4, slow_factor=0, clock=clk)
+    clk.advance(1.0)
+    root = tr.begin_block(7)
+    tr.add("feed_wait", clk.t - 0.4, clk.t, parent=root)
+    with tr.span("commit", parent=root):
+        clk.advance(0.05)
+    tr.finish_block(root)
+    dur = root.dur
+    clk.advance(0.1)
+    with tr.span("apply", parent=root, queued_ms=100.0):
+        with tr.span("apply.write", writes=3):
+            clk.advance(0.2)
+    return tr, root, dur
+
+
+@pytest.mark.parametrize("surface", ["format_block", "trace_dump", "chrome"])
+def test_late_and_early_children_render(surface):
+    """Children outside the root's extent (the applier's, after it; the
+    feed wait, before it) show on every surface, and the root's own
+    duration stays the commit's."""
+    import traceview
+
+    from fabric_tpu.observe import format_block
+
+    tr, root, dur = _late_tree()
+    assert root.dur == dur == pytest.approx(0.05)
+    if surface == "format_block":
+        text = format_block(root)
+    elif surface == "trace_dump":
+        assert tr.block(7)["dur_ms"] == pytest.approx(50.0)
+        text = traceview.render(tr.block(7))
+    else:
+        text = traceview.render({"traceEvents": tr.chrome_events()})
+    for name in ("feed_wait", "commit", "apply", "apply.write"):
+        assert name in text, (surface, name)
+    if surface != "format_block":
+        assert text.startswith("block 7  total 50.00 ms")
+
+
+@pytest.mark.parametrize("name,covers", [("feed_wait", False),
+                                         ("apply", True)])
+def test_overlap_counts_the_applier_but_not_the_feed_wait(name, covers):
+    """A neighbour's ``apply`` hides a block's device wait (host work
+    on another thread); its ``feed_wait`` does not (nobody works)."""
+    from fabric_tpu.observe import coverage_from_spans
+
+    rows = [(1, "device_wait", 1.0, 2.0), (2, name, 0.5, 2.5),
+            (2, "launch", 3.0, 3.1)]
+    got = coverage_from_spans(rows, window=1)
+    assert got["blocks_measured"] == 1
+    assert got["mean"] == (1.0 if covers else 0.0)
 
 
 # ---------------------------------------------------------------------------

@@ -540,3 +540,148 @@ def test_commit_pipeline_resident_state_matches_serial(net):
                 "the hot working set never hit the resident table"
             )
         v_p.close()
+
+
+# ---------------------------------------------------------------------------
+# what is inside launch, and the wait between submits, on the span tree
+
+
+@pytest.fixture
+def traced_roots():
+    """The process tracer armed, a listener collecting finished roots."""
+    from fabric_tpu import observe
+
+    tracer = observe.global_tracer()
+    was, roots = tracer.ring_blocks, []
+    observe.configure(ring_blocks=16)
+    tracer.add_listener(roots.append)
+    yield roots
+    tracer.remove_listener(roots.append)
+    observe.configure(ring_blocks=was)
+
+
+def _chained(net, n_blocks, replay_in=None):
+    """Blocks 0..n-1 chained by hash, 16 txs each; block ``replay_in``
+    carries an envelope of block 0 again."""
+    blocks, prev, first = [], b"", None
+    for n in range(n_blocks):
+        envs = [_tx(net, writes=[(f"w{n}", b"1")])]
+        first = first or envs[0]
+        if n == replay_in:
+            envs.append(first)
+        blk = _block(n, prev, envs, pad_net=net)
+        prev = pu.block_header_hash(blk.header)
+        blocks.append(blk)
+    return blocks
+
+
+@pytest.mark.parametrize("store", ["block_store", "in_flight_only"])
+def test_dup_txid_span_is_a_child_of_launch(net, tmp_path, traced_roots,
+                                            store):
+    """``dup_txid`` hangs under ``launch`` and says how many txs it
+    checked and how many it found: against the block store's index
+    (block 0 committed long before block 2 launches), or against the
+    in-flight predecessor's txid set alone."""
+    from fabric_tpu.ledger.blockstore import BlockStore
+
+    by_store = store == "block_store"
+    blocks = _chained(net, 3, replay_in=2 if by_store else 1)
+    bs = BlockStore(str(tmp_path / "chains")) if by_store else None
+    state = _state(net)
+    v = BlockValidator(net["mgr"], net["prov"], state, block_store=bs)
+    filters = {}
+
+    def commit_fn(res):
+        num = res.block.header.number
+        if bs is not None:
+            pu.set_tx_filter(res.block, res.tx_filter)
+            bs.add_block(res.block, txids=res.txids)
+        state.apply_updates(res.batch, (num, 0))
+        filters[num] = list(res.tx_filter)
+
+    with CommitPipeline(v, commit_fn, depth=2) as pipe:
+        for b in blocks:
+            pipe.submit(b)
+    if bs is not None:
+        bs.close()
+    v.close()
+    dup_block = 2 if by_store else 1
+    assert filters[dup_block].count(C.DUPLICATE_TXID) == 1
+    assert [r.attrs["block"] for r in traced_roots] == [0, 1, 2]
+    for r in traced_roots:
+        launch = [c for c in r.children if c.name == "launch"]
+        assert len(launch) == 1
+        dups = [c for c in launch[0].children if c.name == "dup_txid"]
+        # block 0 of the store-less run has nothing to check against
+        if not by_store and r.attrs["block"] == 0:
+            assert dups == []
+            continue
+        assert len(dups) == 1
+        d = dups[0]
+        assert d.thread == launch[0].thread
+        assert launch[0].t0 <= d.t0 <= d.t1 <= launch[0].t1
+        # every tx of these blocks is a well-formed endorser tx, still
+        # undetermined when the launch looks it up
+        assert d.attrs["lookups"] == len(
+            blocks[r.attrs["block"]].data.data)
+        assert d.attrs["hits"] == (r.attrs["block"] == dup_block)
+        assert d.attrs["idx_wait_ms"] >= 0.0
+
+
+def test_feed_wait_covers_the_gap_between_submits(net, traced_roots):
+    """``feed_wait`` on a block's root runs from the previous submit's
+    return to this submit's entry, so it ends where the root begins;
+    after a flush it runs from the flush's return and says so; the
+    idle that led to a flush lands on the block the flush finishes;
+    the first block has none before it.  It is a wait:
+    ``observe.overlap`` does not count it as host work."""
+    from fabric_tpu.observe.overlap import NON_HOST
+
+    blocks = _chained(net, 4)
+    state = _state(net)
+    v = BlockValidator(net["mgr"], net["prov"], state)
+    marks = {}
+    with CommitPipeline(
+            v, lambda res: state.apply_updates(
+                res.batch, (res.block.header.number, 0)),
+            depth=2) as pipe:
+        pipe.submit(blocks[0])
+        marks["ret0"] = time.perf_counter()
+        time.sleep(0.08)
+        marks["in1"] = time.perf_counter()
+        pipe.submit(blocks[1])
+        pipe.submit(blocks[2])
+        time.sleep(0.03)
+        marks["flush_in"] = time.perf_counter()
+        pipe.flush()
+        marks["flush_ret"] = time.perf_counter()
+        time.sleep(0.05)
+        marks["in3"] = time.perf_counter()
+        pipe.submit(blocks[3])
+    v.close()
+    by_block = {r.attrs["block"]: r for r in traced_roots}
+    waits = {k: [c for c in r.children if c.name == "feed_wait"]
+             for k, r in by_block.items()}
+    assert [[sorted(w.attrs) for w in waits[k]] for k in range(4)] == [
+        [], [[]], [[], ["before_flush"]],
+        [["after_flush"], ["before_flush"]]]   # the pipe's exit flushes
+    for k in (1, 2, 3):
+        w, r = waits[k][0], by_block[k]
+        assert w.t0 <= w.t1 <= r.t0 and w.thread == r.thread
+    w1, w2, w3 = waits[1][0], waits[2][0], waits[3][0]
+    # the sleep between two submits, and nothing of either submit
+    assert w1.t0 <= marks["ret0"] and marks["in1"] <= w1.t1
+    assert 0.08 <= w1.t1 - w1.t0 < 0.08 + 0.05
+    assert w2.t1 - w2.t0 < 0.02
+    # the idle before a flush lands on the block in hand, after its
+    # launch and before its finish
+    idle, r2 = waits[2][1], by_block[2]
+    launch, finish = (next(c for c in r2.children if c.name == n)
+                      for n in ("launch", "finish"))
+    assert launch.t1 <= idle.t0 and idle.t1 <= finish.t0
+    assert 0.03 <= idle.t1 - idle.t0 < 0.03 + 0.05
+    assert idle.t1 <= marks["flush_in"] + 1e-3
+    # after a flush: from the flush's return, not from submit(2)'s
+    assert marks["flush_in"] < w3.t0 <= marks["flush_ret"]
+    assert 0.05 <= w3.t1 - w3.t0 < 0.05 + 0.05
+    assert "feed_wait" in NON_HOST
