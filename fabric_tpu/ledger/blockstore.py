@@ -58,23 +58,48 @@ class BlockStore:
         # (ensure_synced — the durability fence); uncontended cost is
         # one futex op per block
         self._io_lock = threading.Lock()
-        # serializes the index connection between the committer, the
-        # validator's dup-txid lookups and the gateway's status reads:
-        # two threads running the SAME statement text on one sqlite3
-        # connection share its cached prepared statement, and a bind
-        # racing a step fails with "bad parameter or other API misuse"
+        # The index has two sqlite connections, one for the thread that
+        # writes and one for everybody who reads; each has a lock of its
+        # own and neither side ever takes the other's.  Each side hands
+        # sqlite a block's work in one statement (_index_block,
+        # existing_txids), never one a tx.
+        # ``_idx`` (``_idx_lock``) belongs to those who write:
+        # add_block's insert + commit on the committer thread,
+        # _recover, bootstrap_from_snapshot, and the two snapshot
+        # iterators (their cursors live as long as the export, which a
+        # reader's must not: see _rd_rows).
+        # ``_rd`` (``_rd_lock``) answers every lookup: the validator's
+        # duplicate-txid query, the gateway's status reads, and the
+        # committer's own ``height``.  The index is in WAL mode, so a
+        # read on ``_rd`` never waits for the writer's transaction and
+        # sees every transaction committed before the read began.  The
+        # lock is for the readers among themselves: two threads running
+        # the SAME statement text on one sqlite3 connection share its
+        # cached prepared statement, and a bind racing a step fails
+        # with "bad parameter or other API misuse".
         self._idx_lock = threading.Lock()
-        # seconds spent WAITING for _idx_lock, by the side that waited:
-        # readers (_idx_row: the dup-txid lookups, height, status
-        # reads) and the writer (add_block's index insert + commit).
-        # Only a contended acquire reads the clock; each float is
-        # updated while holding the lock.  The ``dup_txid`` and
-        # ``commit.index`` spans carry the delta over their extent.
+        self._rd_lock = threading.Lock()
+        # seconds spent WAITING for a lock, by the side that waited:
+        # readers for ``_rd_lock`` (held for one statement by another
+        # reader), the writer for ``_idx_lock`` (nobody else holds it
+        # on the commit path).  Only a contended acquire reads the
+        # clock; each float is updated while holding the lock.  The
+        # ``dup_txid`` and ``commit.index`` spans carry the delta over
+        # their extent.
         self.idx_wait_reader_s = 0.0
         self.idx_wait_writer_s = 0.0
+        # statements existing_txids has sent (the launching thread is
+        # its one caller): ``dup_txid`` carries the delta as
+        # ``queries``, 1 a block
+        self.txid_queries = 0
         os.makedirs(dirpath, exist_ok=True)
         self._idx = sqlite3.connect(
             os.path.join(dirpath, "index.db"), check_same_thread=False
+        )
+        # variables one statement takes (32,766 since sqlite 3.32, 999
+        # before): what cuts the block-wide statements of both sides
+        self._max_vars = self._idx.getlimit(
+            sqlite3.SQLITE_LIMIT_VARIABLE_NUMBER
         )
         self._idx.execute("PRAGMA journal_mode=WAL")
         # the index is DERIVED state (rebuilt forward — and clamped
@@ -100,6 +125,12 @@ class BlockStore:
             " first_block INTEGER, prev_hash BLOB, commit_hash BLOB)"
         )
         self._recover()
+        # opened after recovery, so its first read sees the index
+        # as _recover left it
+        self._rd = sqlite3.connect(
+            os.path.join(dirpath, "index.db"), check_same_thread=False
+        )
+        self._rd.execute("PRAGMA query_only=ON")
         # fsync watermark in block numbers: everything recovery left in
         # the files is already durable (or was truncated away), so the
         # synced watermark starts at the tip
@@ -206,14 +237,24 @@ class BlockStore:
                     continue  # non-envelope payload: nothing to index
                 if ch.tx_id:
                     txids.append((ch.tx_id, i))
-        self._idx.executemany(
-            "INSERT OR IGNORE INTO txids VALUES (?,?,?,?)",
-            [
-                (txid, block.header.number, i,
-                 flags[i] if i < len(flags) else 254)
-                for txid, i in txids if txid
-            ],
-        )
+        num = block.header.number
+        rows = [
+            (txid, num, i, flags[i] if i < len(flags) else 254)
+            for txid, i in txids if txid
+        ]
+        # the block's rows in one statement (as many as sqlite takes
+        # variables in one), not one step a row: sqlite3 hands the
+        # interpreter lock over around every step, and 1000 hand-overs
+        # a block beside the launching, prefetch and applier threads
+        # cost the committer, and them, more than the rows do
+        per = self._max_vars // 4
+        for at in range(0, len(rows), per):
+            part = rows[at:at + per]
+            self._idx.execute(
+                "INSERT OR IGNORE INTO txids VALUES "
+                + ",".join(["(?,?,?,?)"] * len(part)),
+                [v for row in part for v in row],
+            )
 
     # -- public API --------------------------------------------------------
 
@@ -239,17 +280,27 @@ class BlockStore:
             )
         ctr.add(1, trigger=trigger)
 
-    def _idx_row(self, sql: str, args: tuple = ()):
-        """One index row (or None) under the connection lock."""
-        lock = self._idx_lock
+    def _rd_rows(self, sql: str, args=()) -> list:
+        """Every row of one statement on the read connection, under the
+        readers' lock.  The cursor is fetched to its end before the
+        lock is released: an open cursor on a WAL reader pins its
+        snapshot, so later reads on the connection would answer from
+        before the commits since, checkpoints could not finish and
+        ``index.db-wal`` would grow without bound."""
+        lock = self._rd_lock
         if not lock.acquire(False):
             t0 = time.perf_counter()
             lock.acquire()
             self.idx_wait_reader_s += time.perf_counter() - t0
         try:
-            return self._idx.execute(sql, args).fetchone()
+            return self._rd.execute(sql, args).fetchall()
         finally:
             lock.release()
+
+    def _idx_row(self, sql: str, args: tuple = ()):
+        """One index row (or None)."""
+        rows = self._rd_rows(sql, args)
+        return rows[0] if rows else None
 
     @property
     def height(self) -> int:
@@ -421,6 +472,22 @@ class BlockStore:
     def tx_exists(self, txid: str) -> bool:
         return self.get_tx_loc(txid) is not None
 
+    def existing_txids(self, txids) -> set[str]:
+        """The subset of ``txids`` the index holds: the duplicate check
+        of a whole block in one statement (as many as sqlite takes
+        variables in one), never one a tx."""
+        txids = list(txids)
+        found: set[str] = set()
+        for i in range(0, len(txids), self._max_vars):
+            part = txids[i:i + self._max_vars]
+            marks = ",".join("?" * len(part))
+            rows = self._rd_rows(
+                f"SELECT txid FROM txids WHERE txid IN ({marks})", part
+            )
+            self.txid_queries += 1
+            found.update(t for (t,) in rows)
+        return found
+
     def iter_blocks(self, start: int = 0):
         num = start
         while True:
@@ -472,4 +539,5 @@ class BlockStore:
     def close(self):
         self.sync()
         self._fh.close()
+        self._rd.close()
         self._idx.close()

@@ -1373,7 +1373,18 @@ class BlockValidator:
         launches without waiting for any predecessor's fsync.
         ``extra_txids``: txids of EVERY in-flight predecessor for the
         duplicate-txid check (their block-store index inserts may not
-        have landed yet).
+        have landed yet).  The check asks the block store once a block
+        (``existing_txids``, on the index's read connection) and is
+        exact, because index ∪ ``extra_txids`` holds every txid of the
+        chain at every pipeline depth: a read on that connection sees
+        every index transaction committed before it began (WAL); the
+        pipeline has waited out every commit it no longer carries in
+        its window before it launches (``_drain_commits``), so those
+        blocks' rows are committed; and the commits still in its
+        window are the ones whose txids arrive here
+        (``_launch_overlay``).  Nothing is remembered in between: no
+        window of recent txids, no filter, nothing to rebuild after a
+        restart.
 
         Pipelined callers must SERIALIZE around blocks that rotate
         validation inputs — config blocks (MSP/policy object rotation)
@@ -1409,34 +1420,38 @@ class BlockValidator:
         # vectorized state_fill reads it as the live verdict array.
         if self.blocks is not None or extra_txids:
             # ``dup_txid``: a child of the pipeline's ``launch`` (no-op
-            # off a traced launch).  What it carries is counted outside
-            # the loop, so a lookup costs what it did: ``lookups`` is
-            # the txs checked (each calls tx_exists unless an in-flight
-            # predecessor's txid set already answered), ``idx_wait_ms``
-            # what readers of the block store's index waited for its
-            # lock meanwhile
+            # off a traced launch).  ``lookups`` is the txs checked,
+            # ``queries`` the statements the block store's index was
+            # sent for them (one a block; none when the in-flight
+            # predecessors' txid set answered every tx), ``idx_wait_ms``
+            # what readers of the index waited for their lock meanwhile
             with self._tracer.span("dup_txid") as dsp:
+                blocks = self.blocks
                 if dsp is not None:
-                    lookups = sum(1 for ptx in txs if ptx.undetermined
-                                  and not ptx.is_config)
-                    waited0 = getattr(self.blocks, "idx_wait_reader_s", 0.0)
+                    waited0 = getattr(blocks, "idx_wait_reader_s", 0.0)
+                    queries0 = getattr(blocks, "txid_queries", 0)
+                inflight = extra_txids or ()
+                cand = [ptx for ptx in txs
+                        if ptx.undetermined and not ptx.is_config]
+                committed = ()
+                if blocks is not None:
+                    committed = blocks.existing_txids(
+                        [ptx.txid for ptx in cand
+                         if ptx.txid not in inflight])
                 hits = 0
-                for ptx in txs:
-                    if ptx.undetermined and not ptx.is_config and (
-                        (extra_txids is not None
-                         and ptx.txid in extra_txids)
-                        or (self.blocks is not None
-                            and self.blocks.tx_exists(ptx.txid))
-                    ):
+                for ptx in cand:
+                    if ptx.txid in inflight or ptx.txid in committed:
                         ptx.code = C.DUPLICATE_TXID
                         hits += 1
                         if fb is not None:
                             fb.codes[ptx.idx] = int(C.DUPLICATE_TXID)
                 if dsp is not None:
                     dsp.attrs.update(
-                        lookups=lookups, hits=hits,
+                        lookups=len(cand), hits=hits,
+                        queries=getattr(blocks, "txid_queries", 0)
+                        - queries0,
                         idx_wait_ms=(getattr(
-                            self.blocks, "idx_wait_reader_s", 0.0)
+                            blocks, "idx_wait_reader_s", 0.0)
                             - waited0) * 1000.0)
 
         pending = PendingBlock(

@@ -98,8 +98,9 @@ def test_blockstore_append_get_and_txids(tmp_path):
 
 def test_blockstore_index_reads_survive_concurrent_threads(tmp_path):
     """The validator's dup-txid lookups, the gateway's status reads and
-    the committer share one index connection; threads running the same
-    statement used to fail with sqlite3.InterfaceError."""
+    the committer's ``height`` share the index's read connection;
+    threads running the same statement on one connection used to fail
+    with sqlite3.InterfaceError."""
     import sys
     import threading
     import time
@@ -112,6 +113,7 @@ def test_blockstore_index_reads_survive_concurrent_threads(tmp_path):
         while not stop.is_set():
             try:
                 bs.get_tx_loc(f"tx{i % 64}-0")
+                bs.existing_txids([f"tx{i % 64}-0", "nope"])
                 bs.height
             except Exception as e:  # the invariant under test
                 errors.append(repr(e))
@@ -159,20 +161,23 @@ def _hold(lock, seconds):
 
 @pytest.mark.parametrize("side", ["reader", "writer"])
 def test_blockstore_idx_lock_wait_is_counted_by_side(tmp_path, side):
-    """A lookup (``tx_exists``) that finds the index lock held adds its
-    wait to ``idx_wait_reader_s``, an ``add_block`` to
-    ``idx_wait_writer_s``; a free lock reads no clock and leaves both
-    as they were."""
+    """Each side counts the wait for the lock it really has: a lookup
+    that finds the READ connection's lock held (another reader is
+    inside its statement) adds to ``idx_wait_reader_s``, an
+    ``add_block`` that finds the WRITER's lock held adds to
+    ``idx_wait_writer_s``; neither takes the other's lock, and a free
+    lock reads no clock and leaves both as they were."""
     bs = BlockStore(str(tmp_path / "chains"))
     b0 = _block(0, b"", [b"a", b"b"])
     bs.add_block(b0)
     assert bs.tx_exists("tx0-1") and not bs.tx_exists("nope")
+    assert bs.existing_txids(["tx0-0", "nope"]) == {"tx0-0"}
     assert bs.height == 1
     assert (bs.idx_wait_reader_s, bs.idx_wait_writer_s) == (0.0, 0.0)
     hold_s = 0.2
     if side == "reader":
-        t = _hold(bs._idx_lock, hold_s)
-        assert bs.tx_exists("tx0-0")
+        t = _hold(bs._rd_lock, hold_s)
+        assert bs.existing_txids(["tx0-0"]) == {"tx0-0"}
         got, other = bs.idx_wait_reader_s, bs.idx_wait_writer_s
     else:
         b1 = _block(1, pu.block_header_hash(b0.header), [b"c"])
@@ -199,6 +204,201 @@ def test_blockstore_idx_lock_wait_is_counted_by_side(tmp_path, side):
     assert 0.5 * hold_s < got < 10 * hold_s
     assert other == 0.0
     bs.close()
+
+
+def _chain(bs, n_blocks, payloads=(b"a", b"b"), start=0, prev=b""):
+    for n in range(start, start + n_blocks):
+        blk = _block(n, prev, list(payloads))
+        bs.add_block(blk)
+        prev = pu.block_header_hash(blk.header)
+    return prev
+
+
+@pytest.mark.parametrize("case", ["empty", "none_known", "all_known",
+                                  "more_than_one_statement", "bootstrapped"])
+def test_blockstore_existing_txids_is_the_committed_subset(tmp_path, case):
+    """``existing_txids`` answers a block's whole duplicate check:
+    exactly the txids the index holds, whatever the size of the
+    question, txids a snapshot brought included; never a statement a
+    tx (``txid_queries`` counts them)."""
+    bs = BlockStore(str(tmp_path / "chains"))
+    if case == "bootstrapped":
+        bs.bootstrap_from_snapshot(7, b"h" * 32, [("snap-a", 0), ("snap-b", 11)])
+        assert bs.height == 7
+        known = {"snap-a", "snap-b"}
+    else:
+        _chain(bs, 3)
+        known = {f"tx{n}-{i}" for n in range(3) for i in range(2)}
+    asked, want, statements = {
+        "empty": ([], set(), 0),
+        "none_known": (["nope", "tx3-0", ""], set(), 1),
+        "all_known": (sorted(known), known, 1),
+        # more variables than one statement takes: the question is cut
+        "more_than_one_statement": (
+            [f"absent{i}" for i in range(4)] + sorted(known), known, 3),
+        "bootstrapped": (["snap-b", "tx0-0", "snap-a"], known, 1),
+    }[case]
+    if case == "more_than_one_statement":
+        bs._max_vars = 4
+    assert bs.existing_txids(asked) == want
+    assert bs.existing_txids(iter(asked)) == want       # any iterable
+    assert bs.txid_queries == 2 * statements
+    assert all(bs.tx_exists(t) for t in want)
+    bs.close()
+
+
+@pytest.mark.parametrize("statements", ["one", "several"])
+def test_blockstore_indexes_a_block_in_block_wide_statements(tmp_path,
+                                                             statements):
+    """``add_block`` hands a block's txid rows to sqlite in one
+    statement (cut only where a statement takes no more variables), and
+    indexes what a statement a row did: every txid at its position with
+    its code, the first of a repeated txid winning, also on the rebuild
+    from the files."""
+    path = str(tmp_path / "chains")
+    bs = BlockStore(path)
+    if statements == "several":
+        bs._max_vars = 9               # two rows of four a statement
+    sent = []
+    bs._idx.set_trace_callback(sent.append)
+    blk = _block(0, b"", [b"a", b"b", b"c", b"d", b"e"])
+    txids = [("tx0-0", 0), ("tx0-1", 1), ("tx0-0", 2), ("", 3), ("tx0-4", 4)]
+    pu.set_tx_filter(blk, bytes([0, 11, 9, 0, 254]))
+    bs.add_block(blk, txids=txids)
+    bs._idx.set_trace_callback(None)
+    inserts = [q for q in sent if q.startswith("INSERT OR IGNORE INTO txids")]
+    assert len(inserts) == (1 if statements == "one" else 2)
+    want = {"tx0-0": (0, 0, 0), "tx0-1": (0, 1, 11), "tx0-4": (0, 4, 254)}
+    assert {t: bs.get_tx_loc(t) for t in want} == want
+    assert bs.existing_txids(["tx0-0", "tx0-1", "tx0-2", "tx0-4", ""]) == set(want)
+    bs.close()
+    # the rebuild parses the envelopes: every position has its own txid
+    os.remove(os.path.join(path, "index.db"))
+    bs2 = BlockStore(path)
+    assert [bs2.get_tx_loc(f"tx0-{i}") for i in range(5)] == [
+        (0, 0, 0), (0, 1, 11), (0, 2, 9), (0, 3, 0), (0, 4, 254)]
+    bs2.close()
+
+
+def test_blockstore_add_block_is_visible_to_a_reader_thread(tmp_path):
+    """The read connection sees a block's txids as soon as ``add_block``
+    has returned, from whichever thread asks (each statement is a read
+    of its own: nothing pins an older snapshot)."""
+    bs = BlockStore(str(tmp_path / "chains"))
+    added, asked, answers = threading.Event(), threading.Event(), []
+
+    def reader():
+        answers.append(bs.existing_txids(["tx0-0", "tx0-1"]))
+        asked.set()
+        assert added.wait(10)
+        answers.append(bs.existing_txids(["tx0-0", "tx0-1"]))
+        answers.append(bs.height)
+
+    t = threading.Thread(target=reader)
+    t.start()
+    assert asked.wait(10)
+    _chain(bs, 1)
+    added.set()
+    t.join(10)
+    assert not t.is_alive()
+    assert answers == [set(), {"tx0-0", "tx0-1"}, 1]
+    bs.close()
+
+
+def test_blockstore_reader_does_not_wait_for_the_writer(tmp_path):
+    """A lookup finishes while another thread holds the writer's lock
+    with the next block's rows inserted and not yet committed (where
+    the committer spends its ``commit.index``); it sees the index as of
+    the last commit, and the new rows once they are committed."""
+    bs = BlockStore(str(tmp_path / "chains"))
+    prev = _chain(bs, 1)
+    inserted, release = threading.Event(), threading.Event()
+
+    def writer():
+        with bs._idx_lock:
+            bs._index_block(_block(1, prev, [b"c"]), 0, 0)
+            inserted.set()
+            assert release.wait(10)
+            bs._idx.commit()
+
+    w = threading.Thread(target=writer)
+    w.start()
+    assert inserted.wait(10)
+    answers = []
+
+    def reader():
+        answers.append(bs.existing_txids(["tx0-0", "tx1-0"]))
+        answers.append(bs.tx_exists("tx0-1"))
+        answers.append(bs.height)
+
+    r = threading.Thread(target=reader)
+    r.start()
+    r.join(5)
+    waited = r.is_alive()
+    release.set()
+    w.join(10)
+    r.join(10)
+    assert not waited and not w.is_alive() and not r.is_alive()
+    assert answers == [{"tx0-0"}, True, 1]
+    assert bs.existing_txids(["tx0-0", "tx1-0"]) == {"tx0-0", "tx1-0"}
+    assert bs.height == 2
+    assert bs.idx_wait_reader_s == 0.0
+    bs.close()
+
+
+def test_blockstore_lookups_do_not_pin_the_wal(tmp_path):
+    """Every read cursor is fetched to its end, so no reader pins a
+    WAL snapshot: each lookup sees the block just added, checkpoints
+    finish, and ``index.db-wal`` is reused, not grown, across 50
+    blocks of lookups between inserts.  (A cursor left open on the
+    read connection holds its snapshot: the next lookups answer from
+    before the insert, and the WAL grows with every block.)"""
+    path = str(tmp_path / "chains")
+    bs = BlockStore(path)
+    # checkpoint every few pages, so 50 small blocks see many
+    bs._idx.execute("PRAGMA wal_autocheckpoint=4")
+    wal = os.path.join(path, "index.db-wal")
+    payloads = [b"p"] * 40
+    prev, sizes = b"", []
+    for n in range(50):
+        prev = _chain(bs, 1, payloads, start=n, prev=prev)
+        txids = [f"tx{n}-{i}" for i in range(40)]
+        assert bs.existing_txids(txids + ["nope"]) == set(txids)
+        assert bs.tx_exists(txids[0]) and bs.get_block(n) is not None
+        assert bs.height == n + 1
+        sizes.append(os.path.getsize(wal))
+    assert max(sizes[25:]) <= max(sizes[:25])
+    bs.close()
+
+
+def test_blockstore_read_connection_follows_recovery_and_close(tmp_path):
+    """The read connection is opened after ``_recover``: a store whose
+    index was lost answers from the rebuilt one.  ``close()`` closes
+    it (the last connection gone, sqlite removes the WAL), and a
+    reopened store answers as before."""
+    import sqlite3
+
+    path = str(tmp_path / "chains")
+    bs = BlockStore(path)
+    _chain(bs, 3)
+    asked = ["tx0-0", "tx2-1", "tx3-0", "nope"]
+    want = {"tx0-0", "tx2-1"}
+    assert bs.existing_txids(asked) == want
+    rd = bs._rd
+    bs.close()
+    with pytest.raises(sqlite3.ProgrammingError):
+        rd.execute("SELECT 1")
+    assert not os.path.exists(os.path.join(path, "index.db-wal"))
+    bs2 = BlockStore(path)
+    assert bs2.existing_txids(asked) == want and bs2.height == 3
+    with pytest.raises(sqlite3.OperationalError):   # query_only
+        bs2._rd.execute("DELETE FROM txids")
+    bs2.close()
+    os.remove(os.path.join(path, "index.db"))
+    bs3 = BlockStore(path)
+    assert bs3.existing_txids(asked) == want and bs3.height == 3
+    assert bs3.get_tx_loc("tx2-1") == (2, 1, 254)
+    bs3.close()
 
 
 @pytest.mark.parametrize("trigger", ["group", "lag", "forced", "apply"])

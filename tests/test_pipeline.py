@@ -625,6 +625,101 @@ def test_dup_txid_span_is_a_child_of_launch(net, tmp_path, traced_roots,
         assert d.attrs["lookups"] == len(
             blocks[r.attrs["block"]].data.data)
         assert d.attrs["hits"] == (r.attrs["block"] == dup_block)
+        # one statement a block to the index; none without a store
+        assert d.attrs["queries"] == (1 if by_store else 0)
+        assert d.attrs["idx_wait_ms"] >= 0.0
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_dup_txid_check_is_exact_at_every_depth(net, tmp_path, traced_roots,
+                                                depth):
+    """The duplicate check asks the block store's index once a block,
+    on a read connection the committer does not use, and still equals
+    the serial oracle at every depth.  Block 5 repeats a txid of block
+    4 (its commit in flight at depth >= 2), of block 3 (in flight at
+    depth 3, just committed at depth 2), of block 2 (just committed at
+    depth 3) and of block 0 (old), and carries one txid twice: the
+    index or the in-flight window must know each, with the committer
+    held back so that the launch really reads beside its insert."""
+    import numpy as np
+
+    from fabric_tpu.ledger.blockstore import BlockStore
+
+    def fresh(tag):
+        return _tx(net, writes=[(f"w{tag}", b"1")])
+
+    envs = {n: [fresh(f"{n}_{i}") for i in range(16)] for n in range(5)}
+    twice = fresh("5_twice")
+    envs[5] = ([fresh("5_a"), envs[4][0], fresh("5_b"), envs[3][1],
+                envs[0][2], twice, twice, envs[2][0]]
+               + [fresh(f"5_{i}") for i in range(8)])
+    want_dups = [1, 3, 4, 6, 7]
+    blocks, prev = [], b""
+    for n in range(6):
+        blk = _block(n, prev, envs[n])
+        prev = pu.block_header_hash(blk.header)
+        blocks.append(blk)
+
+    def rig(name):
+        bs = BlockStore(str(tmp_path / name))
+        state = _state(net)
+        v = BlockValidator(net["mgr"], net["prov"], state, block_store=bs)
+        return bs, state, v
+
+    def commit(bs, state, block, flt, batch, txids=None):
+        pu.set_tx_filter(block, flt)
+        bs.add_block(block, txids=txids)
+        state.apply_updates(batch, (block.header.number, 0))
+
+    # the serial oracle: each block committed before the next is looked at
+    bs_s, state_s, v_s = rig("serial")
+    serial = []
+    for b in blocks:
+        b = common_pb2.Block.FromString(b.SerializeToString())
+        flt, batch, _ = v_s.validate(b)
+        commit(bs_s, state_s, b, flt, batch)
+        serial.append(list(flt))
+    bs_s.close()
+    v_s.close()
+    assert [i for i, c in enumerate(serial[5])
+            if c == C.DUPLICATE_TXID] == want_dups
+    assert all(c == C.VALID for f in serial[:5] for c in f)
+
+    bs, state, v = rig("piped")
+    launch, live_dups, filters = v.validate_launch, {}, {}
+
+    def launch_and_look(b, **kw):
+        pend = launch(b, **kw)
+        assert pend.fb is not None      # the columnar parse engaged
+        live_dups[b.header.number] = np.flatnonzero(
+            np.asarray(pend.fb.codes) == int(C.DUPLICATE_TXID)).tolist()
+        return pend
+
+    v.validate_launch = launch_and_look
+
+    def commit_fn(res):
+        time.sleep(0.05)    # hold the commit, and its index insert, in flight
+        commit(bs, state, res.block, res.tx_filter, res.batch, res.txids)
+        filters[res.block.header.number] = list(res.tx_filter)
+
+    with CommitPipeline(v, commit_fn, depth=depth) as pipe:
+        for b in blocks:
+            pipe.submit(b)
+    assert bs.height == 6
+    bs.close()
+    v.close()
+    assert [filters[n] for n in range(6)] == serial
+    # fb.codes, the verdict array state_fill reads, in step at launch
+    assert live_dups == {**{n: [] for n in range(5)}, 5: want_dups}
+    assert [r.attrs["block"] for r in traced_roots[-6:]] == list(range(6))
+    for r in traced_roots[-6:]:
+        (lsp,) = [c for c in r.children if c.name == "launch"]
+        (d,) = [c for c in lsp.children if c.name == "dup_txid"]
+        n = r.attrs["block"]
+        # the in-block repeat was settled by the parse: not looked up
+        assert d.attrs["lookups"] == (15 if n == 5 else 16)
+        assert d.attrs["hits"] == (4 if n == 5 else 0)
+        assert d.attrs["queries"] == 1
         assert d.attrs["idx_wait_ms"] >= 0.0
 
 

@@ -132,6 +132,48 @@ def test_duplicate_txid_in_block(net, validator):
     assert list(flt) == [C.VALID, C.DUPLICATE_TXID]
 
 
+@pytest.mark.parametrize("answered_by", ["index", "in_flight", "both"])
+def test_duplicate_txid_one_question_to_the_index(net, validator, tmp_path,
+                                                  answered_by):
+    """A launch asks the block store once for the txids the in-flight
+    set did not already answer, and marks what either knows: a
+    committed txid, an in-flight one, and neither of two fresh ones;
+    a tx the parse already settled is not asked about."""
+    from fabric_tpu.ledger.blockstore import BlockStore
+
+    e = [net["p1"], net["p2"]]
+    (old, old_id), (flying, flying_id), (new1, new1_id), (new2, new2_id) = [
+        _tx(net, e, writes=[(f"k{i}", b"v")]) for i in range(4)]
+    bs = BlockStore(str(tmp_path / "chains"))
+    b0 = _block([old], num=0)
+    pu.set_tx_filter(b0, bytes([C.VALID]))
+    bs.add_block(b0)
+    asked, existing = [], bs.existing_txids
+
+    def spy(txids):
+        asked.append(list(txids))
+        return existing(txids)
+
+    bs.existing_txids = spy
+    validator.blocks = bs if answered_by != "in_flight" else None
+    extra = {flying_id} if answered_by != "index" else None
+    blk = _block([new1, old, flying, new2, new2], num=1)
+    pend = validator.validate_launch(blk, extra_txids=extra)
+    flt, _, _ = validator.validate_finish(pend)
+    dup = C.DUPLICATE_TXID
+    assert list(flt) == {
+        "index": [C.VALID, dup, C.VALID, C.VALID, dup],
+        "in_flight": [C.VALID, C.VALID, dup, C.VALID, dup],
+        "both": [C.VALID, dup, dup, C.VALID, dup],
+    }[answered_by]
+    assert asked == {
+        "index": [[new1_id, old_id, flying_id, new2_id]],
+        "in_flight": [],
+        "both": [[new1_id, old_id, new2_id]],
+    }[answered_by]
+    bs.close()
+
+
 def test_unknown_namespace_rejected(net, validator):
     env, _ = _tx(net, [net["p1"], net["p2"]], writes=[("k", b"v")], ns="nope")
     flt, _, _ = validator.validate(_block([env]))
