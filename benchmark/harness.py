@@ -49,6 +49,9 @@ class Rig:
 
     def __init__(self, ledger_dir: str, config: dict, mgr, prov,
                  annotate=None, clock=time.perf_counter):
+        """``ledger_dir`` holds what the configuration's preload left:
+        the ledger's height there is the number of the first block the
+        rig will be fed."""
         from fabric_tpu.ledger.kvledger import KVLedger
         from fabric_tpu.peer.pipeline import CommitPipeline
         from fabric_tpu.peer.validator import BlockValidator
@@ -77,7 +80,8 @@ class Rig:
         self._watcher = None
         if self.lg.engine is not None:
             self._watcher = threading.Thread(
-                target=self._watch_applied, name="bench-applied", daemon=True)
+                target=self._watch_applied, args=(self.lg.height,),
+                name="bench-applied", daemon=True)
             self._watcher.start()
 
     def _commit(self, res) -> None:
@@ -100,8 +104,7 @@ class Rig:
         self.devices[num] = (sorted({d.platform for d in out.devices()})
                              if out is not None else [])
 
-    def _watch_applied(self) -> None:
-        num = 0
+    def _watch_applied(self, num: int) -> None:
         while not self._stop.is_set():
             if self.lg.engine.wait_applied(num, timeout=0.2):
                 self.applied[num] = self.clock()
@@ -162,9 +165,11 @@ def run_backlog(rig: Rig, blocks, first: int, ramp: int, seconds: float,
     """Closed loop: every block is ready at once.  Submits from
     ``blocks[first]``; the window opens at the apply of the ``ramp``-th
     block (the pipeline is then full), and submission stops ``seconds``
-    later or at the end of the stream."""
+    later or at the end of the stream.  ``first`` and ``ramp`` count
+    blocks of the stream; what comes back is in block numbers."""
     clock, n = rig.clock, len(blocks)
-    last_ramp = first + ramp - 1
+    base = blocks[0].header.number
+    last_ramp = base + first + ramp - 1
     t_open, k = None, first
     while k < n:
         if t_open is None:
@@ -182,27 +187,32 @@ def run_backlog(rig: Rig, blocks, first: int, ramp: int, seconds: float,
                 else f"{t_stop - t_open:.1f} s into the window")
         raise StreamDry(f"the stream of {n} blocks ended {when}, short of "
                         f"four fifths of {seconds} s")
-    return {"t_open": t_open, "first": last_ramp + 1, "submitted": k}
+    return {"t_open": t_open, "first": last_ramp + 1, "submitted": base + k}
 
 
 def run_paced(rig: Rig, blocks, first: int, n_due: int, rate: float,
-              block_tx: int, idle_flush_s: float, on_open=None,
-              sleep=time.sleep) -> dict:
-    """Open loop: block ``first + k`` is released when its last tx is
-    due, ``(k+1)·block_tx/rate`` after the window opens, whether or not
-    earlier blocks have finished.  While the stream is quiet for
+              idle_flush_s: float, on_open=None, sleep=time.sleep) -> dict:
+    """Open loop: ``blocks[first + k]`` is released when its last tx is
+    due, the txs of blocks ``first..first+k`` over ``rate`` after the
+    window opens, whether or not earlier blocks have finished.  While
+    the stream is quiet for
     ``idle_flush_s`` with a block in flight, the tail is flushed, as the
     deliver loop does (``PeerChannel.PIPELINE_IDLE_FLUSH_S``).  ``lag[k]``
     is how late the harness itself was in releasing block k: the
     overshoot of its own sleep, never the time a block waited for a busy
-    pipeline, which is the system's and counted in the tx latency."""
+    pipeline, which is the system's and counted in the tx latency.
+    ``first`` counts blocks of the stream; what comes back is in block
+    numbers."""
     clock = rig.clock
+    base = blocks[0].header.number
+    sizes = [len(b.data.data) for b in blocks[first:first + n_due]]
     t0 = clock()
+    dues = [timeline.block_due(t0, k, rate, sizes) for k in range(n_due)]
     if on_open is not None:
         on_open(t0)
     lag, k = [], 0
     while k < n_due:
-        due = timeline.block_due(t0, k, rate, block_tx)
+        due = dues[k]
         now = clock()
         late = 0.0
         if now < due:
@@ -213,11 +223,13 @@ def run_paced(rig: Rig, blocks, first: int, n_due: int, rate: float,
             sleep(due - now)
             now = clock()
             late = max(0.0, now - due)
-        ready = min(n_due - k, 1 + int((now - due) * rate / block_tx))
+        ready = 1
+        while k + ready < n_due and dues[k + ready] <= now:
+            ready += 1
         took = rig.feed(blocks, first + k, first + k + ready)
         lag.extend([late] + [0.0] * (took - 1))
         k += took
     sleep(idle_flush_s)
     rig.drain()
-    return {"t_open": t0, "first": first, "submitted": first + k,
-            "lag_s": lag}
+    return {"t_open": t0, "first": base + first,
+            "submitted": base + first + k, "lag_s": lag}

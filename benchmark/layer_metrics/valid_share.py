@@ -7,5 +7,5 @@ LAYER, UNIT, SOURCE, MOVES = ("workload_check", "%", "program_counter",
 
 
 def read(obs):
-    n = (obs.last - obs.first + 1) * obs.block_tx
+    n = sum(obs.block_txs)
     return obs.n_valid / n * 100.0 if n else None

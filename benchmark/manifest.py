@@ -64,3 +64,27 @@ def load_module(kind: str, name: str, root: str = ROOT):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def reference_of(config: dict, root: str = ROOT):
+    """The plain reference a configuration is held to: the module
+    ``benchmark/references/<name>.py`` where it gives ``"reference"``,
+    else ``benchmark/reference.py``.  Same functions either way."""
+    name = config.get("reference")
+    if name:
+        return load_module("references", name, root)
+    from benchmark import reference
+
+    return reference
+
+
+def preload_of(config: dict, root: str = ROOT):
+    """The module whose ``ensure(cache_dir, config, seed)`` builds what a
+    run starts from: ``benchmark/preloads/<name>.py`` where the
+    configuration gives ``"preload"``, else ``benchmark/preload.py``."""
+    name = config.get("preload")
+    if name:
+        return load_module("preloads", name, root)
+    from benchmark import preload
+
+    return preload

@@ -1,11 +1,15 @@
 """The state a run starts from: ``preload_keys`` keys of ``value_bytes``
 seeded bytes at ``preload_version``, loaded through the ledger's own
 state DB (whatever ``KVLedger`` opens by default) before any block.
+This is the preload of a configuration that names none; one that gives
+``"preload": "<name>"`` brings ``benchmark/preloads/<name>.py`` with an
+``ensure`` of the same shape (:func:`ensure_named` finds it).
 
 Loading a million keys takes 8 to 13 s, so a ledger directory is built
-once per (configuration, seed) under ``benchmark/.cache/`` and copied
-into each run's fresh directory; the newest few are kept.  Runs in a
-child process while the parent imports ``jax``.  No ``jax`` here.
+once per (configuration, seed) under ``benchmark/.cache/<configuration>/``
+and copied into each run's fresh directory; the newest few of that
+configuration are kept.  Runs in a child process while the parent imports
+``jax``.  No ``jax`` here.
 """
 
 from __future__ import annotations
@@ -44,18 +48,20 @@ def _load(ledger_dir: str, config: dict, seed: int) -> None:
         lg.close()
 
 
-def ensure(cache_dir: str, config: dict, seed: int) -> tuple:
-    """→ (the preloaded ledger directory for (configuration, seed), the
-    seconds it took to build: 0 where the cache held it)."""
+def cached(cache_dir: str, family: str, tag: str, build,
+           keep: int = KEEP) -> tuple:
+    """→ (``cache_dir/family/tag``, the seconds ``build(dirpath)`` took
+    to make it: 0 where the cache held it).  ``family`` is the
+    configuration's name: the newest ``keep`` directories of that
+    configuration stay, and no other configuration's are touched."""
     t0 = time.perf_counter()
-    tag = (f"{config['name']}-s{int(seed)}-{int(config['preload_keys'])}"
-           f"x{int(config['value_bytes'])}")
+    cache_dir = os.path.join(cache_dir, family)
     want = os.path.join(cache_dir, tag)
     if not os.path.isdir(want):
         os.makedirs(cache_dir, exist_ok=True)
         tmp = f"{want}.{os.getpid()}.tmp"
         shutil.rmtree(tmp, ignore_errors=True)
-        _load(tmp, config, seed)
+        build(tmp)
         os.replace(tmp, want)
         built = time.perf_counter() - t0
     else:
@@ -63,6 +69,26 @@ def ensure(cache_dir: str, config: dict, seed: int) -> tuple:
     os.utime(want)
     kept = sorted((e for e in os.scandir(cache_dir) if e.is_dir()),
                   key=lambda e: e.stat().st_mtime, reverse=True)
-    for e in kept[KEEP:]:
+    for e in kept[keep:]:
         shutil.rmtree(e.path, ignore_errors=True)
     return want, built
+
+
+def ensure(cache_dir: str, config: dict, seed: int) -> tuple:
+    """→ (the preloaded ledger directory for (configuration, seed), the
+    seconds it took to build: 0 where the cache held it, what the stream
+    continues from: the ``prev_hash`` its first block extends)."""
+    tag = (f"s{int(seed)}-{int(config['preload_keys'])}"
+           f"x{int(config['value_bytes'])}")
+    want, built = cached(cache_dir, config["name"], tag,
+                         lambda tmp: _load(tmp, config, seed))
+    return want, built, {"prev_hash": b""}
+
+
+def ensure_named(root: str, cache_dir: str, config: dict, seed: int) -> tuple:
+    """The ``ensure`` of the configuration's own preload, found by name
+    under ``root``: what a child process is handed, since a module
+    loaded by path cannot be."""
+    from benchmark import manifest
+
+    return manifest.preload_of(config, root).ensure(cache_dir, config, seed)

@@ -14,6 +14,13 @@ Two levels, which must agree with each other and with the system:
   validation/validator.go:81 on one thread).  About 0.6 s per 1000-tx
   block, so a seeded sample of blocks is held to it.
 
+A tx that carries the txid of a tx of an earlier block of the stream is
+``DUPLICATE_TXID`` (v20/validator.go:460-481 checkTxIdDupsLedger): by
+construction at the plan level, and at the OpenSSL level because the
+rolling :class:`RefState` holds every txid of the blocks committed to it
+(:func:`block_txids`, from the blocks' bytes), looked up after a good
+creator signature as the validator does.
+
 A copy of ``chip_smoke.serial_reference`` (PR 21), per block and over a
 rolling :class:`RefState`; a corrupted endorsement signature leaves one
 good endorsement of the two the policy asks for and gives
@@ -34,7 +41,10 @@ SIGNATURE_KINDS = {
     "bad_creator_signature": C.BAD_CREATOR_SIGNATURE,
     "bad_endorsement_signature": C.ENDORSEMENT_POLICY_FAILURE,
 }
-KINDS = ("ok", "stale_read", *SIGNATURE_KINDS)
+#: ``duplicate_txid``: the tx carries the txid of an ``ok`` tx of one of
+#: the blocks before its own, with writes and good signatures of its own
+BY_CONSTRUCTION = {**SIGNATURE_KINDS, "duplicate_txid": C.DUPLICATE_TXID}
+KINDS = ("ok", "stale_read", *BY_CONSTRUCTION)
 #: the version a ``stale_read`` tx claims to have read: no tx has index
 #: 1,000,000 and the preload sits at block 1, so it equals no real one
 STALE_VERSION = (0, 1_000_000)
@@ -47,13 +57,15 @@ def key_name(j: int) -> str:
 
 class RefState:
     """The committed state as the reference leaves it: ``preload_keys``
-    keys at ``preload_version`` until a valid tx rewrites them, and what
-    valid txs wrote."""
+    keys at ``preload_version`` until a valid tx rewrites them, what
+    valid txs wrote, and the txid of every tx (valid or not) of the
+    blocks committed to it."""
 
     def __init__(self, config: dict):
         self.preload_keys = int(config["preload_keys"])
         self.preload_version = tuple(config["preload_version"])
         self.written: dict = {}  # key → (value, version)
+        self.txids: set = set()
 
     def preloaded(self, key: str) -> bool:
         return (len(key) == 8 and key[0] == "k" and key[1:].isdigit()
@@ -65,8 +77,9 @@ class RefState:
             return got[1]
         return self.preload_version if self.preloaded(key) else None
 
-    def commit(self, writes: dict) -> None:
+    def commit(self, writes: dict, txids=()) -> None:
         self.written.update(writes)
+        self.txids.update(txids)
 
 
 def _conflicts(key: str, ver, done: dict, state: RefState) -> bool:
@@ -88,7 +101,7 @@ def plan_codes(rows, block_num: int, state: RefState) -> tuple:
     :meth:`RefState.commit` takes.  ``state`` is not changed."""
     codes, done = [], {}
     for i, (kind, reads, writes) in enumerate(rows):
-        code = SIGNATURE_KINDS.get(kind)
+        code = BY_CONSTRUCTION.get(kind)
         if code is None:
             code = C.VALID
             for key, ver in reads:
@@ -102,6 +115,21 @@ def plan_codes(rows, block_num: int, state: RefState) -> tuple:
     return bytes(codes), done
 
 
+def block_txids(blk) -> list:
+    """The txid every tx of a block carries in its channel header, from
+    the block's bytes."""
+    from fabric_tpu import protoutil as pu
+    from fabric_tpu.protos import common_pb2
+
+    out = []
+    for env_bytes in blk.data.data:
+        env = pu.unmarshal(common_pb2.Envelope, env_bytes)
+        payload = pu.unmarshal(common_pb2.Payload, env.payload)
+        out.append(pu.unmarshal(common_pb2.ChannelHeader,
+                                payload.header.channel_header).tx_id)
+    return out
+
+
 def openssl_codes(blk, mgr, prov, state: RefState, chaincode: str) -> tuple:
     """One block through the reference commit path, from its bytes
     alone → (filter bytes, writes) as :func:`plan_codes` gives them."""
@@ -113,15 +141,19 @@ def openssl_codes(blk, mgr, prov, state: RefState, chaincode: str) -> tuple:
     from fabric_tpu.protos import common_pb2
 
     plans: dict = {}
-    codes, done = [], {}
+    codes, done, seen = [], {}, set()
     for txnum, env_bytes in enumerate(blk.data.data):
         env = pu.unmarshal(common_pb2.Envelope, env_bytes)
-        _ch, sh, cap, _prp, cca = pu.extract_action(env)
+        ch, sh, cap, _prp, cca = pu.extract_action(env)
         creator = mgr.deserialize_identity(sh.creator)
         if not creator.is_valid or not creator.verify(env.payload,
                                                       env.signature):
             codes.append(C.BAD_CREATOR_SIGNATURE)
             continue
+        if ch.tx_id in state.txids or ch.tx_id in seen:
+            codes.append(C.DUPLICATE_TXID)
+            continue
+        seen.add(ch.tx_id)
         prp_bytes = cap.action.proposal_response_payload
         idents, valid = [], []
         for e in cap.action.endorsements:

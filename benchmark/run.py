@@ -15,7 +15,9 @@ prints no result; so does a run that could not be measured (a stream
 that ran dry).  A run whose outputs are wrong prints ``"correct": false``.
 
 Set-up, in the order it is paid (``setup_s`` is all of it): a child
-that loads the state DB, the network's keys and worker processes that
+that builds what the ledger starts from (the configuration's preload:
+the state DB, and for a peer joined from a snapshot the txid index at
+the snapshot's height), the network's keys and worker processes that
 sign the block stream, all started before this process imports ``jax``;
 the native libraries (built on a checkout's first run); the device and
 the compile cache; the warm-up blocks through the pipeline itself, which
@@ -32,6 +34,7 @@ import argparse  # noqa: E402
 import concurrent.futures as cf  # noqa: E402
 import contextlib  # noqa: E402
 import gc  # noqa: E402
+import itertools  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import multiprocessing  # noqa: E402
@@ -53,14 +56,35 @@ ZERO_COUNTERS = ("fallback_blocks_total", "validator_degraded",
 #: blocks submitted before the window opens in a backlog run, after the
 #: warm-up: the pipeline and the apply queue are then full
 RAMP_BLOCKS = 2
-#: how much longer than rate x seconds the stream is made.  More than it
-#: looks: the warm-up's rate is that of a short chain, and every cell is
-#: a fifth slower by the end of a window (PERF.md, PR 22); and a stream
-#: may end after four fifths of the window.
+#: how much longer than rate x seconds a backlog stream is made.  More
+#: than it looks: the warm-up's rate is that of a short chain, and every
+#: cell is a fifth slower by the end of a window (PERF.md, PR 22); and a
+#: stream may end after four fifths of the window.  The traffic's
+#: ``stream_hint_blocks_per_s`` is the other bound, and the larger of the
+#: two holds: the workers sign that many blocks a second of window before
+#: any warm rate is known, and the stream is never cut below it, so a
+#: cell holds a window at the hinted rate however slow its warm-up was.
 STREAM_MARGIN = 1.15
 #: blocks held to the OpenSSL reference and read back: the first and the
 #: last of the window and this many between
 SAMPLED_BETWEEN = 2
+#: what ``correct`` compares, each a count of faults with the limit 0 (the
+#: comparisons are exact).  A result carries every one beside its limit.
+COMPARED = (
+    "blocks_whose_filter_differs_from_reference",
+    "blocks_where_the_two_references_disagree",
+    "blocks_not_from_fused_device_path",
+    "keys_wrong_after_flush",
+    "keys_wrong_after_reopen",
+    "ledger_height_off_by",
+    "reopened_height_or_commit_hash_differs",
+    "fallback_counters_moved",
+    "programs_lowered_in_window",
+    "tx_not_committed_and_applied",
+    "blocks_without_one_verify_and_one_stage2_launch",
+    "blocks_whose_duplicate_hits_differ_from_stream",
+    "captures_without_device_work",
+)
 COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
@@ -194,13 +218,14 @@ def sample_blocks(first: int, last: int, seed: int) -> list:
 
 
 def check_reopened(ledger_dir: str, config: dict, end: dict, state,
-                   keys) -> list:
+                   keys, reference=None) -> list:
     """Reopen the ledger directory: same height, same commit hash, the
     same state on ``keys``."""
     from fabric_tpu.ledger.kvledger import KVLedger
 
-    from benchmark import reference
+    from benchmark import manifest
 
+    reference = reference or manifest.reference_of(config)
     lg = KVLedger(ledger_dir, enable_history=bool(config["history_db"]))
     try:
         out = []
@@ -253,20 +278,33 @@ class CellRun:
         if self.loop not in ("backlog", "paced"):
             raise RunFailed(f"traffic {self.cell['traffic']!r}: loop "
                             f"{self.loop!r}")
+        #: the configuration's nominal block size: what sizes the stream
+        #: before a block of it exists.  A block's txs are counted on
+        #: the block
         self.T = int(self.config["block_tx"])
+        self.base = int(self.config.get("first_block", 0))
+        self.reference = manifest.reference_of(self.config, root)
         self.warm = int(self.traffic["warmup_blocks"])
         if self.warm < 3:
             raise RunFailed("warmup_blocks under 3 gives no warm rate")
         if self.loop == "paced":
             self.rate = float(self.traffic["rate_tx_per_s"])
-            self.n_due = timeline.paced_blocks(seconds, self.rate, self.T)
         self.here = os.path.join(root, manifest.HERE)
         self.work = os.path.join(self.here, ".work",
                                  f"{workload}-{os.getpid()}")
         self.blocks, self.plans, self._prev = [], [], b""
+        self.block_txs: dict = {}   # block number → its txs
+        self.replayed: dict = {}    # block number → txids it replays
         self.roots: list = []
         self.rig = self.factory = self.loader = self.capture = None
         self.cleanup = contextlib.ExitStack()
+        self.problems: list = []
+        self.compared = dict.fromkeys(COMPARED, 0)
+
+    def fault(self, what: str, message: str, by: int = 1) -> None:
+        """One more of ``what`` (a name of ``COMPARED``) is wrong."""
+        self.compared[what] += by
+        self.problems.append(message)
 
     def lap(self, name: str) -> None:
         now = time.perf_counter()
@@ -283,30 +321,34 @@ class CellRun:
     # -- set-up --------------------------------------------------------------
 
     def prepare(self) -> None:
-        from benchmark import preload, stream
+        from benchmark import preload, stream, timeline
 
         shutil.rmtree(self.work, ignore_errors=True)
         os.makedirs(self.work)
         self.cleanup.callback(shutil.rmtree, self.work, ignore_errors=True)
-        # the state DB first: loading a million keys is the longest thing
-        # a child does, and the warm-up cannot start without it
+        # the preload first: loading a million keys (or ten million
+        # txids) is the longest thing a child does, and the warm-up
+        # cannot start without it
         self.loader = cf.ProcessPoolExecutor(
             1, mp_context=multiprocessing.get_context("spawn"))
         self.cleanup.callback(self.loader.shutdown, cancel_futures=True)
         loaded = self.loader.submit(
-            preload.ensure, os.path.join(self.here, ".cache"), self.config,
-            self.seed)
+            preload.ensure_named, self.root,
+            os.path.join(self.here, ".cache"), self.config, self.seed)
         self.network = stream.make_network(self.config)
         self.factory = stream.BlockFactory(
             self.root, self.config, self.traffic, self.seed, self.network,
             workers=self.workers)
         self.cleanup.callback(self.factory.close)
         if self.loop == "paced":
-            self.factory.extend(self.warm + self.n_due)
+            # one more than fall due at the nominal size: the block that
+            # shows the window is full
+            self.factory.extend(self.warm + 1 + timeline.paced_blocks(
+                self.seconds, self.rate, itertools.repeat(self.T)))
         else:
+            self.hint = float(self.traffic.get("stream_hint_blocks_per_s", 0))
             self.factory.extend(self.warm + RAMP_BLOCKS + math.ceil(
-                float(self.traffic.get("stream_hint_blocks_per_s", 0))
-                * self.seconds))
+                self.hint * self.seconds))
         self.lap("keys_and_children")
 
         from fabric_tpu import native
@@ -340,11 +382,15 @@ class CellRun:
         self.lap("import_jax_and_claim_device")
 
         self.ledger_dir = os.path.join(self.work, "ledger")
-        template, built = loaded.result()
+        template, built, start = loaded.result()
+        self.lap("preload_wait")
         shutil.copytree(template, self.ledger_dir)
-        self.lap("state_db_wait_and_copy")
-        self.parts["state_db_build_in_child"] = round(built, 2)
-        # set-up has just written the state DB once or twice over.  Left
+        self.lap("preload_copy")
+        self.parts["preload_build_in_child"] = round(built, 2)
+        for part, took in start.get("build_parts_s", {}).items():
+            self.parts[f"preload_build.{part}"] = round(took, 2)
+        self._prev = start["prev_hash"]
+        # set-up has just written the preload once or twice over.  Left
         # to the kernel, those pages are written back half a minute later,
         # a few seconds into the window.  Without this flush one block of
         # a paced run in six waited over a second longer for its state
@@ -377,39 +423,57 @@ class CellRun:
         self.compiles = self.cleanup.enter_context(CompileWatch())
 
     def assemble(self, upto: int) -> None:
-        """Chain the blocks up to ``upto`` as the workers deliver them."""
+        """Chain the stream's blocks up to the ``upto``-th as the workers
+        deliver them, from the block and the hash the preload left."""
         from benchmark import stream
 
-        for b in range(len(self.blocks), upto):
-            rows, envs = self.factory.take(b)
-            blk, self._prev = stream.chain(b, self._prev, envs)
+        for k in range(len(self.blocks), upto):
+            rows, envs, replayed = self.factory.take(k)
+            blk, self._prev = stream.chain(self.base + k, self._prev, envs)
             self.blocks.append(blk)
             self.plans.append(rows)
+            self.block_txs[self.base + k] = len(blk.data.data)
+            self.replayed[self.base + k] = replayed
 
     def warm_up(self) -> None:
         """The cell's own shapes, through the pipeline itself; then the
         rest of the stream, sized from the rate the warm blocks showed."""
-        from benchmark import harness
+        from benchmark import harness, timeline
 
         self.rig = harness.Rig(self.ledger_dir, self.config, self.mgr,
                                self.prov, annotate=self.annotate)
-        rig, warm = self.rig, self.warm
+        rig, warm, base = self.rig, self.warm, self.base
+        if rig.lg.height != base:
+            raise RunFailed(f"the ledger opens at height {rig.lg.height}, "
+                            f"the stream starts at block {base}")
         self.assemble(warm)
         self.lap("first_blocks_wait")
-        for b in range(warm):
-            rig.feed(self.blocks, b, warm)
+        for k in range(warm):
+            rig.feed(self.blocks, k, warm)
         rig.drain()
-        # block 0 compiled and block 1 was parsed under it: only the rest
-        # ran warm
-        self.warm_rate = (warm - 2) / (rig.ack[warm - 1] - rig.ack[1])
+        # the first block compiled and the second was parsed under it:
+        # only the rest ran warm
+        self.warm_rate = (warm - 2) / (rig.ack[base + warm - 1]
+                                       - rig.ack[base + 1])
         self.lap("warmup_blocks")
         say(f"warm-up: {warm} blocks, then {self.warm_rate:.2f} blocks/s; "
             f"jax {self.compiles.seconds()}, persistent cache "
             f"{self.compiles.hits} hits / {self.compiles.misses} misses")
         if self.loop == "backlog":
             self.factory.extend(warm + RAMP_BLOCKS + math.ceil(
-                STREAM_MARGIN * self.warm_rate * self.seconds))
+                max(self.hint, STREAM_MARGIN * self.warm_rate)
+                * self.seconds))
         self.assemble(len(self.factory))
+        if self.loop == "paced":
+            # blocks smaller than the nominal size: more of them fall due
+            while True:
+                sizes = [len(b.data.data) for b in self.blocks[warm:]]
+                self.n_due = timeline.paced_blocks(self.seconds, self.rate,
+                                                   sizes)
+                if self.n_due < len(sizes):
+                    break
+                self.factory.extend(len(self.factory) + warm)
+                self.assemble(len(self.factory))
         self.factory.close()
         self.loader.shutdown()
         # the stream's millions of small objects must not be walked by a
@@ -420,7 +484,9 @@ class CellRun:
         self.lap("rest_of_stream_wait")
         self.flushed.join()
         self.lap("flush_of_setup_writes_wait")
-        say(f"stream: {len(self.blocks)} blocks of {self.T} tx")
+        sizes = set(self.block_txs.values())
+        say(f"stream: blocks {base}..{base + len(self.blocks) - 1} of "
+            f"{min(sizes)}..{max(sizes)} tx")
 
     # -- the window ----------------------------------------------------------
 
@@ -440,7 +506,7 @@ class CellRun:
                 on_open=lambda _t: opened.set())
         else:
             ran = harness.run_paced(
-                rig, self.blocks, self.warm, self.n_due, self.rate, self.T,
+                rig, self.blocks, self.warm, self.n_due, self.rate,
                 PeerChannel.PIPELINE_IDLE_FLUSH_S,
                 on_open=lambda _t: opened.set())
         self.t_end = time.perf_counter()
@@ -463,7 +529,9 @@ class CellRun:
             self.t_close = max(done, default=self.t_open + self.seconds)
             if len(done) == self.n_due:
                 self.latencies = timeline.tx_latencies_ms(
-                    self.t_open, done, self.rate, self.T)
+                    self.t_open, done, self.rate,
+                    [self.block_txs[b] for b in range(self.first,
+                                                      self.last + 1)])
         self.parts["ramp_to_window_open"] = round(self.t_open - self._mark, 2)
         say(f"window: blocks {self.first}..{self.last} in "
             f"{self.t_close - self.t_open:.2f} s; "
@@ -475,48 +543,56 @@ class CellRun:
     def check(self) -> None:
         from fabric_tpu.protos import transaction_pb2
 
-        from benchmark import reference
+        from benchmark import spans
 
-        rig, config, T = self.rig, self.config, self.T
+        rig, config, reference = self.rig, self.config, self.reference
         first, last, submitted = self.first, self.last, self.submitted
+        base, txs = self.base, self.block_txs
         VALID = transaction_pb2.TxValidationCode.VALID
-        problems = self.problems = []
-        self.attempted = (submitted - first) * T
-        self.failed = sum(T for b in range(first, submitted)
+        problems, fault = self.problems, self.fault
+        self.attempted = sum(txs[b] for b in range(first, submitted))
+        self.failed = sum(txs[b] for b in range(first, submitted)
                           if b not in self.ack or b not in self.applied)
         state = reference.RefState(config)
         sampled = sample_blocks(first, last, self.seed)
         touched: set = set()
         self.n_valid = 0
-        for b in range(submitted):
-            want, done = reference.plan_codes(self.plans[b], b, state)
+        for b in range(base, submitted):
+            plan, blk = self.plans[b - base], self.blocks[b - base]
+            want, done = reference.plan_codes(plan, b, state)
             if b in sampled:
                 full, full_done = reference.openssl_codes(
-                    self.blocks[b], self.mgr, self.prov, state,
-                    config["chaincode"])
+                    blk, self.mgr, self.prov, state, config["chaincode"])
                 if full != want or full_done != done:
-                    problems.append(f"block {b}: the OpenSSL reference and "
-                                    "the plan-level reference disagree")
-                for _kind, _reads, writes in self.plans[b]:
+                    fault("blocks_where_the_two_references_disagree",
+                          f"block {b}: the OpenSSL reference and the "
+                          "plan-level reference disagree")
+                for _kind, _reads, writes in plan:
                     touched.update(k for k, _v in writes)
             got = rig.filters.get(b)
             if got is not None:
                 if got != want:
-                    diff = [i for i in range(T) if got[i] != want[i]][:8]
-                    problems.append(f"block {b}: tx filter differs from the "
-                                    f"reference at {diff}")
+                    diff = ([i for i in range(min(len(got), len(want)))
+                             if got[i] != want[i]][:8]
+                            or f"{len(got)} verdicts for {len(want)} txs")
+                    fault("blocks_whose_filter_differs_from_reference",
+                          f"block {b}: tx filter differs from the reference "
+                          f"at {diff}")
                 if first <= b <= last:
                     self.n_valid += got.count(VALID)
                 if not rig.fused.get(b):
-                    problems.append(f"block {b}: verdicts not from the "
-                                    "fused device path")
-                if rig.devices.get(b) != [self.platform]:
-                    problems.append(f"block {b}: verify output on "
-                                    f"{rig.devices.get(b)}")
-            state.commit(done)
+                    fault("blocks_not_from_fused_device_path",
+                          f"block {b}: verdicts not from the fused device "
+                          "path")
+                elif rig.devices.get(b) != [self.platform]:
+                    fault("blocks_not_from_fused_device_path",
+                          f"block {b}: verify output on "
+                          f"{rig.devices.get(b)}")
+            state.commit(done, reference.block_txids(blk))
         keys = sorted(touched)
-        problems += [f"after flush: {m}" for m in reference.state_mismatches(
-            rig.lg.state, config["chaincode"], state, keys)[:8]]
+        for m in reference.state_mismatches(
+                rig.lg.state, config["chaincode"], state, keys):
+            fault("keys_wrong_after_flush", f"after flush: {m}")
         self.launch_rows = []
         if self.trace:
             self.launch_rows = [r for r in self.led.rows()
@@ -525,27 +601,44 @@ class CellRun:
                 mine = [r["kernel"] for r in self.launch_rows
                         if r["block"] == str(b)]
                 if mine.count("verify") != 1 or mine.count("stage2") != 1:
-                    problems.append(f"block {b}: launches {mine}, want one "
-                                    "verify and one stage2")
+                    fault("blocks_without_one_verify_and_one_stage2_launch",
+                          f"block {b}: launches {mine}, want one verify and "
+                          "one stage2")
+            # the duplicate check found the stream's replays and no other
+            hits = spans.attr_per_block(self.roots, "dup_txid", "hits")
+            for b in range(first, last + 1):
+                if b in hits and hits[b] != self.replayed[b]:
+                    fault("blocks_whose_duplicate_hits_differ_from_stream",
+                          f"block {b}: the duplicate check hit {hits[b]} "
+                          f"txs, the stream replays {self.replayed[b]}")
         self.depth = rig.pipe.depth
         end = rig.close()
         self.rig = None
         if end["height"] != submitted:
-            problems.append(f"ledger height {end['height']} != {submitted} "
-                            "blocks submitted")
-        problems += check_reopened(self.ledger_dir, config, end, state, keys)
+            fault("ledger_height_off_by",
+                  f"ledger height {end['height']} != {submitted}: first "
+                  f"block {base} and {submitted - base} blocks submitted",
+                  by=abs(end["height"] - submitted))
+        for m in check_reopened(self.ledger_dir, config, end, state, keys,
+                                reference):
+            fault("keys_wrong_after_reopen" if m.startswith("after reopen")
+                  else "reopened_height_or_commit_hash_differs", m)
         moved = {n: counter_total(n) - v
                  for n, v in self.zero_before.items()}
         if any(moved.values()):
-            problems.append(f"fallback counters moved: {moved}")
+            fault("fallback_counters_moved",
+                  f"fallback counters moved: {moved}",
+                  by=sum(1 for v in moved.values() if v))
         lowered = self.compiles.lowered_between(self.t_open, self.t_close)
         if lowered:
-            problems.append(f"{lowered} programs were lowered inside the "
-                            "window")
+            fault("programs_lowered_in_window",
+                  f"{lowered} programs were lowered inside the window",
+                  by=lowered)
         if self.failed:
-            problems.append(f"{self.failed} tx were not committed and "
-                            "applied")
-        say(f"checked {submitted} blocks against the reference, "
+            fault("tx_not_committed_and_applied",
+                  f"{self.failed} tx were not committed and applied",
+                  by=self.failed)
+        say(f"checked {submitted - base} blocks against the reference, "
             f"{len(sampled)} of them with OpenSSL and {len(keys)} keys "
             f"read back twice, in {time.perf_counter() - self.t_end:.1f} s: "
             f"{problems[:6] or 'ok'}")
@@ -573,6 +666,7 @@ class CellRun:
         obs = types.SimpleNamespace(
             cell=self.workload, config=self.config, traffic=self.traffic,
             loop=self.loop, seconds=self.seconds, block_tx=self.T,
+            block_txs=[self.block_txs[b] for b in range(first, last + 1)],
             depth=self.depth, device_kind=device["kind"],
             t_start=self.t_start, t_open=self.t_open, t_close=self.t_close,
             first=first, last=last, ack=self.ack, applied=self.applied,
@@ -606,8 +700,9 @@ class CellRun:
             device["busy_s"] = trace_reduce.busy_seconds(device_trace, t0, t1)
             device["window_s"] = t1 - t0
             if device["busy_s"] <= 0:
-                self.problems.append("no operation ran on the device "
-                                     "inside the capture")
+                self.fault("captures_without_device_work",
+                           "no operation ran on the device inside the "
+                           "capture")
             host = {label: [(a + offset, b + offset) for a, b in ivals]
                     for label, ivals in
                     spans.self_intervals(self.roots).items()}
@@ -616,7 +711,7 @@ class CellRun:
                 "idle_gaps": trace_reduce.attribute_gaps(
                     trace_reduce.idle_gaps(device_trace, t0, t1), host),
             }
-        result["correct"] = not self.problems
+        result["correct"] = not any(self.compared.values())
         # beside what the driver reads: why a run was not correct, where
         # set-up's seconds went, and when each block after the ramp was
         # applied (what any other window length would have given)
@@ -638,6 +733,9 @@ class CellRun:
                     obs.roots, [name])]
                 for name in ("prefetch", "launch", "state_fill", "finish",
                              "commit")}
+        # last: each number ``correct`` compared, beside its limit
+        result["compared"] = {name: {"value": n, "limit": 0}
+                              for name, n in self.compared.items()}
         return result
 
 
@@ -677,6 +775,10 @@ def main(argv=None) -> int:
         print(f"benchmark: {e}", file=sys.stderr)
         return 2
     print(json.dumps(result), flush=True)
+    for name, c in result["compared"].items():
+        print(f"compared {name} {c['value']} limit {c['limit']}",
+              file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

@@ -12,9 +12,11 @@ from __future__ import annotations
 
 from benchmark.timeline import median
 
-#: span names that are not host work (``observe/overlap.py`` NON_HOST)
+#: span names that are not host work (``observe/overlap.py`` NON_HOST):
+#: containers and waits, ``feed_wait`` (the caller waiting for the next
+#: block of the stream) among them
 NON_HOST = {"block", "finish", "device_wait", "commit_wait",
-            "prefetch_wait", "queue_wait"}
+            "prefetch_wait", "queue_wait", "feed_wait"}
 
 
 def walk(root):
@@ -52,6 +54,18 @@ def self_ms_per_block(roots, name: str) -> list:
                 total += (sp.t1 - sp.t0) - overlap_len(kids,
                                                        [(sp.t0, sp.t1)])
         out.append(total * 1000.0)
+    return out
+
+
+def attr_per_block(roots, name: str, attr: str) -> dict:
+    """block → the sum of ``attrs[attr]`` over its spans called ``name``,
+    for the blocks that have such a span with such an attribute."""
+    out = {}
+    for r in roots:
+        got = [sp.attrs[attr] for sp in walk(r)
+               if sp.name == name and attr in sp.attrs]
+        if got:
+            out[block_of(r)] = sum(got)
     return out
 
 
@@ -143,7 +157,7 @@ def _on_device(sp) -> bool:
 
 def _role(thread: str) -> str:
     """A thread's role without its pool's running number."""
-    for role in ("prefetch", "committer"):
+    for role in ("prefetch", "committer", "applier"):
         if role in thread:
             return role
     return "caller"

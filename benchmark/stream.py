@@ -2,6 +2,11 @@
 the envelope of one planned tx, and the pool of worker processes that
 signs blocks while the parent imports ``jax`` and compiles.
 
+A tx's nonce, and so its txid (``sha256(nonce ‖ creator)``), is a function
+of (seed, block, position): any worker can make any block, and a tx that
+replays an earlier one (``duplicate_txid``) needs only that tx's block and
+position to carry its txid.  The creator is the run's one client.
+
 Nothing here imports ``jax``: the workers must never open the chip, and
 they start faster without it.  Signing a 1000-tx block (three ECDSA
 signatures and a dozen protobuf messages per tx) takes a quarter of a
@@ -12,6 +17,7 @@ the chip's host while the others are busy, which is why it is farmed out.
 from __future__ import annotations
 
 import concurrent.futures as cf
+import hashlib
 import multiprocessing
 import os
 
@@ -54,16 +60,41 @@ def msp_manager(network: dict):
     })
 
 
-class _Unsigned:
-    """The client as the creator of a proposal: a block carries the
-    proposal and never the ``SignedProposal`` around it, so that
-    signature (a quarter of the signing) is not made."""
+def nonce(seed: int, block_num: int, position: int) -> bytes:
+    """The 24 bytes tx ``position`` of block ``block_num`` carries as its
+    nonce (``protoutil.random_nonce`` draws as many from the OS)."""
+    return hashlib.sha256(
+        f"nonce|{int(seed)}|{int(block_num)}|{int(position)}".encode()
+    ).digest()[:24]
 
-    def __init__(self, signer):
-        self.serialized = signer.serialized
 
-    def sign(self, _message: bytes) -> bytes:
-        return b""
+def proposal(creator: bytes, channel: str, chaincode: str, tx_nonce: bytes):
+    """The proposal of one invoke, as ``txassembly.create_signed_proposal``
+    builds it but for the nonce, which is handed in, and the
+    ``SignedProposal`` around it, which no block carries (that signature,
+    a quarter of the signing, is not made)."""
+    from fabric_tpu import protoutil as pu
+    from fabric_tpu.protos import common_pb2, proposal_pb2
+
+    ext = proposal_pb2.ChaincodeHeaderExtension()
+    ext.chaincode_id.name = chaincode
+    ch = pu.make_channel_header(
+        common_pb2.HeaderType.ENDORSER_TRANSACTION, channel,
+        tx_id=pu.compute_tx_id(tx_nonce, creator),
+        extension=ext.SerializeToString())
+    sh = pu.make_signature_header(creator, tx_nonce)
+    spec = proposal_pb2.ChaincodeInvocationSpec()
+    spec.chaincode_spec.type = proposal_pb2.ChaincodeSpec.EXTERNAL
+    spec.chaincode_spec.chaincode_id.name = chaincode
+    spec.chaincode_spec.input.args.append(b"invoke")
+    cpp = proposal_pb2.ChaincodeProposalPayload(
+        input=spec.SerializeToString())
+    return proposal_pb2.Proposal(
+        header=common_pb2.Header(
+            channel_header=ch.SerializeToString(),
+            signature_header=sh.SerializeToString(),
+        ).SerializeToString(),
+        payload=cpp.SerializeToString())
 
 
 def _spoil(sig: bytes) -> bytes:
@@ -71,19 +102,22 @@ def _spoil(sig: bytes) -> bytes:
     return sig[:-4] + bytes(4)
 
 
-def envelopes(rows, config: dict, endorsers, client) -> list:
+def envelopes(rows, config: dict, endorsers, client, seed: int,
+              block_num: int, replays=None) -> list:
     """One block's plan → its serialized tx envelopes.  Tx i is endorsed
-    by peers i and i+1 (mod the orgs), ``endorsements_per_tx`` of them."""
+    by peers i and i+1 (mod the orgs), ``endorsements_per_tx`` of them.
+    ``replays`` maps a position to the (block, position) whose nonce, and
+    so whose txid, that tx carries instead of its own."""
     from fabric_tpu.ledger.rwset import TxRWSet
     from fabric_tpu.peer import txassembly as txa
 
     channel, cc = config["channel"], config["chaincode"]
     n_end, n_org = int(config["endorsements_per_tx"]), len(endorsers)
-    proposer = _Unsigned(client)
+    creator, replays = client.serialized, replays or {}
     out = []
     for i, (kind, reads, writes) in enumerate(rows):
-        _, _, prop = txa.create_signed_proposal(proposer, channel, cc,
-                                                [b"invoke"])
+        prop = proposal(creator, channel, cc,
+                        nonce(seed, *replays.get(i, (block_num, i))))
         tx = TxRWSet()
         ns = tx.ns_rwset(cc)
         for key, ver in reads:
@@ -128,16 +162,25 @@ def _init_worker(root, generator, config, traffic, seed, network):
         sys.path.insert(0, root)
     from benchmark import manifest
 
-    _worker["config"] = config
+    _worker["config"], _worker["seed"] = config, seed
     _worker["planner"] = manifest.load_module(
-        "generators", generator, root).planner(config, traffic, seed)
+        "generators", generator, root).planner(
+            config, traffic, seed, manifest.reference_of(config, root))
     _worker["endorsers"], _worker["client"] = signers(network)
 
 
-def _make_block(b: int) -> tuple:
-    rows = _worker["planner"].rows(b)
-    return rows, envelopes(rows, _worker["config"], _worker["endorsers"],
-                           _worker["client"])
+def _make_block(k: int) -> tuple:
+    """The k-th block of the stream → (rows, envelopes, how many of its
+    txs carry the txid of an earlier one)."""
+    planner, config = _worker["planner"], _worker["config"]
+    b = int(config.get("first_block", 0)) + k
+    rows = planner.rows(b)
+    # a generator that replays txids says which, for the block it
+    # planned last
+    replays = planner.replays(b) if hasattr(planner, "replays") else None
+    return (rows, envelopes(rows, config, _worker["endorsers"],
+                            _worker["client"], _worker["seed"], b, replays),
+            len(replays or ()))
 
 
 def default_workers() -> int:
@@ -149,7 +192,8 @@ def default_workers() -> int:
 class BlockFactory:
     """Signs blocks in worker processes, in block order.  ``extend(n)``
     asks for the stream to reach n blocks (or cuts what has not started
-    back to n); ``take(b)`` waits for block b's plan and envelopes."""
+    back to n); ``take(k)`` waits for the plan and envelopes of the
+    stream's k-th block, which is block ``first_block + k``."""
 
     def __init__(self, root, config, traffic, seed, network,
                  workers: int | None = None):
@@ -171,9 +215,11 @@ class BlockFactory:
     def __len__(self) -> int:
         return len(self._futs)
 
-    def take(self, b: int) -> tuple:
-        """→ (rows, envelopes) of block b."""
-        return self._futs[b].result()
+    def take(self, k: int) -> tuple:
+        """→ (rows, envelopes, txids replayed) of the stream's k-th
+        block; the last is what the system's duplicate check has to find
+        there."""
+        return self._futs[k].result()
 
     def close(self) -> None:
         self._pool.shutdown(wait=True, cancel_futures=True)

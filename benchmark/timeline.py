@@ -26,30 +26,39 @@ def median(values) -> float:
 # -- open loop ---------------------------------------------------------------
 
 
-def paced_blocks(seconds: float, rate_tx_per_s: float, block_tx: int) -> int:
-    """How many full blocks fall due inside a window of ``seconds``: block
-    k (from 0) is released when its last tx is due, (k+1)·T/R after the
-    window opens."""
-    return int(math.floor(seconds * rate_tx_per_s / block_tx + 1e-9))
+def paced_blocks(seconds: float, rate_tx_per_s: float, block_txs) -> int:
+    """How many of the stream's blocks fall due inside a window of
+    ``seconds``: block k (from 0) is released when its last tx is due,
+    ``sum(block_txs[:k+1])/R`` after the window opens.  ``block_txs``
+    gives the blocks' tx counts in order (any iterable: an endless one
+    of the nominal size says how many blocks to ask a generator for)."""
+    n = total = 0
+    for txs in block_txs:
+        total += txs
+        if total > seconds * rate_tx_per_s + 1e-9 * txs:
+            break
+        n += 1
+    return n
 
 
-def block_due(t0: float, k: int, rate_tx_per_s: float, block_tx: int) -> float:
-    """When block k's last tx is due: the moment a block cutter with
-    ``MaxMessageCount = block_tx`` would cut it."""
-    return t0 + (k + 1) * block_tx / rate_tx_per_s
+def block_due(t0: float, k: int, rate_tx_per_s: float, block_txs) -> float:
+    """When block k's last tx is due: the moment a block cutter that
+    cuts on the message count alone would cut it."""
+    return t0 + sum(block_txs[:k + 1]) / rate_tx_per_s
 
 
 def tx_latencies_ms(t0: float, applied, rate_tx_per_s: float,
-                    block_tx: int) -> np.ndarray:
+                    block_txs) -> np.ndarray:
     """Per tx, from the moment it was due to arrive to the moment its
     block's state was applied.  Tx n (from 1) is due at ``t0 + n/R``
     whatever the generator or the system did since: a stalled block makes
     every tx due behind it wait, and that wait is counted.  ``applied[k]``
-    is block k's apply time."""
+    is block k's apply time and ``block_txs[k]`` its tx count."""
     applied = np.asarray(applied, np.float64)
-    n = np.arange(1, len(applied) * block_tx + 1, dtype=np.float64)
+    counts = np.asarray(block_txs[:len(applied)], np.int64)
+    n = np.arange(1, int(counts.sum()) + 1, dtype=np.float64)
     due = t0 + n / rate_tx_per_s
-    return (np.repeat(applied, block_tx) - due) * 1000.0
+    return (np.repeat(applied, counts) - due) * 1000.0
 
 
 # -- closed loop -------------------------------------------------------------
@@ -68,6 +77,6 @@ def backlog_window(t_open: float, seconds: float, applied) -> tuple:
     return inside[-1], len(inside)
 
 
-def tx_per_s(t_open: float, t_close: float, n_blocks: int,
-             block_tx: int) -> float:
-    return n_blocks * block_tx / (t_close - t_open)
+def tx_per_s(t_open: float, t_close: float, block_txs) -> float:
+    """``block_txs``: the tx counts of the blocks applied in the window."""
+    return sum(block_txs) / (t_close - t_open)

@@ -7,6 +7,7 @@ import re
 
 import pytest
 
+from bench_tiny import listed
 from benchmark import manifest, roofline
 
 REPO = manifest.ROOT
@@ -15,9 +16,12 @@ LAYER = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-@pytest.fixture(scope="module")
-def man():
-    return manifest.load()
+@pytest.fixture(scope="module", params=["as_committed", "with_the_cells_"
+                                        "measured_and_not_listed"])
+def man(request):
+    """The manifest, and what it becomes when the cells under
+    ``benchmark/cells/*.json`` are listed: both must pass."""
+    return manifest.load() if request.param == "as_committed" else listed()
 
 
 def test_top_level_keys_and_limits(man):

@@ -9,7 +9,7 @@ import types
 
 import pytest
 
-from bench_tiny import tiny_cell
+from bench_tiny import listed, tiny_cell
 from benchmark import harness, manifest, preload, spans, stream
 from fabric_tpu.observe import Span
 
@@ -107,7 +107,63 @@ def test_gather_that_meets_no_write_reads_zero_not_nothing():
     assert read("state_gather_ms", made_up(False)) == pytest.approx(150.0)
 
 
-@pytest.mark.parametrize("name", BACKLOG + PACED)
+def _chain(index_ms, hits=None):
+    """One tree a block, from block 40: ``commit.index`` of
+    ``index_ms[k]`` ms, ``dup_txid`` with ``hits[k]`` hits."""
+    out = []
+    for k, ms in enumerate(index_ms):
+        at = 2.0 * k
+        out.append(sp("block", at, at + 1.4, block=40 + k, kids=[
+            sp("launch", at + 0.1, at + 0.5, kids=[
+                sp("dup_txid", at + 0.1, at + 0.2, lookups=20,
+                   hits=hits[k] if hits else 0)]),
+            sp("commit", at + 1.0, at + 1.4, COMMITTER, kids=[
+                sp("commit.index", at + 1.0, at + 1.0 + ms / 1000.0,
+                   COMMITTER, txids=20)])]))
+    return out
+
+
+def test_index_growth_is_the_last_fifth_over_the_first():
+    # eleven blocks: fifths of two; means 25 and 155
+    ms = [20, 30, 60, 80, 100, 110, 120, 130, 140, 150, 160]
+    assert read("commit_index_growth", _chain(ms)) == pytest.approx(155 / 25)
+    # in any order the tracer finished them
+    assert read("commit_index_growth", _chain(ms)[::-1]) == pytest.approx(
+        155 / 25)
+    assert read("commit_index_growth", _chain([50] * 10)) == pytest.approx(1.0)
+    # a flat index that pays a checkpoint every second block is flat,
+    # whichever of the two values a fifth holds once more
+    assert read("commit_index_growth", _chain([115, 223] * 8 + [115])) \
+        == pytest.approx((115 * 2 + 223) / (115 + 223 + 115))
+    # under five blocks there is no fifth
+    assert read("commit_index_growth", _chain(ms[:4])) is None
+
+
+def test_duplicate_hits_are_the_median_of_the_spans_counts():
+    assert read("dup_txid_hits", _chain([50] * 5, [33, 34, 33, 34, 33])) == 33
+    assert read("dup_txid_hits", _chain([50] * 4)) == 0
+    assert spans.attr_per_block(_chain([50] * 3, [1, 0, 2]), "dup_txid",
+                                "hits") == {40: 1, 41: 0, 42: 2}
+
+
+def test_the_applier_is_a_role_and_feed_wait_is_no_host_work():
+    roots = made_up(overlapping=True)
+    own = spans.self_intervals(roots)
+    assert "applier:apply.write" in own and "applier:apply.history" in own
+    assert not any(k.startswith("caller:apply") for k in own)
+    assert "committer:commit.index" in own and "caller:dup_txid" in own
+    # a caller that waits for the stream covers no block's device_wait
+    wait = sp("block", 0.0, 1.0, block=1, kids=[
+        sp("finish", 0.2, 0.6, kids=[sp("device_wait", 0.2, 0.6)])])
+    fed = sp("block", 0.6, 1.5, block=2, kids=[
+        sp("feed_wait", 0.1, 0.6), sp("launch", 0.6, 0.8)])
+    assert spans.overlap_coverage([wait, fed]) == 0.0
+    fed.children[0].name = "prefetch"
+    assert spans.overlap_coverage([wait, fed]) == pytest.approx(1.0)
+
+
+@pytest.mark.parametrize("name", BACKLOG + PACED + ["commit_index_growth",
+                                                    "dup_txid_hits"])
 def test_reader_finds_nothing_in_a_program_without_the_spans(name):
     """The parent commit's trees: the reader returns None and does not
     raise, so the result line leaves the metric out."""
@@ -118,18 +174,25 @@ def test_reader_finds_nothing_in_a_program_without_the_spans(name):
     assert read(name, []) is None
 
 
-def test_each_new_reader_is_in_the_manifest_for_its_cells():
-    man = manifest.load()
+@pytest.mark.parametrize("with_unlisted", [False, True])
+def test_each_new_reader_is_in_the_manifest_for_its_cells(with_unlisted):
+    """As committed, and with ``long_chain_backlog`` (measured, not
+    listed: ``benchmark/cells/long_chain_backlog.json``) listed."""
+    man = listed() if with_unlisted else manifest.load()
+    cells = ["rw_backlog", "insert_backlog", "zipf_backlog"] + (
+        ["long_chain_backlog"] if with_unlisted else [])
     by = {m["name"]: m for m in man["per_layer"]}
-    for name in BACKLOG:
-        assert by[name]["workloads"] == ["rw_backlog", "insert_backlog",
-                                         "zipf_backlog"]
+    for name in BACKLOG + ["commit_index_growth"]:
+        assert by[name]["workloads"] == cells
         assert by[name]["moves"] == "commit_tx_per_s"
     for name in PACED:
         assert by[name]["workloads"] == ["rw_paced"]
         assert by[name]["moves"] == "tx_commit_p50_ms"
     # appended: what was there is still first, in its order
-    assert [m["name"] for m in man["per_layer"]][-13:] == BACKLOG + PACED
+    new = BACKLOG + PACED + ["commit_index_growth"] + (
+        ["dup_txid_hits"] if with_unlisted else [])
+    assert [m["name"] for m in man["per_layer"]][-len(new):] == new
+    assert ("dup_txid_hits" in by) == with_unlisted
 
 
 # ---------------------------------------------------------------------------
@@ -153,13 +216,14 @@ def rig_roots(tmp_path_factory):
     net = stream.make_network(config)
     endorsers, client = stream.signers(net)
     planner = manifest.load_module(
-        "generators", traffic["generator"]).planner(config, traffic, 11)
+        "generators", traffic["generator"]).planner(
+            config, traffic, 11, manifest.reference_of(config))
     blocks, prev = [], b""
     for b in range(N_BLOCKS):
         blk, prev = stream.chain(b, prev, stream.envelopes(
-            planner.rows(b), config, endorsers, client))
+            planner.rows(b), config, endorsers, client, 11, b))
         blocks.append(blk)
-    template, _built = preload.ensure(str(tmp / "cache"), config, 11)
+    template, _built, _start = preload.ensure(str(tmp / "cache"), config, 11)
     ledger_dir = str(tmp / "ledger")
     shutil.copytree(template, ledger_dir)
     prov = PolicyProvider({config["chaincode"]: NamespaceInfo(

@@ -106,13 +106,23 @@ def test_gaps_are_attributed_to_what_the_host_was_doing():
 def test_kernel_readers_and_the_roofline_share(capture):
     obs = types.SimpleNamespace(
         device_trace=capture, capture_window=(T0, T1, 0.0), block_tx=1000,
-        config={"signatures_per_tx": 3}, device_kind="TPU v5 lite")
+        config={"signatures_per_tx": 3}, device_kind="TPU v5 lite",
+        launch_rows=[{"block": "7", "kernel": "verify", "lanes": 3000},
+                     {"block": "7", "kernel": "stage2", "lanes": 1000},
+                     {"block": "8", "kernel": "verify", "lanes": 3000}])
     read = lambda name: manifest.load_module("layer_metrics", name).read(obs)
     assert read("verify_kernel_ms") == pytest.approx(26.904942)
     assert read("stage2_kernel_ms") == pytest.approx((95605 + 95056) / 2e6)
     # 3000 signatures x 5265 muls x 12,696 FLOP at 197 TFLOP/s = 1.0179 ms
     assert read("verify_roofline") == pytest.approx(
         1.0179 / 26.904942 * 100, rel=1e-4)
+    # the lanes are the block's own: a block of 100 txs needs a tenth of
+    # the operations, whatever the configuration's nominal size
+    obs.launch_rows = [{"block": "7", "kernel": "verify", "lanes": 300}]
+    assert read("verify_roofline") == pytest.approx(
+        0.10179 / 26.904942 * 100, rel=1e-4)
+    obs.launch_rows = []
+    assert read("verify_roofline") is None
     obs.device_trace = None
     assert read("verify_kernel_ms") is None and read("verify_roofline") is None
 
@@ -170,7 +180,8 @@ def test_span_arithmetic_on_made_up_trees():
 def test_span_readers_take_the_median_over_blocks():
     roots = [_block(k, 10.0 + 0.41 * k) for k in range(3)]
     obs = types.SimpleNamespace(
-        roots=roots, depth=2, first=0, last=2, block_tx=1000, n_valid=2700,
+        roots=roots, depth=2, first=0, last=2, block_tx=1000,
+        block_txs=[1000, 1000, 1000], n_valid=2700,
         ack={0: 1.0, 1: 2.0, 2: 3.0}, applied={0: 1.1, 1: 2.3, 2: 3.2},
         launch_rows=[{"block": "0", "h2d_bytes": 1929216},
                      {"block": "0", "h2d_bytes": 12288},
