@@ -102,16 +102,20 @@ class LaunchRecord:
 
     __slots__ = ("ledger", "kernel", "lane", "key", "lanes", "compiled",
                  "sharded", "t0", "t1", "t_sync0", "h2d_bytes", "h2d_s",
-                 "d2h_bytes", "_parent", "_ref", "_done",
+                 "d2h_bytes", "shape", "_parent", "_ref", "_done",
                  "_dispatch_marked", "_pins")
 
     def __init__(self, ledger: "LaunchLedger", kernel: str, lane: str,
                  compiled: bool, lanes: int, parent, ref,
-                 sharded: bool | None = None):
+                 sharded: bool | None = None, shape: dict | None = None):
         self.ledger = ledger
         self.kernel = kernel
         self.lane = lane
         self.lanes = int(lanes)
+        #: what the row says of the launch's size beside ``lanes``
+        #: (verify: ``bucket``, the padded lanes; stage2: ``txs``, the
+        #: block's real txs under its T bucket)
+        self.shape = shape
         self.compiled = bool(compiled)
         #: None = no mesh configured (untagged row); True/False = a
         #: mesh WAS configured and the dispatch did / did not shard —
@@ -270,14 +274,18 @@ class LaunchLedger:
     def launch(self, kernel: str, *, key=None, lane: str = "dev",
                lanes: int = 0, compiled: bool | None = None,
                h2d_bytes: int = 0,
-               sharded: bool | None = None) -> LaunchRecord:
+               sharded: bool | None = None,
+               shape: dict | None = None) -> LaunchRecord:
         """Open a record for one device dispatch.  ``compiled`` is the
         caller's exact program-cache verdict where it owns the cache;
         None infers miss-on-first-sight of ``(kernel, key)``.
         ``sharded`` tags the row when a device mesh is configured:
         False marks a dispatch whose operands fell back to unsharded
         (ragged axis 0 — see parallel.mesh ``shard``), the
-        silent-unparallel case /launches must surface.  The tracer's
+        silent-unparallel case /launches must surface.  ``shape``:
+        further size columns of the row (``bucket`` beside a verify
+        launch's real ``lanes``, ``txs`` beside a stage-2 launch's T
+        bucket).  The tracer's
         thread-current span is captured as the parent the device
         child spans land under (None off traced paths)."""
         if compiled is None:
@@ -294,7 +302,7 @@ class LaunchLedger:
                 ns = a.get("ns", "")
                 ref = f"{ns}:{blk}" if ns else str(blk)
         rec = LaunchRecord(self, kernel, lane, compiled, lanes,
-                           parent, ref, sharded=sharded)
+                           parent, ref, sharded=sharded, shape=shape)
         if h2d_bytes:
             rec.note_h2d(h2d_bytes)
         return rec
@@ -336,6 +344,8 @@ class LaunchLedger:
                 "wall_ms": (None if f is None else
                             round((rec.h2d_s + f - t0) * 1000.0, 4)),
             }
+            if rec.shape:
+                row.update(rec.shape)
             if rec.sharded is not None:
                 row["sharded"] = rec.sharded
             if rec._ref is not None:

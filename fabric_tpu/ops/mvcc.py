@@ -314,7 +314,7 @@ def prepare_block_static(txs: list[TxRWSet], bucketed: bool = False) -> StaticBl
     of similar shape share one compiled executable (padding rows carry
     key id −1 and are inert).
     """
-    from fabric_tpu.utils.batching import next_pow2
+    from fabric_tpu.utils.batching import block_shapes
 
     universe = set()
     read_key_set = set()
@@ -336,8 +336,8 @@ def prepare_block_static(txs: list[TxRWSet], bucketed: bool = False) -> StaticBl
     W = max(1, max((len(t.writes) for t in txs), default=1))
     Q = max(1, max((len(t.range_reads) for t in txs), default=1))
     if bucketed:
-        T = max(16, next_pow2(T))
-        R, W, Q = next_pow2(R), next_pow2(W), next_pow2(Q)
+        sh = block_shapes(txs=T, reads=R, writes=W, ranges=Q)
+        T, (R, W, Q) = sh.txs, sh.dims
 
     read_keys = np.full((T, R), -1, np.int32)
     read_present = np.zeros((T, R), bool)
@@ -427,14 +427,14 @@ def prepare_block_from_flat(n_txs: int, rwp, composite_keys: list) -> VecStaticB
     """Native mvcc_prep flat arrays → device-static arrays with pure
     numpy scatters (no per-read Python loop).  ``composite_keys``:
     [n_keys] mvcc-form keys for state lookups."""
-    from fabric_tpu.utils.batching import next_pow2
+    from fabric_tpu.utils.batching import block_shapes
 
-    Tb = max(16, next_pow2(max(1, n_txs)))
     nr, nw = rwp.n_reads, rwp.n_writes
     rc = rwp.r_count[:n_txs]
     wc = rwp.w_count[:n_txs]
-    R = next_pow2(max(1, int(rc.max()) if n_txs else 1))
-    W = next_pow2(max(1, int(wc.max()) if n_txs else 1))
+    sh = block_shapes(txs=n_txs, reads=int(rc.max()) if n_txs else 0,
+                      writes=int(wc.max()) if n_txs else 0)
+    Tb, (R, W, _q) = sh.txs, sh.dims
 
     read_keys = np.full((Tb, R), -1, np.int32)
     read_present = np.zeros((Tb, R), bool)

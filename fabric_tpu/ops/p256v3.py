@@ -40,7 +40,7 @@ from fabric_tpu import faults as _faults
 from fabric_tpu.crypto import ec_ref
 from fabric_tpu.observe import ledger as _ledger
 from fabric_tpu.ops import rns
-from fabric_tpu.utils.batching import next_pow2
+from fabric_tpu.utils.batching import MIN_LANES, block_shapes
 
 P = ec_ref.P
 N = ec_ref.N
@@ -281,17 +281,20 @@ verify_batch_jit = jax.jit(verify_batch)
 # ---------------------------------------------------------------------------
 # Host side: admission checks, batched inversion, recoding, residues
 
-MIN_BUCKET = 16
+MIN_BUCKET = MIN_LANES
 
 
 def _bucket(n: int) -> int:
-    """Batch bucket: powers of two up to 512, then multiples of 512 —
-    a 1000-tx block's ~3000 signatures pad to 3072, not 4096 (the
-    padding lanes are pure wasted MXU work).  Few distinct shapes keep
-    the persistent compile cache small."""
-    if n <= 512:
-        return max(MIN_BUCKET, next_pow2(n))
-    return -(-n // 512) * 512
+    """Lanes of the verify program a batch of ``n`` signatures runs in:
+    ``utils/batching.block_shapes``'s verify axis (powers of two from 16
+    to 512, then multiples of 512), the one rule the validator's
+    warm-up enumerates too.  On the v5e one execution takes 2.4 ms at
+    32 lanes, 3.4 at 64, 2.8 at 128, 3.6 at 256, 5.4 at 512, 9.2 at
+    1024, 13.3 at 1536 and 26.9 at 3072 (PERF.md section 6, PR 28): a
+    floor near 2.5 ms and 8.7 us a lane above 512 lanes, so a padded
+    lane costs what a real one does and the small buckets earn the
+    program each of them costs at a start."""
+    return block_shapes(signatures=n).verify
 
 
 def _batch_inv_mod_n(ss: list[int]) -> list[int]:
@@ -1317,14 +1320,38 @@ def _verify_rec(n_real: int, chunk: int, mesh, recode_device: bool):
     the ledger is disarmed — a single global read + None check).  The
     structural key drives the ledger's first-seen compile inference:
     the jitted kernel retraces per (padded bucket or chunk shape,
-    recode variant, mesh layout)."""
-    shape = chunk if (chunk and n_real > chunk) else _bucket(n_real)
-    return _ledger.launch(
-        "verify",
-        key=(shape, bool(recode_device),
-             mesh.size if mesh is not None else 0),
-        lanes=n_real,
-    )
+    recode variant, mesh layout).  The row's ``bucket`` is the lanes
+    the launch ran padded: ``_bucket(n_real)`` for a chunked launch
+    too, whose chunks add up to it (``_chunk_bounds``).  The gauge
+    ``device_verify_programs`` is an operations metric like
+    ``device_stage2_programs``, not tracing: armed or not, a launch
+    pays one set lookup for it."""
+    bucket = _bucket(n_real)
+    shape = chunk if (chunk and n_real > chunk) else bucket
+    key = (shape, bool(recode_device), mesh.size if mesh is not None else 0)
+    if key not in _PROGRAM_KEYS:
+        _note_program(key)
+    return _ledger.launch("verify", key=key, lanes=n_real,
+                          shape={"bucket": bucket})
+
+
+#: structural keys of the verify dispatches this process has made: the
+#: jitted kernel holds one program per key (a chunked launch's padded
+#: tail may add one more, which is not counted)
+_PROGRAM_KEYS: set = set()
+
+
+def _note_program(key) -> None:
+    """A verify shape's first dispatch: ``device_verify_programs``, the
+    verify side of ``device_stage2_programs`` — a gauge that still
+    grows on a warmed channel means a size class the warm-up missed."""
+    from fabric_tpu.ops_metrics import global_registry
+
+    _PROGRAM_KEYS.add(key)
+    global_registry().gauge(
+        "device_verify_programs",
+        "distinct verify program shapes dispatched by this process",
+    ).set(len(_PROGRAM_KEYS))
 
 
 def _to_cols(items):

@@ -233,11 +233,20 @@ class DeviceBlockPipeline:
         )
 
     def run(self, handle, launch_vec, groups, static_packed, static_dims,
-            pre_ok_pad_len, mesh=None, resident=None):
+            pre_ok_pad_len, mesh=None, resident=None, n_txs=None):
         """handle: p256v3.VerifyHandle; launch_vec np [T,3] i32;
         groups: list of (plan, packed_dev [Eb, S·P+S+1], Eb, S);
         static_packed: device [T, R+W+2Q] i32; static_dims: (R, W, Q).
         Returns a zero-arg fetch → dict of numpy arrays.
+
+        Every shape in the program key is one ``utils/batching.
+        block_shapes`` gave the caller (T = ``pre_ok_pad_len``, the
+        verify lanes, each group's E and S, the dims), which is what
+        lets ``BlockValidator.warmup`` walk ``channel_shapes`` for the
+        tx shapes a channel has committed and leave no block like them
+        a program to lower.  ``n_txs``: the
+        block's real txs, for the launch ledger's row beside the T
+        bucket.
 
         ``mesh``: parallel.mesh data mesh — the per-tx (launch_vec,
         static_packed) and per-endorsement (group) lanes shard axis 0
@@ -296,7 +305,9 @@ class DeviceBlockPipeline:
             sharded = all(pmesh.will_shard(mesh, a) for a in data_planes)
         rec = _ledger.launch("stage2", compiled=compiled,
                              lanes=t_bucket, h2d_bytes=h2d,
-                             sharded=sharded)
+                             sharded=sharded,
+                             shape=None if n_txs is None
+                             else {"txs": int(n_txs)})
         # the fused path never calls the verify handle's fetch (the
         # signature vector stays on device as a stage-2 operand), so
         # its ledger record would never close: complete it

@@ -38,6 +38,11 @@ from fabric_tpu.protos import common_pb2, proposal_pb2
 
 _log = logging.getLogger("fabric_tpu.peer")
 
+#: how many of a channel's last committed blocks ``_warm_programs``
+#: looks at for the tx shapes its stage-2 programs are warmed for: half
+#: a minute of a channel that cuts one every second
+WARM_FROM_BLOCKS = 32
+
 
 class PeerChannel:
     """One channel's ledger + validator + commit loop on this peer.
@@ -235,6 +240,10 @@ class PeerChannel:
                 if warmed:
                     _log.info("%s: resident cache warmed with %d keys "
                               "from snapshot", channel_id, warmed)
+        # the device programs this channel's blocks can key, compiled
+        # before its first block (a peer with a sidecar owns no lane)
+        self._device_lane = not sidecar_endpoint
+        self._warm_programs()
         from fabric_tpu.peer.coordinator import PvtDataCoordinator
         from fabric_tpu.peer.transient import TransientStore
 
@@ -264,6 +273,60 @@ class PeerChannel:
         # traffic autopilot actuates runtime knobs through it
         # (apply_knob); None between deliver sessions
         self.pipe = None
+
+    def _warm_programs(self) -> None:
+        """``BlockValidator.warmup`` for this channel: every verify
+        program up to the largest block its orderer cuts
+        (``BatchSize.max_message_count`` of the channel's configuration,
+        each tx with its creator's signature and one endorsement from
+        every principal of the widest application policy), and the
+        stage-2 programs of blocks that look like the last
+        ``WARM_FROM_BLOCKS`` the channel committed, at every size up to
+        that count.  Without it a live peer lowers and compiles a
+        program inside its commit path each time a block's size class
+        first appears, a stall of seconds to tens of seconds, some
+        nineteen times after a start on a channel at the orderer's
+        defaults.  A freshly joined channel has no blocks to look at:
+        its verify programs are warmed and each stage-2 program is
+        lowered where its first block commits, about a second each.
+
+        Called when the channel opens and after every committed
+        configuration update (a raised count, another policy): what is
+        compiled already costs a launch, a third of a second in all.
+        SYNCHRONOUS: the caller (``Peer.join_channel``, the commit of a
+        configuration block) waits two to four minutes where the
+        programs are new to the process, and a peer that joins on its
+        event loop serves nothing meanwhile.  Only where a chip is the
+        device lane (``xla_env.on_accelerator``), never for a sidecar's
+        peer; a channel without a configuration (dev mode) has no such
+        count."""
+        from fabric_tpu.crypto import policy as pol
+        from fabric_tpu.protos import orderer_pb2
+        from fabric_tpu.utils.xla_env import on_accelerator
+
+        bundle = getattr(self.processor, "bundle", None)
+        if bundle is None or not self._device_lane or not on_accelerator():
+            return
+        size = bundle.orderer_value("BatchSize", orderer_pb2.BatchSize)
+        max_tx = int(size.max_message_count) if size is not None else 0
+        if max_tx <= 0:
+            return
+        asts = [bundle.application_policy_ast(name)
+                for name in ("Endorsement", "LifecycleEndorsement")]
+        per_tx = 1 + max((len(pol.compile_plan(a).principals)
+                          for a in asts if a is not None), default=0)
+        seen: set = set()
+        height = self.ledger.blocks.height
+        for num in range(max(0, height - WARM_FROM_BLOCKS), height):
+            block = self.ledger.blocks.get_block(num)
+            if block is not None:  # under a snapshot's height: not held
+                seen |= self.validator.tx_shapes(block)
+        warmed = self.validator.warmup(max_tx, per_tx, seen)
+        _log.info("%s: warmed %d verify and %d stage-2 programs (%d tx "
+                  "shapes in blocks %d..%d) for blocks of up to %d tx in "
+                  "%.1f s", self.id, warmed["verify"], warmed["stage2"],
+                  len(seen), max(0, height - WARM_FROM_BLOCKS), height - 1,
+                  max_tx, warmed["seconds"])
 
     # -- runtime re-knobbing (the traffic autopilot's actuator) ----------
 
@@ -592,6 +655,15 @@ class PeerChannel:
                     "apply — bundle is now STALE relative to the ledger",
                     self.id, ptx.idx, block.header.number,
                 )
+                continue
+            try:
+                # a raised MaxMessageCount, another policy: new programs
+                self._warm_programs()
+            except Exception:
+                _log.exception(
+                    "%s: warm-up after the CONFIG tx of block %d failed — "
+                    "new programs are lowered where their blocks commit",
+                    self.id, block.header.number)
 
     def verify_block_signature(self, block) -> None:
         """VerifyBlock at deliver (block_verification.go:243): a block

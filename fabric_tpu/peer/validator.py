@@ -38,6 +38,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 from functools import cached_property
+from typing import NamedTuple
 
 import numpy as np
 
@@ -77,6 +78,17 @@ class NamespaceInfo:
     #  "max_peer_count": int, "btl": int}} — static assemblies;
     # lifecycle-backed providers read the committed definition instead
     collections: dict = field(default_factory=dict)
+
+
+class TxShape(NamedTuple):
+    """What of a block's device programs its tx count does not give:
+    read off a committed block by ``BlockValidator.tx_shapes``, and
+    what ``BlockValidator.warmup`` pairs with every block size."""
+
+    policy: tuple    # ``device_block.plan_sig`` of the plan its txs are
+    #                  judged under, at buckets 0: the policy's structure
+    signatures: int  # per tx: the creator's and its endorsements
+    dims: tuple      # (reads, writes, range queries) as stage 2 pads them
 
 
 class PolicyProvider:
@@ -538,24 +550,143 @@ class BlockValidator:
                     if self.host_pool is not None else 0
                 )
 
-    def _t(self, key: str, t0: float) -> float:
+    def _t(self, key: str, t0: float, handle=None) -> float:
+        """``handle``: the verify launch a ``sig_prepare_launch`` stage
+        made — on a traced path its span says the launch's real
+        ``lanes`` and the ``bucket`` they were padded to."""
         t1 = time.perf_counter()
         if self.timings is not None:
             self.timings[key] = self.timings.get(key, 0.0) + (t1 - t0)
         self._stage_hist.observe(t1 - t0, stage=key)
-        self._tracer.add(key, t0, t1)  # no-op off the traced paths
+        out = None
+        if handle is not None and self._tracer.current() is not None:
+            out = getattr(handle, "device_out", None)
+        if out is None:
+            self._tracer.add(key, t0, t1)  # no-op off the traced paths
+        else:
+            self._tracer.add(key, t0, t1, lanes=int(handle.n_real),
+                             bucket=int(out.shape[0]))
         return t1
 
-    def warmup(self, n_sigs: int = 16) -> None:
-        """Compile (or load from the persistent cache) the signature
-        kernel for the smallest batch bucket before serving traffic —
-        first-block latency must not eat a cold compile."""
-        from fabric_tpu.crypto import ec_ref
+    def tx_shapes(self, block) -> set:
+        """The :class:`TxShape` of each policy group of a committed
+        block, as this validator would launch it now (its namespaces'
+        policies resolved as they stand, the rwset dims as
+        ``_device_preprocess`` pads them, signatures per tx as the
+        block's signature batch over its envelopes).  Empty for a block
+        the fused device path would not take (a configuration block, a
+        custom plugin).  The block's own parse and ``device_pre``
+        stages and nothing after them: no launch, and no state read
+        but the policy provider's."""
+        from fabric_tpu.peer.device_block import plan_sig
 
+        txs, items, rwp, fb = self._parse(block)
+        dpre = self._device_preprocess(txs, rwp, fb)
+        if dpre is None or not txs:
+            return set()
+        per_tx = -(-len(items) // len(txs))
+        return {TxShape(plan_sig(plan, 0, 0), per_tx,
+                        tuple(dpre.static.dims))
+                for plan, _packed, _e, _s in dpre.groups}
+
+    def warmup(self, max_tx: int, signatures_per_tx: int, seen) -> dict:
+        """Lower and compile (or load from the persistent cache) the
+        device programs a channel's blocks key, before its first block.
+        ``max_tx``: the most txs the channel's orderer puts in a block
+        (``BatchSize.max_message_count``); ``signatures_per_tx``: the
+        most signatures one tx carries (its creator's and one
+        endorsement from each principal of the widest policy);
+        ``seen``: the :class:`TxShape` of the blocks this channel has
+        committed lately (``tx_shapes``; ``peer/node.py`` reads them
+        off the block store when a channel opens), empty on a fresh
+        join.
+
+        Walks ``utils/batching.channel_shapes`` through the real
+        launch path (``_verify_launch_guarded``, the fused stage 2 of
+        ``DeviceBlockPipeline.run``) on inert inputs:
+
+        * verify: EVERY bucket up to the one ``max_tx`` txs of
+          ``signatures_per_tx`` signatures fill: the programs that
+          cost seconds to tens of seconds each.  Afterwards no block
+          of the channel lowers a verify program;
+        * stage 2: for each shape of ``seen``, the programs of every
+          block of 1..``max_tx`` txs of that shape.  Afterwards no
+          block of 1..``max_tx`` txs that looks like a block of
+          ``seen`` (one namespace a tx, that policy's structure, that
+          many signatures a tx, those rwset dims) lowers a program.  A
+          block that looks like none the channel has committed lately
+          (on a fresh join: every first one) lowers its own stage-2
+          program on first sight, which costs about a second and not
+          the verify program's tens.
+
+        Not walked: the chunked launch's tail shapes (``verify_chunk``)
+        and the resident-state variant of stage 2 (both off by
+        default), and the sidecar's programs (a ``SidecarValidator``
+        owns no device lane).  → what was warmed, and the seconds
+        stage 2 took of the whole."""
+        from fabric_tpu.crypto import ec_ref
+        from fabric_tpu.ops.p256v3 import SigCollector
+        from fabric_tpu.utils.batching import block_shapes, channel_shapes
+
+        t_start = time.perf_counter()
+        stage2: dict = {}  # verify lanes → {(shapes, policy structure)}
+        for shape in seen:
+            for sh in channel_shapes(max_tx, shape.signatures, *shape.dims):
+                stage2.setdefault(sh.verify, set()).add((sh, shape.policy))
+        top = max([block_shapes(
+            signatures=max_tx * signatures_per_tx).verify, *stage2])
         k = ec_ref.SigningKey.generate()
         e = ec_ref.digest_int(b"warmup")
-        r, s = k.sign_digest(e)
-        p256.verify_host([(e, r, s, *k.public)] * n_sigs)
+        item = (e, *k.sign_digest(e), *k.public)
+        done = {"verify": 0, "stage2": 0, "stage2_seconds": 0.0}
+        lanes = 0
+        while lanes < top:
+            lanes = block_shapes(signatures=lanes + 1).verify
+            batch = SigCollector()
+            for _ in range(lanes):
+                batch.add_slow(item)
+            handle = self._verify_launch_guarded(batch)
+            done["verify"] += 1
+            if getattr(handle, "device_out", None) is not None:
+                t0 = time.perf_counter()
+                for sh, policy in sorted(stage2.get(lanes, ())):
+                    self._warm_stage2(handle, sh, policy)
+                    done["stage2"] += 1
+                done["stage2_seconds"] += time.perf_counter() - t0
+            if not all(handle.fetch()):
+                raise RuntimeError(
+                    f"warm-up: the {lanes}-lane verify program rejected "
+                    "a valid signature")
+        done["stage2_seconds"] = round(done["stage2_seconds"], 3)
+        done["seconds"] = round(time.perf_counter() - t_start, 3)
+        return done
+
+    def _warm_stage2(self, handle, sh, policy) -> None:
+        """One fused stage-2 launch of shapes ``sh`` under a plan of
+        structure ``policy`` (who its principals are is not in the
+        program's key, only how many) over ``handle``'s device output,
+        every lane inert (no tx structural, no entry, no key)."""
+        import jax.numpy as jnp
+
+        from fabric_tpu.peer.device_block import DeviceBlockPipeline
+
+        if self._device_pipeline is None:
+            self._device_pipeline = DeviceBlockPipeline()
+        T, E, S, (R, W, Q) = sh.txs, sh.entries, sh.slots, sh.dims
+        P = policy.n_principals
+        plan = pol.BatchPlan(
+            principals=[None] * P, leaf_principal=list(policy.leaf_principal),
+            leaf_rank=list(policy.leaf_rank),
+            gates=[(n, list(c)) for n, c in policy.gates])
+        launch_vec = np.zeros((T, 3), np.int32)
+        launch_vec[:, 0] = -1
+        gp = np.zeros((E, S * P + S + 1), np.int32)
+        gp[:, S * P:] = -1
+        static = jnp.asarray(np.full((T, R + W + 2 * Q), -1, np.int32))
+        self._device_pipeline.run(
+            handle, launch_vec, [(plan, self._put_group(gp), E, S)], static,
+            (R, W, Q), T, mesh=self.mesh, n_txs=0,
+        )()
 
     # -- device lane: guarded dispatch + CPU fallback ----------------------
 
@@ -726,7 +857,7 @@ class BlockValidator:
         block order, sharing the dup registry.  Returns None when no
         envelope qualifies (the legacy loop takes over)."""
         from fabric_tpu.ops.p256v3 import ColumnarSigBatch
-        from fabric_tpu.utils.batching import next_pow2
+        from fabric_tpu.utils.batching import block_shapes
 
         n = len(block.data.data)
         blob = native.blob
@@ -883,7 +1014,7 @@ class BlockValidator:
         start = native.endo_start[:n].astype(np.int64)
         ecnt = (np.bincount(tx_of_e[e_rows], minlength=n)
                 if ne else np.zeros(n, np.int64))
-        S = max(4, next_pow2(int(ecnt.max()) if ne else 1))
+        S = block_shapes(endorsements=int(ecnt.max()) if ne else 0).slots
         uid_mat = np.zeros((n, S), np.int64)
         endo_idx_mat = np.full((n, S), -1, np.int32)
         if ne:
@@ -1250,7 +1381,7 @@ class BlockValidator:
         txs, items, rwp, fb = self._parse(block)
         t0 = self._t("host_parse", t0)
         fetch = self._verify_launch_guarded(items)
-        t0 = self._t("sig_prepare_launch", t0)
+        t0 = self._t("sig_prepare_launch", t0, handle=fetch)
         dpre = self._device_preprocess(txs, rwp, fb)
         t0 = self._t("device_pre", t0)
         # header+data wire form for the ledger commit (the committer
@@ -1829,7 +1960,7 @@ class BlockValidator:
         the static arrays come from numpy scatters over its flat
         output instead of per-read Python loops."""
         from fabric_tpu.ops import mvcc as mvcc_ops
-        from fabric_tpu.utils.batching import next_pow2
+        from fabric_tpu.utils.batching import block_shapes
 
         if not txs or p256._KERNEL in ("v1", "v2"):
             return None  # fused device path requires the v3 kernel
@@ -1878,9 +2009,9 @@ class BlockValidator:
         for key, ents in by_policy.items():
             plan = plans[key]
             P = len(plan.principals)
-            S = max(4, next_pow2(max(
-                (len(p.endorsements) for p, _ in ents), default=1) or 1))
-            E = max(16, next_pow2(len(ents)))
+            sh = block_shapes(entries=len(ents), endorsements=max(
+                (len(p.endorsements) for p, _ in ents), default=0))
+            S, E = sh.slots, sh.entries
             pool_rows = [np.zeros(P, bool)]  # row 0 = padding (no match)
             pool_of: dict[int, int] = {}
             idx_mat = np.zeros((E, S), np.int32)
@@ -1968,7 +2099,7 @@ class BlockValidator:
         returns NotImplemented otherwise (caller falls back to the
         generic builder), or None for custom plugins (host path)."""
         from fabric_tpu.ops import mvcc as mvcc_ops
-        from fabric_tpu.utils.batching import next_pow2
+        from fabric_tpu.utils.batching import block_shapes
 
         if rwp is None:
             return NotImplemented
@@ -2036,7 +2167,7 @@ class BlockValidator:
             else:
                 gtx = etx
             E = len(gtx)
-            Eb = max(16, next_pow2(max(E, 1)))
+            Eb = block_shapes(entries=E).entries
             row_pool = np.zeros((n_pool + 1, P), bool)
             for u in range(n_pool):
                 if fb.has_ec[u]:
@@ -2175,7 +2306,7 @@ class BlockValidator:
         fetch2 = self._device_pipeline.run(
             handle, launch_vec, dpre.groups, static.packed_static(),
             static.dims, t_bucket, mesh=self.mesh,
-            resident=resident_pack,
+            resident=resident_pack, n_txs=len(txs),
         )
         self._t("stage2_dispatch", t0)
         return fetch2, range_phantom

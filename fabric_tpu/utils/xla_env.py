@@ -98,6 +98,18 @@ def pin_cpu_backend() -> None:
     jax.config.update("jax_platforms", "cpu")
 
 
+def on_accelerator() -> bool:
+    """Whether this process's default backend is an accelerator.  What
+    is only worth doing where a chip is the device lane asks here: a
+    channel's warm-up (``peer/node.py``) lowers a dozen programs, tens
+    of seconds each on any backend, and on the CPU backend (tests, dev
+    networks, a peer attached to a sidecar) no deployment waits for
+    them while a test network opens channels by the dozen."""
+    import jax
+
+    return jax.default_backend() != "cpu"
+
+
 def claim_device(who: str) -> dict:
     """Initialize the backend for a device-owning process and say what
     it runs on: → ``{"platform", "kind", "count"}`` as jax reports

@@ -11,7 +11,6 @@ def main(n_tx=1000):
     blk = blocks[0]
     state = fresh_state()
     v = fresh_validator(state)
-    v.warmup()
 
     # piecewise timings of validator.validate
     from fabric_tpu.ops import p256

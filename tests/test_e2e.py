@@ -96,7 +96,7 @@ class Network:
             self.peers.append(p)
         # one warmup loads the verify kernel into the in-process jit
         # cache for BOTH peers (first-block commits must not eat it)
-        self.peers[0].channels[CHANNEL].validator.warmup()
+        self.peers[0].channels[CHANNEL].validator.warmup(5, 3, ())
         self.client = BroadcastClient(orderer_addrs)
         assert await _wait(lambda: any(
             n.chains[CHANNEL].raft.state == "leader" for n in self.orderers))

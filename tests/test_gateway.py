@@ -78,7 +78,7 @@ def test_gateway_round_trip(tmp_path):
         # cross-register each peer in the other's registry
         peers[0].registry.add(PeerInfo("Org2MSP", "127.0.0.1", peers[1].port))
         peers[1].registry.add(PeerInfo("Org1MSP", "127.0.0.1", peers[0].port))
-        peers[0].channels[CHANNEL].validator.warmup()
+        peers[0].channels[CHANNEL].validator.warmup(5, 3, ())
 
         gw = GatewayClient("127.0.0.1", peers[0].port, client)
         try:

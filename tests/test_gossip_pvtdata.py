@@ -108,7 +108,7 @@ def test_pvt_distribution_and_pull(tmp_path):
         try:
             p0.channels[CHANNEL].start_deliver([("127.0.0.1", orderer.port)])
             p1.channels[CHANNEL].start_deliver([("127.0.0.1", orderer.port)])
-            p0.channels[CHANNEL].validator.warmup()
+            p0.channels[CHANNEL].validator.warmup(5, 3, ())
 
             # endorse ONLY on p0 with transient value; p0 distributes
             # to p1's transient store at endorsement time
@@ -269,7 +269,7 @@ def test_anti_entropy_catchup(tmp_path):
             # only p0 talks to the orderer (org leader); p1 relies on
             # anti-entropy pulls from p0
             p0.channels[CHANNEL].start_deliver([("127.0.0.1", orderer.port)])
-            p0.channels[CHANNEL].validator.warmup()
+            p0.channels[CHANNEL].validator.warmup(5, 3, ())
             bc = BroadcastClient([("127.0.0.1", orderer.port)])
             for i in range(3):
                 signed, tx_id, prop = txa.create_signed_proposal(
@@ -321,7 +321,7 @@ def test_non_member_org_never_holds_cleartext(tmp_path):
         try:
             p0.channels[CHANNEL].start_deliver([("127.0.0.1", orderer.port)])
             p1.channels[CHANNEL].start_deliver([("127.0.0.1", orderer.port)])
-            p0.channels[CHANNEL].validator.warmup()
+            p0.channels[CHANNEL].validator.warmup(5, 3, ())
 
             from fabric_tpu.comm.rpc import RpcClient
             from fabric_tpu.protos import proposal_pb2
@@ -399,7 +399,7 @@ def test_btl_expiry_purges_state_and_store(tmp_path):
                 prov = p.channels[CHANNEL].validator.policies
                 prov.infos[CC].collections["collA"]["btl"] = 1
             p0.channels[CHANNEL].start_deliver([("127.0.0.1", orderer.port)])
-            p0.channels[CHANNEL].validator.warmup()
+            p0.channels[CHANNEL].validator.warmup(5, 3, ())
 
             from fabric_tpu.comm.rpc import RpcClient
             from fabric_tpu.protos import proposal_pb2
