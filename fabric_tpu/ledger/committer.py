@@ -275,6 +275,28 @@ class AsyncApplyEngine(VersionedDB):
     # query) and ``sf.pending`` (the overlay walk) under the calling
     # thread's current span, the validator's ``launch``: two spans a
     # call, nothing per key, no-ops off a traced path.
+    #
+    # Both take the pending snapshot BEFORE the inner DB is asked.  An
+    # entry leaves the queue only after its apply has committed, so an
+    # apply that ends while the query runs is still in the snapshot,
+    # and the overlay, which wins, carries what it wrote.  The other
+    # order loses it: the query's snapshot predates the commit and the
+    # queue no longer holds the entry.
+
+    def _inner_gather(self, gather, keys):
+        """``gather(keys)`` on the inner DB inside ``sf.gather``.  Where
+        the inner DB answers on a read connection of its own
+        (``SqliteVersionedDB.rd_wait_s``) the span says so: ``reader``
+        1, and ``rd_wait_ms``, what readers waited for that
+        connection's lock over the span."""
+        with global_tracer().span("sf.gather", keys=len(keys)) as gsp:
+            waited0 = getattr(self._inner, "rd_wait_s", None)
+            out = gather(keys)
+            if gsp is not None and waited0 is not None:
+                gsp.attrs.update(
+                    reader=1,
+                    rd_wait_ms=(self._inner.rd_wait_s - waited0) * 1000.0)
+            return out
 
     def get_versions_bulk(self, keys):
         tracer = global_tracer()
@@ -293,19 +315,18 @@ class AsyncApplyEngine(VersionedDB):
                     else:
                         rest.append(k)
         if not pend:
-            with tracer.span("sf.gather", keys=len(keys)):
-                return self._inner.get_versions_bulk(keys)
+            return self._inner_gather(self._inner.get_versions_bulk, keys)
         if rest:
-            with tracer.span("sf.gather", keys=len(rest)):
-                out.update(self._inner.get_versions_bulk(rest))
+            out.update(
+                self._inner_gather(self._inner.get_versions_bulk, rest))
         return out
 
     def get_versions_cols(self, keys):
         tracer = global_tracer()
-        with tracer.span("sf.gather", keys=len(keys)):
-            present, vers = self._inner.get_versions_cols(keys)
+        pend = self._pending()
+        present, vers = self._inner_gather(
+            self._inner.get_versions_cols, keys)
         with tracer.span("sf.pending") as psp:
-            pend = self._pending()
             tracer.set_attrs(psp, pending=len(pend))
             if pend:
                 for i, k in enumerate(keys):

@@ -13,17 +13,23 @@ scans, savepoints.  Two backends mirror the reference's split:
   the reference itself documents CouchDB as a throughput liability
   (docs/source/performance.md:180-186).
 
-The TPU-relevant member is ``get_versions_bulk``: one gather of
-committed versions for every read key of a block, feeding
-fabric_tpu.ops.mvcc.prepare_block (the reference bulk-preload:
-txmgmt/validation/validator.go:27-78).
+The member on the commit path is ``get_versions_cols``: one gather a
+block of the committed versions of its unique keys, as two arrays that
+the validator's ``state_fill`` compares the block's reads with
+(``peer/validator.py`` ``_flat_ver_ok``; ``state/residency.py`` gathers
+its misses the same way).  ``get_versions_bulk`` is its dict form, kept
+for the validator's host lane (``_committed_versions``; the reference
+bulk-preload: txmgmt/validation/validator.go:27-78).
 """
 
 from __future__ import annotations
 
 import json
 import sqlite3
+import threading
+import time
 from bisect import bisect_left
+from contextlib import contextmanager
 from dataclasses import dataclass
 
 Version = tuple[int, int]
@@ -404,11 +410,35 @@ class MemVersionedDB(VersionedDB):
 
 
 class SqliteVersionedDB(VersionedDB):
-    """Durable backend over sqlite (WAL mode)."""
+    """Durable backend over sqlite (WAL mode).
+
+    Two connections to the one file, as the block store's index has
+    (``BlockStore._idx`` / ``_rd``).  ``_conn`` belongs to whoever
+    writes: ``apply_updates`` (the applier thread, under the async
+    engine), and the three iterators, whose cursors live as long as
+    their caller walks them (an open cursor on a WAL reader pins its
+    snapshot and stops checkpoints: see ``BlockStore._rd_rows``).
+    ``_rd`` (``query_only``) answers the lookups: the block's version
+    gather, ``get_state``, ``savepoint``.  In WAL mode a read on it
+    never waits for the writer's transaction nor for its ``commit()``,
+    and sees what was committed before the read began; what is
+    submitted and not yet committed the async engine's pending overlay
+    supplies (``ledger/committer.py``).  ``_rd_lock`` is for the
+    readers among themselves: one transaction at a time on the
+    connection, and two threads on one statement text would share its
+    prepared statement.
+    """
 
     def __init__(self, path: str):
         self.path = path
         self._conn: sqlite3.Connection | None = None
+        self._rd: sqlite3.Connection | None = None
+        self._rd_lock = threading.Lock()
+        # seconds readers spent WAITING for ``_rd_lock`` (held by
+        # another reader for one gather or one lookup).  Only a
+        # contended acquire reads the clock; updated while holding the
+        # lock.  The engine's ``sf.gather`` span carries the delta.
+        self.rd_wait_s = 0.0
 
     def open(self):
         self._conn = sqlite3.connect(self.path, check_same_thread=False)
@@ -432,30 +462,79 @@ class SqliteVersionedDB(VersionedDB):
             "SELECT COUNT(*) FROM state WHERE metadata IS NOT NULL"
             " AND metadata != x''"
         ).fetchone()[0]
+        # opened once the schema is committed.  ``isolation_level=None``:
+        # the only transactions on it are the ones _reading opens
+        self._rd = sqlite3.connect(self.path, check_same_thread=False,
+                                   isolation_level=None)
+        self._rd.execute("PRAGMA query_only=ON")
 
     def close(self):
+        # the reader first: the LAST connection to close checkpoints
+        # the WAL and removes ``state.db-wal``, and that is the writer
+        if self._rd:
+            self._rd.close()
+            self._rd = None
         if self._conn:
             self._conn.close()
             self._conn = None
 
+    @contextmanager
+    def _rd_locked(self):
+        """The read connection, under the readers' lock."""
+        lock = self._rd_lock
+        if not lock.acquire(False):
+            t0 = time.perf_counter()
+            lock.acquire()
+            self.rd_wait_s += time.perf_counter() - t0
+        try:
+            yield self._rd
+        finally:
+            lock.release()
+
+    def _rd_row(self, sql: str, args=()):
+        """First row of one statement on the read connection, fetched
+        to its end before the lock is released, so no cursor is left
+        open on a snapshot."""
+        with self._rd_locked() as rd:
+            rows = rd.execute(sql, args).fetchall()
+        return rows[0] if rows else None
+
+    @contextmanager
+    def _reading(self):
+        """A cursor on the read connection inside ONE read transaction:
+        every statement of the ``with`` body sees the same snapshot and
+        the WAL's read lock is taken once (a ``SELECT`` of its own
+        takes and drops it, once a key, and slows the writer beside
+        it).  The transaction ends with the body, raised or not,
+        so the connection never pins a snapshot between calls."""
+        with self._rd_locked() as rd:
+            cur = rd.cursor()
+            cur.execute("BEGIN")
+            try:
+                yield cur
+            finally:
+                cur.close()  # resets a statement left mid-way
+                rd.execute("COMMIT")
+
     def get_state(self, ns, key):
-        row = self._conn.execute(
+        row = self._rd_row(
             "SELECT value, metadata, block, txnum FROM state WHERE ns=? AND key=?",
             (ns, key),
-        ).fetchone()
+        )
         if row is None:
             return None
         return VersionedValue(row[0], row[1], (row[2], row[3]))
 
     def get_versions_bulk(self, keys):
         out = {}
-        cur = self._conn.cursor()
-        for ns, key in keys:
-            row = cur.execute(
-                "SELECT block, txnum FROM state WHERE ns=? AND key=?", (ns, key)
-            ).fetchone()
-            if row:
-                out[(ns, key)] = (row[0], row[1])
+        with self._reading() as cur:
+            for ns, key in keys:
+                row = cur.execute(
+                    "SELECT block, txnum FROM state WHERE ns=? AND key=?",
+                    (ns, key),
+                ).fetchone()
+                if row:
+                    out[(ns, key)] = (row[0], row[1])
         return out
 
     def get_versions_cols(self, keys):
@@ -466,15 +545,15 @@ class SqliteVersionedDB(VersionedDB):
         U = len(keys)
         present = np.zeros(U, bool)
         vers = np.zeros((U, 2), np.uint32)
-        cur = self._conn.cursor()
-        for i, (ns, key) in enumerate(keys):
-            row = cur.execute(
-                "SELECT block, txnum FROM state WHERE ns=? AND key=?",
-                (ns, key),
-            ).fetchone()
-            if row:
-                present[i] = True
-                vers[i] = row
+        with self._reading() as cur:
+            for i, (ns, key) in enumerate(keys):
+                row = cur.execute(
+                    "SELECT block, txnum FROM state WHERE ns=? AND key=?",
+                    (ns, key),
+                ).fetchone()
+                if row:
+                    present[i] = True
+                    vers[i] = row
         return present, vers
 
     def iter_all(self):
@@ -561,7 +640,5 @@ class SqliteVersionedDB(VersionedDB):
         self._conn.commit()
 
     def savepoint(self):
-        row = self._conn.execute(
-            "SELECT block, txnum FROM savepoint WHERE id=0"
-        ).fetchone()
+        row = self._rd_row("SELECT block, txnum FROM savepoint WHERE id=0")
         return (row[0], row[1]) if row else None
