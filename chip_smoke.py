@@ -52,7 +52,7 @@ import threading
 import time
 
 CHANNEL, CC = "smokechan", "smokecc"
-#: version every preloaded key carries (bench.py's convention)
+#: version every preloaded key carries
 PRELOAD_VERSION = (1, 0)
 #: phase-1 counters that must stay 0 — each one is a path that would
 #: let a block commit without the device doing the work
@@ -194,8 +194,7 @@ def build_blocks(size: Size, rng, endorsers, client):
     fresh_key)`` with kind ``ok`` | ``bad_sig`` | ``stale``.  Tx i of
     block b reads one cold preloaded key (its own, never shared) and
     one key of the hot read-only set, rewrites the cold key and writes
-    a fresh one — the shape bench.py's mixed variant builds, over a
-    state the size a deployment holds."""
+    a fresh one, over a state the size a deployment holds."""
     from fabric_tpu import protoutil as pu
     from fabric_tpu.ledger.rwset import TxRWSet
     from fabric_tpu.peer import txassembly as txa

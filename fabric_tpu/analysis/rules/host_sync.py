@@ -10,7 +10,7 @@ The rule builds a project-wide call graph rooted at the functions of
 ``peer/validator.py`` and ``peer/coordinator.py`` and flags sync
 constructs in every reachable function.  Resolution is IMPORT-AWARE:
 
-* ``p256.verify_host()`` where ``p256`` was imported from an analyzed
+* ``p256v3.verify_host()`` where ``p256v3`` was imported from an analyzed
   module links only to THAT module's ``verify_host`` def — not to
   every same-named def in the project;
 * ``from mod import foo`` (incl. ``as`` renames and relative imports,
@@ -62,8 +62,8 @@ def _fn_key(mod: ModuleCtx, fn: ast.FunctionDef) -> tuple[str, str, int]:
 
 
 def _dotted_of(relpath: str) -> str:
-    """Module relpath → dotted form ("fabric_tpu/ops/p256.py" →
-    "fabric_tpu.ops.p256"; packages drop the __init__ leaf)."""
+    """Module relpath → dotted form ("fabric_tpu/ops/p256v3.py" →
+    "fabric_tpu.ops.p256v3"; packages drop the __init__ leaf)."""
     p = relpath[:-3] if relpath.endswith(".py") else relpath
     if p.endswith("/__init__"):
         p = p[: -len("/__init__")]
@@ -75,9 +75,9 @@ class _ModuleIndex:
 
     Matching is suffix-tolerant in both directions because the
     analysis root is not necessarily the import root: analyzing from
-    the repo root gives dotted forms like ``fabric_tpu.ops.p256``
-    while analyzing the package directory gives ``ops.p256`` — both
-    must resolve ``from fabric_tpu.ops import p256``."""
+    the repo root gives dotted forms like ``fabric_tpu.ops.p256v3``
+    while analyzing the package directory gives ``ops.p256v3`` — both
+    must resolve ``from fabric_tpu.ops import p256v3``."""
 
     def __init__(self, modules: list[ModuleCtx]):
         self._dotted = [(_dotted_of(m.relpath), m.relpath)
@@ -86,8 +86,8 @@ class _ModuleIndex:
         # of these are clearly external.  The analysis ROOT's own
         # directory name rides along because absolute imports name the
         # super-package even when the root IS the package directory
-        # (root=fabric_tpu/ gives dotted forms like "ops.p256", yet
-        # code says "from fabric_tpu.ops import p256" — without this,
+        # (root=fabric_tpu/ gives dotted forms like "ops.p256v3", yet
+        # code says "from fabric_tpu.ops import p256v3" — without this,
         # an unresolvable absolute project import would be classified
         # external and silently under-approximate the graph).
         self.roots = set()

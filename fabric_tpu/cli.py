@@ -150,7 +150,6 @@ def _build_peer(cfg):
         coalesce_blocks=cfg.coalesce_blocks,
         host_stage_workers=cfg.host_stage_workers,
         recode_device=cfg.recode_device,
-        host_stage_mode=cfg.host_stage_mode,
         trace_ring_blocks=cfg.trace_ring_blocks,
         trace_slow_factor=cfg.trace_slow_factor,
         slos=cfg.slos,

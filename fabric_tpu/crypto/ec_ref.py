@@ -1,6 +1,6 @@
 """Pure-Python NIST P-256 reference implementation (correctness oracle).
 
-This is the host-side oracle the TPU kernel (`fabric_tpu.ops.p256`) is
+This is the host-side oracle the TPU kernel (`fabric_tpu.ops.p256v3`) is
 tested bit-exactly against, and the arithmetic backing for key/cert
 generation where the `cryptography` package is not used.  Semantics
 mirror the reference's SW BCCSP verifier: ECDSA P-256 with SHA-256

@@ -4,7 +4,7 @@ Host-side identity handling (analog of msp/identities.go).  The
 expensive part — ECDSA verification — is NOT done here per-identity:
 identities expose their public-key coordinates so the commit pipeline
 can feed the whole block's (digest, r, s, qx, qy) tuples to the batched
-TPU kernel (fabric_tpu.ops.p256).  ``verify`` below is the host
+TPU kernel (fabric_tpu.ops.p256v3).  ``verify`` below is the host
 fallback (reference semantics: msp/identities.go:170-199 — SHA-256 the
 message, then ECDSA-verify with low-S enforcement per
 bccsp/sw/ecdsa.go:41-58).

@@ -10,7 +10,7 @@ The TPU-first redesign flattens the tree into a *batch plan*: a list of
 principals (leaf columns) plus a post-order gate array, so that policy
 evaluation over a whole block becomes array ops on the boolean
 signature-validity vector produced by the batched ECDSA kernel
-(fabric_tpu.ops.p256) — see fabric_tpu.peer.device_block.
+(fabric_tpu.ops.p256v3) — see fabric_tpu.peer.device_block.
 
 Two evaluators:
 

@@ -52,8 +52,8 @@ class KVLedger:
         the background applier (ledger/committer.py) — reads stay
         consistent through the engine's pending overlay, the bounded
         queue (``apply_queue_blocks``) backpressures at the block
-        boundary.  The peer/bench layers turn this ON by default
-        (nodeconfig ``async_commit``, ``FABTPU_BENCH_ASYNC_COMMIT``);
+        boundary.  The peer turns this ON by default (nodeconfig
+        ``async_commit``), and so does the benchmark's rig;
         the library default stays serial so direct KVLedger users get
         apply-on-return semantics unless they opt in."""
         os.makedirs(ledger_dir, exist_ok=True)

@@ -168,13 +168,6 @@ class PeerConfig:
     # Bit-equal to serial staging; enable on multi-core hosts whose
     # sharded device outruns its single-threaded feeder.
     host_stage_workers: int = 0
-    # host staging pool flavor: "thread" (default — the staging hot
-    # loops are numpy/hashlib/native-C and release the GIL) or
-    # "process" for Python-bound CUSTOM staging workloads on a
-    # directly-constructed HostStagePool.  The validator's built-in
-    # staging is shared-memory (in-place slab writes) and always runs
-    # on threads — it coerces "process" back with a warning.
-    host_stage_mode: str = "thread"
     # window recoding location (ops/p256v3.py): ship u1/u2 as 16-bit
     # scalar limbs and derive the 4-bit window digits ON DEVICE, so
     # the packed verify H2D frame shrinks (window planes 4×, whole
@@ -247,7 +240,7 @@ class PeerConfig:
     tx_flow: bool = True
     # device-lane degradation (peer/degrade.py DeviceLaneGuard): after
     # device_fail_threshold CONSECUTIVE device-verify failures the
-    # validator latches a degraded CPU mode (ops/p256.verify_host +
+    # validator latches a degraded CPU mode (ops/p256v3.verify_host +
     # the host MVCC path — correctness identical, the channel stays
     # live) with a recovery probe every device_recovery_s.  0 = guard
     # off entirely (failures raise through, today's behavior) — the
@@ -589,12 +582,6 @@ def _load(cls, source, environ=None):
             f"key 'apply_queue_blocks': must be >= 1 trailing batch "
             f"(the bound is what keeps apply lag and crash-recovery "
             f"replay finite), got {cfg.apply_queue_blocks}"
-        )
-    if isinstance(cfg, PeerConfig) and cfg.host_stage_mode not in (
-            "thread", "process"):
-        raise ConfigError(
-            f"key 'host_stage_mode': must be 'thread' or 'process', "
-            f"got {cfg.host_stage_mode!r}"
         )
     if isinstance(cfg, PeerConfig) and cfg.vitals_interval_s < 0:
         raise ConfigError(

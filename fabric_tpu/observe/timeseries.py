@@ -9,9 +9,8 @@ attribute unless a human was polling at the right moment.
 :class:`MetricsSampler` closes that gap: a periodic walker over the
 metrics :class:`~fabric_tpu.ops_metrics.Registry` that records, per
 metric and label variant, a bounded ring of ``(t, value)`` points —
-the trailing series ``/vitals`` serves, the black-box recorder
-(observe/blackbox.py) snapshots into incident bundles, and
-``FABTPU_BENCH_VITALS`` dumps into BENCH_*.json extras.
+the trailing series ``/vitals`` serves and the black-box recorder
+(observe/blackbox.py) snapshots into incident bundles.
 
 Delta semantics per metric kind (raw monotones are useless trails):
 

@@ -1,21 +1,28 @@
-"""Batched ECDSA P-256 verification over RNS field arithmetic ("v3",
-the Cox-Rower kernel) — the flagship data-plane kernel.
+"""Batched ECDSA P-256 verification over RNS field arithmetic (the
+Cox-Rower kernel) — the data-plane kernel, and the only ECDSA verify
+kernel.
 
-Design deltas vs ops.p256v2 (digit-polynomial "v2"):
+This is the hot loop of the reference's block-commit path: every
+endorsement on every transaction is an ECDSA-P256 signature verified on
+the host CPU one at a time (reference: msp/identities.go:170-199 →
+bccsp.Verify; ~2-3 verifies per tx at a 2-of-3 policy, validator
+fan-out in core/committer/txvalidator/v20/validator.go:193-208).  Here
+the whole block's signatures are verified in ONE batched TPU dispatch.
+
+Design:
 
 * Field core: fabric_tpu.ops.rns — Montgomery multiplication whose only
   non-elementwise work is two DENSE [B,46]@[46,72] bf16 MXU matmuls
-  (exact by 6-bit chunking), ~25× less matmul volume per modmul than
-  v2's one-hot contraction, at DEFAULT (single-pass) precision.
-* Scalar recoding moved to the HOST: u1 = e·s⁻¹, u2 = r·s⁻¹ (mod n)
+  (exact by 6-bit chunking) at DEFAULT (single-pass) precision.
+* Scalar recoding on the HOST: u1 = e·s⁻¹, u2 = r·s⁻¹ (mod n)
   are computed with one Montgomery-batched inversion over the whole
   batch (3(B−1) 256-bit mults + ONE modular inversion, microseconds of
   numpy/Python work) — RNS has no cheap positional form, and the
   device has no business running a 256-round Fermat loop when the host
   does the whole batch in milliseconds.  The device receives 4-bit
   window digits.
-* Point arithmetic: unchanged mathematics — Renes–Costello–Batina 2016
-  COMPLETE projective formulas (a = −3), 64 ladder steps of
+* Point arithmetic: Renes–Costello–Batina 2016 COMPLETE projective
+  formulas (a = −3), 64 ladder steps of
   [4 doublings + u2·Q table add + u1·G mixed add], in-kernel Q window
   table, host-precomputed Montgomery-form G table.
 * Ladder body lives in a fori_loop with a FIXED loop-state bound
@@ -65,8 +72,7 @@ def _const_rv(x: int) -> rns.RV:
 
 # ---------------------------------------------------------------------------
 # RCB16 complete point ops (projective X:Y:Z, a = -3) over rns.RV.
-# Identical op schedules to ops.p256v2 (alg. 4/5/6); the field layer
-# changed, the mathematics did not.
+# Op schedules of the paper's algorithms 4/5/6.
 
 
 def pt_add(p1, p2, b_rv, ctx):
@@ -438,8 +444,8 @@ class SigCollector:
         return self.n
 
     def tuples(self) -> list:
-        """Legacy (digest, r, s, qx, qy) int tuples — the v1/v2
-        comparison kernels and host fallbacks consume these."""
+        """(digest, r, s, qx, qy) int tuples — the host fallbacks and
+        the sidecar's wire consume these."""
         out = [None] * self.n
         for arrs, row, ident, pos in self.entries:
             d, r, s = arrs
@@ -472,8 +478,8 @@ class ColumnarSigBatch:
         self.qx_res, self.qy_res, self.pub_ok = qx_res, qy_res, pub_ok
         self.slow = []
         self.n_fast = len(digest_b)
-        # per-fast-item identity (uid array + pool) — only for the
-        # v1/v2 tuples() compatibility path
+        # per-fast-item identity (uid array + pool) — only for
+        # tuples() (host fallbacks, the sidecar's wire)
         self.ident_of = ident_of
         self.idents = idents
 
@@ -517,8 +523,8 @@ class ColumnarSigBatch:
         return digest_b, r_b, s_b, qx_res, qy_res, pub_ok
 
     def tuples(self) -> list:
-        """Legacy int-tuple form (v1/v2 comparison kernels only);
-        pubkey ints come from the identity pool, not the residues."""
+        """Int-tuple form (host fallbacks, the sidecar's wire); pubkey
+        ints come from the identity pool, not the residues."""
         out = []
         for i in range(self.n_fast):
             ident = self.idents[int(self.ident_of[i])]
@@ -1462,6 +1468,6 @@ def _batch_len(items) -> int:
 
 
 def verify_host(items) -> list[bool]:
-    """items: iterable of (digest_int, r, s, qx, qy) Python ints —
-    same interface and accept set as ops.p256.verify_host."""
+    """items: iterable of (digest_int, r, s, qx, qy) Python ints, or
+    a column-form batch; the synchronous form of ``verify_launch``."""
     return verify_launch(items)()

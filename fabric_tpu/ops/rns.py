@@ -2,16 +2,16 @@
 "Cox-Rower" design (Kawamura et al., CHES 2000) that dedicated ECC
 hardware uses, re-expressed as TPU matmuls.
 
-Why RNS beats digit-polynomial arithmetic (ops.digits) on TPU: in RNS a
-256-bit value is its residues modulo ~23 small coprime primes, so a
-big-int multiply is an ELEMENTWISE lane-wise product — no convolution
-at all.  The only non-elementwise step is Montgomery reduction's base
-extension, which is a DENSE [B, 2n] @ [2n, 3n+…] matmul against a
-constant matrix — exactly the shape the MXU wants.  Contrast
-ops.digits.mul: a [B, K²=1849] @ [1849, 85] one-hot contraction that
-wastes ~99% of its MXU flops on structural zeros and needs HIGHEST
-(multi-pass) precision.  Here every matmul input is a 6-bit chunk, so
-single-pass bf16×bf16→f32 MXU arithmetic is EXACT by construction:
+Why RNS on TPU: a 256-bit value is its residues modulo ~23 small
+coprime primes, so a big-int multiply is an ELEMENTWISE lane-wise
+product — no convolution at all.  The only non-elementwise step is
+Montgomery reduction's base extension, which is a DENSE
+[B, 2n] @ [2n, 3n+…] matmul against a constant matrix — exactly the
+shape the MXU wants (a digit-polynomial multiply is a one-hot
+contraction whose MXU flops fall mostly on structural zeros and needs
+HIGHEST, multi-pass, precision).  Here every matmul input is a 6-bit
+chunk, so single-pass bf16×bf16→f32 MXU arithmetic is EXACT by
+construction:
 products ≤ 63·63 < 2^12, accumulated over ≤ 2n=46 rows < 2^18 « 2^24.
 
 Representation.  Two bases A = {m_1..m_n}, B = {m'_1..m'_n} of 12-bit
@@ -255,7 +255,7 @@ class RV:
     """An RNS value: [..., 2n] int32 canonical residues (base A ‖ B)
     plus a Python-int bound on the represented non-negative integer.
     The bound rides along tracing, so Montgomery/extension preconditions
-    are asserted while BUILDING the jaxpr (cf. ops.p256v2.FV)."""
+    are asserted while BUILDING the jaxpr."""
 
     __slots__ = ("arr", "bound")
 

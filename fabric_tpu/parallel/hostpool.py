@@ -331,7 +331,7 @@ class HostStagePool:
         return False
 
 
-def resolve_host_pool(workers: int, mode: str = "thread") -> HostStagePool | None:
+def resolve_host_pool(workers: int) -> HostStagePool | None:
     """Production knob → pool (the nodeconfig ``host_stage_workers``
     knob; mirrors parallel.mesh.resolve_mesh):
 
@@ -346,4 +346,4 @@ def resolve_host_pool(workers: int, mode: str = "thread") -> HostStagePool | Non
     n = cores if workers < 0 else min(workers, cores)
     if n < 2:
         return None
-    return HostStagePool(n, mode=mode)
+    return HostStagePool(n)

@@ -3,7 +3,7 @@ recovery probing.
 
 Before this module every failure on the device verify lane was
 happy-path: a TPU launch raising tore the whole deliver stream down,
-and the CPU ``ops/p256.verify_host`` path existed but nothing ever
+and the CPU ``ops/p256v3.verify_host`` path existed but nothing ever
 routed to it.  :class:`DeviceLaneGuard` is the state machine that
 makes the lane survivable, shared by ``BlockValidator`` and the
 crypto-free toy validators the chaos tests drive:
@@ -14,7 +14,7 @@ crypto-free toy validators the chaos tests drive:
   ``device_verify_retries_total``;
 * **degraded latch** — after ``fail_threshold`` CONSECUTIVE failed
   attempts the guard latches degraded: blocks route to the caller's
-  CPU fallback (``ops/p256.verify_host`` + the host MVCC path in the
+  CPU fallback (``ops/p256v3.verify_host`` + the host MVCC path in the
   real validator — correctness identical, the channel stays live),
   counted on ``fallback_blocks_total``, with the
   ``validator_degraded`` gauge at 1 and the state surfaced on

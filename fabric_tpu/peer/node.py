@@ -67,7 +67,6 @@ class PeerChannel:
                  mesh_topology=None,
                  coalesce_blocks: int = 0, host_stage_workers: int = 0,
                  recode_device: bool = False,
-                 host_stage_mode: str = "thread",
                  trace_ring_blocks: int | None = None,
                  trace_slow_factor: float | None = None,
                  device_fail_threshold: int = 0,
@@ -194,7 +193,7 @@ class PeerChannel:
             verify_chunk=verify_chunk, mesh_devices=mesh_devices,
             mesh_topology=mesh_topology,
             host_stage_workers=host_stage_workers,
-            recode_device=recode_device, host_stage_mode=host_stage_mode,
+            recode_device=recode_device,
             device_fail_threshold=device_fail_threshold,
             device_retries=device_retries,
             device_recovery_s=device_recovery_s,
@@ -1275,7 +1274,6 @@ class PeerNode:
                  mesh_devices: int = 0, mesh_topology=None,
                  coalesce_blocks: int = 0,
                  host_stage_workers: int = 0, recode_device: bool = False,
-                 host_stage_mode: str = "thread",
                  trace_ring_blocks: int | None = None,
                  trace_slow_factor: float | None = None,
                  slos: str = "",
@@ -1329,7 +1327,6 @@ class PeerNode:
         self.coalesce_blocks = int(coalesce_blocks)
         self.host_stage_workers = int(host_stage_workers)
         self.recode_device = bool(recode_device)
-        self.host_stage_mode = host_stage_mode
         # span-tracer knobs (None = leave the global tracer as-is)
         self.trace_ring_blocks = trace_ring_blocks
         self.trace_slow_factor = trace_slow_factor
@@ -1580,7 +1577,6 @@ class PeerNode:
             coalesce_blocks=self.coalesce_blocks,
             host_stage_workers=self.host_stage_workers,
             recode_device=self.recode_device,
-            host_stage_mode=self.host_stage_mode,
             trace_ring_blocks=self.trace_ring_blocks,
             trace_slow_factor=self.trace_slow_factor,
             device_fail_threshold=self.device_fail_threshold,

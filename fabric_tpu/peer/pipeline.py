@@ -1,5 +1,6 @@
 """The production commit pipeline: depth-N block overlap as a
-reusable subsystem shared by the peer node's deliver loop and bench.py.
+reusable subsystem shared by the peer node's deliver loop and the
+benchmark's harness (benchmark/harness.py).
 
 Shape at depth 3 (the TPU analog of the reference peer's deliver
 prefetch + committer overlap, gossip/state/state.go:540 + the

@@ -16,7 +16,7 @@ TPU-first re-ordering — compute first, control flow after:
                   (digest, r, s, qx, qy) tuples; bulk-load committed
                   versions for every read key.
   phase 1 (TPU)   ONE batched ECDSA verify over all signatures
-                  (ops.p256), ONE vectorized policy reduction per
+                  (ops.p256v3), ONE vectorized policy reduction per
                   distinct policy shape (peer.device_block).
   phase 2 (TPU)   ONE MVCC kernel call over the whole block (ops.mvcc)
                   with pre_ok = structural ∧ creator-sig ∧ policy.
@@ -49,7 +49,7 @@ from fabric_tpu.crypto.identity import Identity, sig_to_ints
 from fabric_tpu.ledger.rwset import TxRWSet
 from fabric_tpu.ledger.statedb import UpdateBatch
 from fabric_tpu.ops import mvcc as mvcc_ops
-from fabric_tpu.ops import p256
+from fabric_tpu.ops import p256v3
 from fabric_tpu.protos import common_pb2, configtx_pb2, transaction_pb2
 
 C = transaction_pb2.TxValidationCode
@@ -171,7 +171,7 @@ class PendingBlock:
     block: object
     txs: list
     items: object
-    fetch: object          # p256 VerifyHandle
+    fetch: object          # p256v3 VerifyHandle
     dpre: object           # _DevicePre or None
     overlay: object = None  # predecessor UpdateBatch (in-flight commit)
     fetch2: object = None   # stage-2 packed fetch, set by _launch_device
@@ -227,7 +227,7 @@ class _SlowItems:
 
 class _HostVerifyHandle:
     """A completed CPU verify masquerading as a fetch handle: the
-    degraded device lane routes blocks here (``ops/p256.verify_host``
+    degraded device lane routes blocks here (``ops/p256v3.verify_host``
     under ``faults.shield()``, pure-Python ``ec_ref`` as the last
     ditch).  It deliberately exposes NO ``device_out`` — the fused
     stage-2 program never launches for these blocks, so they take the
@@ -331,7 +331,6 @@ class BlockValidator:
         mesh_devices: int = 0,
         host_stage_workers: int = 0,
         recode_device: bool = False,
-        host_stage_mode: str = "thread",
         device_fail_threshold: int = 0,
         device_retries: int = 2,
         device_recovery_s: float = 30.0,
@@ -397,23 +396,10 @@ class BlockValidator:
         if self.host_stage_workers:
             from fabric_tpu.parallel.hostpool import resolve_host_pool
 
-            if host_stage_mode == "process":
-                # the validator's staging is SHARED-MEMORY by design:
-                # workers write row slabs into preallocated arrays in
-                # place and the fan-out submits bound methods/closures
-                # — neither crosses a process boundary.  Process mode
-                # is for custom picklable staging workloads on a
-                # directly-constructed HostStagePool; here it would
-                # crash the first validated block, so coerce loudly.
-                _log.warning(
-                    "host_stage_mode='process' is not usable for the "
-                    "validator's in-place staging; using threads (the "
-                    "staging hot loops release the GIL)"
-                )
-                host_stage_mode = "thread"
-            self.host_pool = resolve_host_pool(
-                self.host_stage_workers, mode=host_stage_mode
-            )
+            # threads: the staging is SHARED-MEMORY by design (workers
+            # write row slabs into preallocated arrays in place, and
+            # the hot loops release the GIL)
+            self.host_pool = resolve_host_pool(self.host_stage_workers)
         else:
             self.host_pool = None
         # window recoding location (nodeconfig ``recode_device``):
@@ -427,7 +413,7 @@ class BlockValidator:
         # device-lane degradation guard (peer/degrade.py, nodeconfig
         # device_fail_threshold / device_retries / device_recovery_s /
         # verify_deadline_ms): bounded-retry device launches that latch
-        # a degraded CPU mode (ops/p256.verify_host + the host MVCC
+        # a degraded CPU mode (ops/p256v3.verify_host + the host MVCC
         # path — correctness identical, the channel stays live) after
         # consecutive failures, with a periodic recovery probe.
         # threshold 0 = guard off entirely (today's raise-through
@@ -696,7 +682,7 @@ class BlockValidator:
         guard is configured — the zero-overhead default."""
 
         def launch():
-            return p256.verify_launch(
+            return p256v3.verify_launch(
                 items, chunk=self.verify_chunk or None, mesh=self.mesh,
                 pool=self.host_pool, recode_device=self.recode_device,
             )
@@ -717,7 +703,7 @@ class BlockValidator:
         ``fallback_blocks_total``)."""
 
         def launch():
-            return p256.verify_launch_many(
+            return p256v3.verify_launch_many(
                 itemsets, chunk=self.verify_chunk or None,
                 mesh=self.mesh, pool=pool,
                 recode_device=self.recode_device,
@@ -743,7 +729,7 @@ class BlockValidator:
         return _HostVerifyHandle(self._host_verify_fallback(items))
 
     def _host_verify_fallback(self, items) -> list:
-        """items → list[bool] on the CPU lane.  ``ops/p256.verify_host``
+        """items → list[bool] on the CPU lane.  ``ops/p256v3.verify_host``
         under ``faults.shield()`` first (the plain synchronous path);
         if even that lane is dead, the pure-Python ``ec_ref`` oracle
         verifies signature by signature — slow, dependency-free, and
@@ -753,7 +739,7 @@ class BlockValidator:
             return []
         try:
             with _faults.shield():
-                return [bool(v) for v in p256.verify_host(tuples)]
+                return [bool(v) for v in p256v3.verify_host(tuples)]
         except Exception as e:
             _log.warning(
                 "CPU verify_host lane failed too (%s) — falling back to "
@@ -1396,7 +1382,7 @@ class BlockValidator:
     def preprocess_many(self, blocks: list) -> list:
         """Coalesced ``preprocess`` over several in-flight blocks: each
         block parses as usual, then ALL their signature batches go up
-        in ONE concatenated verify dispatch (p256.verify_launch_many),
+        in ONE concatenated verify dispatch (p256v3.verify_launch_many),
         amortizing the ladder's dispatch latency across the blocks the
         pipeline has in flight.  Each returned tuple is a drop-in
         ``pre`` for ``validate_launch`` — the per-block VerifyHandle is
@@ -1962,8 +1948,8 @@ class BlockValidator:
         from fabric_tpu.ops import mvcc as mvcc_ops
         from fabric_tpu.utils.batching import block_shapes
 
-        if not txs or p256._KERNEL in ("v1", "v2"):
-            return None  # fused device path requires the v3 kernel
+        if not txs:
+            return None
         default = self.plugins.get("default")
         if type(default).__name__ != "DefaultValidation":
             return None

@@ -21,7 +21,7 @@ Flow per connection:
   frame, never a dropped request or an unbounded buffer;
 * a single dispatcher task drains cross-tenant batches of up to
   ``coalesce`` requests and launches them as ONE padded device
-  dispatch through ``ops.p256.verify_launch_many`` — the first time
+  dispatch through ``ops.p256v3.verify_launch_many`` — the first time
   the coalescing path merges genuinely concurrent traffic — then
   streams each batch's verdict vector back on its tenant's stream.
 
@@ -83,7 +83,7 @@ class SidecarServer:
 
     ``verify_fn(itemsets) -> list[list[bool]]`` runs on the device
     executor thread; the default routes through the mesh-resolved
-    ``ops.p256`` production dispatch (``mesh_devices`` /
+    ``ops.p256v3`` production dispatch (``mesh_devices`` /
     ``verify_chunk`` / ``recode_device`` mean exactly what they mean
     on ``BlockValidator``)."""
 
@@ -461,9 +461,9 @@ class SidecarServer:
     def _device_verify(self, itemsets: list) -> list:
         """The production path: ONE coalesced padded dispatch over the
         mesh for the whole cross-tenant group, then per-batch fetches."""
-        from fabric_tpu.ops import p256
+        from fabric_tpu.ops import p256v3
 
-        handles = p256.verify_launch_many(
+        handles = p256v3.verify_launch_many(
             itemsets, chunk=self.verify_chunk or None, mesh=self.mesh,
             recode_device=self.recode_device,
         )

@@ -5,7 +5,7 @@ device fabric ("a new BCCSP-style provider shipping signature batches
 over gRPC") — so the unit on the wire is one block's signature batch:
 a list of ``(e, r, s, qx, qy)`` integer tuples (digest, DER-split
 signature halves, public-key affine coordinates — exactly what
-``ops/p256.verify_host`` consumes), and the reply is that batch's
+``ops/p256v3.verify_host`` consumes), and the reply is that batch's
 boolean verdict vector.  Parse, policy evaluation and MVCC stay on
 the peer, which owns the state they read.
 

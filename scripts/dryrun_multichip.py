@@ -21,7 +21,7 @@ What one run proves, in order:
    ECDSA verify, and the fused stage-2 program, bit-equal per lane.
 
 Exit 0 = all green.  ``--out MULTICHIP_rNN.json`` records the run
-(the repo's MULTICHIP_r0*.json series) with ``extras.shard_balance``.
+(the repo keeps ``MULTICHIP_r06.json``) with ``extras.shard_balance``.
 """
 
 import json

@@ -3,8 +3,8 @@
 with no browser.
 
 Input (auto-detected):
-  * Chrome trace-event JSON written by ``Tracer.export_chrome`` /
-    ``FABTPU_BENCH_TRACE=trace.json`` ({"traceEvents": [...]}), or
+  * Chrome trace-event JSON written by ``Tracer.export_chrome``
+    ({"traceEvents": [...]}), or
   * a ``/trace`` endpoint dump (``curl :PORT/trace > dump.json`` —
     either the index payload or a single ``?block=N`` tree).
 

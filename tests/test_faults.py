@@ -451,13 +451,17 @@ class TestValidatorDeviceLane:
     def test_last_ditch_ecref_when_host_lane_dies(self, monkeypatch):
         items, want = _ecref_items()
         v = _real_validator(device_fail_threshold=1, device_retries=0)
-        from fabric_tpu.ops import p256
+        from fabric_tpu.ops import p256v3
+
+        calls = []
 
         def dead(*a, **kw):
+            calls.append(a)
             raise RuntimeError("jax runtime gone")
 
-        monkeypatch.setattr(p256, "verify_host", dead)
+        monkeypatch.setattr(p256v3, "verify_host", dead)
         assert [bool(x) for x in v._host_verify_fallback(items)] == want
+        assert calls  # the verdicts above are ec_ref's
 
 
 # -- /healthz surfaces a degraded lane (end-to-end, crypto-free) ------------

@@ -431,6 +431,32 @@ def test_pooled_staging_pipeline_matches_serial(key):
         assert DeviceToyValidator.VALID in flt
 
 
+def _signed_network(n_tx: int, n_blocks: int):
+    """chip_smoke's network (3 orgs, 2-of-3) and stream (each tx reads
+    a cold and a hot preloaded key, rewrites the cold one and writes a
+    fresh one), tiny, over an in-memory state → (blocks, fresh_state,
+    mgr, prov)."""
+    import chip_smoke
+
+    size = chip_smoke.Size(n_tx=n_tx, n_blocks=n_blocks, preload_keys=64,
+                           hot_keys=8)
+    mgr, prov, endorsers, client = chip_smoke.build_network()
+    blocks, _plan = chip_smoke.build_blocks(
+        size, np.random.default_rng(7), endorsers, client
+    )
+
+    def fresh_state():
+        seed = UpdateBatch()
+        for j in range(size.preload_keys):
+            seed.put(chip_smoke.CC, chip_smoke.key_name(j), b"genesis",
+                     chip_smoke.PRELOAD_VERSION)
+        db = MemVersionedDB()
+        db.apply_updates(seed, chip_smoke.PRELOAD_VERSION)
+        return db
+
+    return blocks, fresh_state, mgr, prov
+
+
 def test_pooled_block_validator_preprocess_many(tmp_path):
     """BlockValidator._preprocess_many_pooled (parse fan-out + pooled
     device_pre + pooled coalesced staging) vs the serial
@@ -438,12 +464,10 @@ def test_pooled_block_validator_preprocess_many(tmp_path):
     validate_launch/finish.  Crypto-gated — the seed condition on
     containers without the ``cryptography`` package."""
     pytest.importorskip("cryptography")
-    from bench import _build_commit_network
     from fabric_tpu.peer.validator import BlockValidator
     from fabric_tpu.protos import common_pb2
 
-    (blocks, fresh_state, _fv, mgr, prov, _cc,
-     _ninv) = _build_commit_network(6, 2)
+    blocks, fresh_state, mgr, prov = _signed_network(6, 2)
 
     def run(workers, recode):
         state = fresh_state()
@@ -474,11 +498,9 @@ def test_full_validator_sharded_block(tmp_path):
     the pipelined validator.  Crypto-gated — the seed condition on
     containers without the ``cryptography`` package."""
     pytest.importorskip("cryptography")
-    from bench import _build_commit_network
-
-    (blocks, fresh_state, _fresh_validator, mgr, prov, _cc,
-     _ninv) = _build_commit_network(6, 2)
     from fabric_tpu.peer.validator import BlockValidator
+
+    blocks, fresh_state, mgr, prov = _signed_network(6, 2)
 
     def run(mesh_devices):
         state = fresh_state()

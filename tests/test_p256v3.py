@@ -1,12 +1,14 @@
 """RNS ECDSA kernel (ops.rns + ops.p256v3) verification tests.
 
-Oracle layers mirror tests/test_p256v2.py:
+Oracle layers:
 1. field core — tests/test_rns.py;
 2. RCB complete point formulas over RNS vs crypto.ec_ref point ops,
    including the degenerate lanes (doubling, inverses, infinity);
 3. full verify_batch vs the reference accept set
    (bccsp/sw/ecdsa.go:41-58 semantics: low-S, ranges, on-curve).
 """
+
+import hashlib
 
 import numpy as np
 import jax.numpy as jnp
@@ -136,6 +138,133 @@ def test_verify_matches_oracle_randomized(keys, rng):
     want = [ec_ref.verify_digest((x, y), e, r, s) for (e, r, s, x, y) in items]
     assert got == want
     assert any(want) and not all(want)
+
+
+def test_verify_batch_valid_and_corrupted(rng):
+    keys = [
+        ec_ref.SigningKey(d=int.from_bytes(rng.bytes(40), "big") % (ec_ref.N - 1) + 1)
+        for _ in range(4)
+    ]
+    items, want = [], []
+    for i in range(16):
+        sk = keys[i % len(keys)]
+        msg = b"payload-%d" % i
+        e = ec_ref.digest_int(msg)
+        r, s = sk.sign_digest(e)
+        qx, qy = sk.public
+        kind = i % 4
+        if kind == 0:  # valid
+            items.append((e, r, s, qx, qy))
+            want.append(True)
+        elif kind == 1:  # corrupted digest
+            items.append((e ^ 1, r, s, qx, qy))
+            want.append(False)
+        elif kind == 2:  # corrupted s
+            items.append((e, r, (s + 1) % ec_ref.N, qx, qy))
+            want.append(False)
+        else:  # wrong key
+            ox, oy = keys[(i + 1) % len(keys)].public
+            items.append((e, r, s, ox, oy))
+            want.append(False)
+    got = v3.verify_host(items)
+    assert got == want
+    # agree with the pure-python oracle on every case
+    for (e, r, s, qx, qy), g in zip(items, got):
+        assert ec_ref.verify_digest((qx, qy), e, r, s) == g
+
+
+def test_verify_against_openssl_generated():
+    """Cross-check with OpenSSL-generated (non-low-S-normalized) sigs."""
+    from cryptography.hazmat.primitives.asymmetric import ec as cec
+    from cryptography.hazmat.primitives import hashes
+    from cryptography.hazmat.primitives.asymmetric.utils import decode_dss_signature
+
+    items, want = [], []
+    for i in range(16):
+        key = cec.generate_private_key(cec.SECP256R1())
+        pub = key.public_key().public_numbers()
+        msg = b"openssl-%d" % i
+        sig = key.sign(msg, cec.ECDSA(hashes.SHA256()))
+        r, s = decode_dss_signature(sig)
+        if s > ec_ref.HALF_N:
+            s = ec_ref.N - s  # normalize as the reference signer does
+        e = int.from_bytes(hashlib.sha256(msg).digest(), "big")
+        items.append((e, r, s, pub.x, pub.y))
+        want.append(True)
+    assert v3.verify_host(items) == want
+
+
+def _forge(pub, seed: int):
+    """A low-S (e, r, s) that verifies under ``pub`` without its private
+    key: R = u1·G + u2·Q, r = x(R) mod n, s = r/u2, e = u1·s (mod n)."""
+    G = (ec_ref.GX, ec_ref.GY)
+    for u in range(seed, seed + 64):
+        u1, u2 = u, u + 7
+        R = ec_ref.pt_add(ec_ref.pt_mul(u1, G), ec_ref.pt_mul(u2, pub))
+        r = R[0] % ec_ref.N
+        s = r * pow(u2, -1, ec_ref.N) % ec_ref.N
+        if 0 < r and 0 < s <= ec_ref.HALF_N:
+            return u1 * s % ec_ref.N, r, s
+    raise AssertionError("no low-S forgery in 64 tries")
+
+
+# the accept set, one admission rule a lane (bccsp/sw/ecdsa.go:41-58)
+ACCEPT_SET_CASES = (
+    "valid_low_s", "high_s", "r_zero", "s_zero", "r_eq_n", "s_eq_n",
+    "r_plus_n", "s_plus_one", "digest_bit_flipped", "other_keys_point",
+    "negated_point", "off_curve_qy_plus_one", "coordinate_ge_p",
+    "q_zero_zero", "digest_zero", "digest_plus_n",
+)
+
+
+@pytest.fixture(scope="module")
+def accept_set(keys):
+    """The sixteen lanes and the kernel's verdicts on them: ONE 16-lane
+    launch a module."""
+    N = ec_ref.N
+    sk, other = keys[0], keys[1]
+    qx, qy = sk.public
+    e = ec_ref.digest_int(b"accept-set")
+    r, s = sk.sign_digest(e)
+    r0, s0 = sk.sign_digest(0)
+    e_small = 0x1234567
+    rs, ss = sk.sign_digest(e_small)
+    # x = 0 is on the curve (y² = b): presented as x = P the point is
+    # the same mod P, and only the range rule rejects it
+    y0 = pow(ec_ref.B, (P + 1) // 4, P)
+    assert ec_ref.is_on_curve((0, y0))
+    ef, rf, sf = _forge((0, y0), 0xACCE97)
+    assert ec_ref.verify_digest((0, y0), ef, rf, sf)
+    lanes = {
+        "valid_low_s": (e, r, s, qx, qy),
+        "high_s": (e, r, N - s, qx, qy),
+        "r_zero": (e, 0, s, qx, qy),
+        "s_zero": (e, r, 0, qx, qy),
+        "r_eq_n": (e, N, s, qx, qy),
+        "s_eq_n": (e, r, N, qx, qy),
+        "r_plus_n": (e, r + N if r + N < 1 << 256 else (1 << 256) - 1,
+                     s, qx, qy),
+        "s_plus_one": (e, r, s + 1, qx, qy),
+        "digest_bit_flipped": (e ^ (1 << 77), r, s, qx, qy),
+        "other_keys_point": (e, r, s, *other.public),
+        "negated_point": (e, r, s, qx, P - qy),
+        "off_curve_qy_plus_one": (e, r, s, qx, (qy + 1) % P),
+        "coordinate_ge_p": (ef, rf, sf, P, y0),
+        "q_zero_zero": (e, r, s, 0, 0),
+        "digest_zero": (0, r0, s0, qx, qy),
+        "digest_plus_n": (e_small + N, rs, ss, qx, qy),
+    }
+    assert tuple(lanes) == ACCEPT_SET_CASES
+    items = list(lanes.values())
+    return dict(zip(lanes, zip(items, v3.verify_host(items))))
+
+
+@pytest.mark.parametrize("case", ACCEPT_SET_CASES)
+def test_accept_set(accept_set, case):
+    (e, r, s, qx, qy), got = accept_set[case]
+    assert got == ec_ref.verify_digest((qx, qy), e, r, s), case
+    # the three lanes that must verify; every other breaks one rule
+    assert got == (case in ("valid_low_s", "digest_zero", "digest_plus_n"))
 
 
 def test_chunked_launch_matches_monolithic(keys, rng):

@@ -2,7 +2,7 @@
 predecessor-overlay, in-flight dup-txid checks, and the committer-thread
 overlap — the depth-2 pipeline the production CommitPipeline
 (peer/pipeline.py) drives for both the node's deliver loop and
-bench.py, pinned against the serial validate() verdicts.  The
+the benchmark's harness, pinned against the serial validate() verdicts.  The
 crypto-free pipeline-engine semantics live in
 tests/test_commit_pipeline.py."""
 
@@ -151,7 +151,7 @@ def test_overlay_range_phantom(net):
 
 def test_pipelined_stream_matches_serial(net):
     """Full depth-2 pipelined drive (prefetch + committer threads, as
-    in bench.py) over a dependent stream — filters and final state must
+    CommitPipeline runs them) over a dependent stream — filters and final state must
     equal the serial validate()+commit run.  Blocks with range queries
     ride along, exercising the state-DB iteration lock against the
     concurrent apply_updates."""
@@ -458,7 +458,7 @@ def test_commit_pipeline_resident_state_matches_serial(net):
     BlockValidator ≡ the host state_fill oracle — a hot key re-read
     every block (residency hits), k→k+1 reads crossing the in-flight
     window, per-block stale lanes and deletes churning the cache —
-    verdict- and state-identical at depths 2 and 3, plus an 8-slot
+    verdict- and state-identical at depths 2 and 3, plus a 32-slot
     eviction-churn variant."""
     from fabric_tpu.state import ResidencyManager
 
@@ -507,9 +507,13 @@ def test_commit_pipeline_resident_state_matches_serial(net):
         )
         assert v_p.resident is not None
         if tiny:
-            # eviction churn: an 8-slot table over this stream keeps
-            # admitting and evicting, never changing a verdict
-            v_p.resident = ResidencyManager(slots=8, range_bits=2)
+            # eviction churn: a block asks for 18-20 keys (reads,
+            # writes and the padding txs'), and a table smaller than
+            # one block's set sends the block to the host path with
+            # nothing admitted.  32 slots hold one block's set and not
+            # two, so from the second block on every launch evicts,
+            # never changing a verdict
+            v_p.resident = ResidencyManager(slots=32, range_bits=4)
         filters = []
 
         def commit_fn(res, _state=state_p):

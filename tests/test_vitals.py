@@ -13,8 +13,7 @@ Layers:
 * bundle bounds: per-kind rate limiting and the size cap's honest
   ``truncated`` section list;
 * ``/vitals`` round-trip over a live OperationsServer (index +
-  ?metric + ?incident + 404s + unarmed honesty);
-* the bench-extras capture smoke (``FABTPU_BENCH_VITALS``).
+  ?metric + ?incident + 404s + unarmed honesty).
 """
 
 import json
@@ -766,30 +765,3 @@ def test_blackbox_view_renders_postmortem(tmp_path):
     path = next(tmp_path.iterdir())
     rc = blackbox_view.main([str(path), "--no-traces"])
     assert rc == 0
-
-
-# ---------------------------------------------------------------------------
-# bench-extras capture smoke
-
-
-def test_bench_vitals_capture_smoke(monkeypatch):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, root)
-    import bench
-
-    monkeypatch.delenv("FABTPU_BENCH_VITALS", raising=False)
-    assert bench._vitals_capture() is None
-    assert bench._vitals_extras(None) is None
-    monkeypatch.setenv("FABTPU_BENCH_VITALS", "1")
-    monkeypatch.setenv("FABTPU_BENCH_VITALS_INTERVAL_S", "0.01")
-    s = bench._vitals_capture()
-    assert s is not None
-    from fabric_tpu.ops_metrics import global_registry
-
-    global_registry().counter("bench_vitals_smoke_total", "t").add(3)
-    extras = bench._vitals_extras(s)
-    assert extras is not None and extras["series_count"] > 0
-    smoke = extras["series"]["bench_vitals_smoke_total"]["_"]
-    assert smoke["kind"] == "counter"
-    assert sum(v for _t, v in smoke["points"]) == 3.0
-    json.dumps(extras)  # BENCH_*.json-serializable end to end
