@@ -155,7 +155,8 @@ class AsyncApplyEngine(VersionedDB):
         Blocks at the block boundary while the queue is at capacity
         (the backpressure latch).  ``post_apply`` (optional, no-arg)
         runs on the applier thread after the batch lands — the
-        history-DB commit rides here."""
+        history-DB commit rides here, and returns ``(rows, statements)``
+        for its ``apply.history`` span (or None)."""
         # on the committer thread, inside the pipeline's ``commit``
         # span: its root is the block's
         entry = _Pending(num, batch, savepoint, post_apply,
@@ -244,13 +245,31 @@ class AsyncApplyEngine(VersionedDB):
                     self._blocks.ensure_synced(entry.num)
                 _txflow.block_durable(entry.num)
             t0 = time.perf_counter()
+            # ``apply.write``: ``writes`` is the block's rows; where the
+            # inner DB counts its statements (``SqliteVersionedDB``),
+            # ``stmts`` is what its block path sent to the state table
+            # for this block (1: one namespace, no delete) and ``path``
+            # says which way the block went, ``block`` or ``per_key``.
+            # ``apply.history``: ``rows`` and ``stmts`` as the history
+            # DB's ``commit_block`` returns them.
+            inner = self._inner
             with tracer.span("apply.write") as wsp:
-                self._inner.apply_updates(entry.batch, entry.sp)
+                if wsp is not None:
+                    stmts0 = getattr(inner, "apply_statements", None)
+                    fast0 = getattr(inner, "apply_fast_blocks", 0)
+                inner.apply_updates(entry.batch, entry.sp)
             if wsp is not None:
                 wsp.attrs["writes"] = _n_writes(entry.batch)
+                if stmts0 is not None:
+                    wsp.attrs["stmts"] = inner.apply_statements - stmts0
+                    wsp.attrs["path"] = (
+                        "block" if inner.apply_fast_blocks > fast0
+                        else "per_key")
             if entry.post_apply is not None:
-                with tracer.span("apply.history"):
-                    entry.post_apply()
+                with tracer.span("apply.history") as hsp:
+                    sent = entry.post_apply()
+                if hsp is not None and sent is not None:
+                    hsp.attrs["rows"], hsp.attrs["stmts"] = sent
             dur = time.perf_counter() - t0
             # the decoupled path's visibility edge: the block's writes
             # (and history) became readable HERE, on the applier thread

@@ -5,6 +5,8 @@ from __future__ import annotations
 
 import sqlite3
 
+from fabric_tpu.ledger.sqlrows import row_statements
+
 
 class HistoryDB:
     def __init__(self, path: str):
@@ -26,16 +28,25 @@ class HistoryDB:
             " id INTEGER PRIMARY KEY CHECK (id = 0), block INTEGER)"
         )
 
-    def commit_block(self, block_num: int, writes: list[tuple[str, str, int]]):
-        """writes: [(ns, key, txnum)] for VALID txs of the block."""
-        self._conn.executemany(
-            "INSERT OR REPLACE INTO hist VALUES (?,?,?,?)",
-            [(ns, key, block_num, txnum) for ns, key, txnum in writes],
-        )
+    def commit_block(self, block_num: int,
+                     writes: list[tuple[str, str, int]]) -> tuple[int, int]:
+        """writes: [(ns, key, txnum)] for VALID txs of the block, as one
+        statement (``sqlrows``; sqlite >= 3.7.11 takes the multi-row
+        ``VALUES``).  → ``(rows, statements)``, what the applier's
+        ``apply.history`` span carries."""
+        rows = [(ns, key, block_num, txnum) for ns, key, txnum in writes]
+        stmts = 0
+        for sql, params in row_statements(
+            self._conn, rows,
+            head="INSERT OR REPLACE INTO hist VALUES", width=4,
+        ):
+            self._conn.execute(sql, params)
+            stmts += 1
         self._conn.execute(
             "INSERT OR REPLACE INTO savepoint VALUES (0,?)", (block_num,)
         )
         self._conn.commit()
+        return len(rows), stmts
 
     def get_history_for_key(self, ns: str, key: str):
         """Yield (block, txnum) newest-first (like the reference's

@@ -169,7 +169,7 @@ class KVLedger:
                 hist = self.history
 
                 def post_apply(hist=hist, num=num, hw=history_writes):
-                    hist.commit_block(num, hw)
+                    return hist.commit_block(num, hw)
 
             self.engine.submit(num, batch, (num, 0), post_apply=post_apply)
         else:

@@ -31,6 +31,9 @@ import time
 from bisect import bisect_left
 from contextlib import contextmanager
 from dataclasses import dataclass
+from itertools import chain
+
+from fabric_tpu.ledger.sqlrows import row_statements
 
 Version = tuple[int, int]
 
@@ -110,8 +113,7 @@ class ColumnarUpdateBatch(UpdateBatch):
     — launch overlays, ``merged()``, the mem backend — behaves
     byte-for-byte like the dict batch), while
     ``SqliteVersionedDB.apply_updates`` consumes the slabs directly:
-    one ``executemany`` per namespace, zero-copy memoryview value
-    slices.
+    one statement per namespace, zero-copy memoryview value slices.
 
     ``put``/``delete`` after construction (the pvt hashed-write phase,
     BTL purge) land in a small ``_extra`` override dict that shadows
@@ -192,10 +194,11 @@ class ColumnarUpdateBatch(UpdateBatch):
 
     def sqlite_columns(self):
         """→ yields ``(deletes, rows)`` per namespace for the sqlite
-        fast path: ``deletes`` = [(ns, key)], ``rows`` = executemany
-        tuples with zero-copy memoryview value slices.  Per-key
+        block path: ``deletes`` = [(ns, key)], ``rows`` = the table's
+        six columns with zero-copy memoryview value slices.  Per-key
         last-wins dedupe (a later tx's write of the same key shadows
-        the earlier row, exactly like the dict build), and rows
+        the earlier row, exactly like the dict build: no key comes
+        twice, in one list or across the two), and rows
         shadowed by ``_extra`` overrides are skipped — the caller
         applies the extras through the classic per-key path."""
         last: dict = {}  # uid -> last row index
@@ -409,6 +412,28 @@ class MemVersionedDB(VersionedDB):
         return self._savepoint
 
 
+# The two statements of ``SqliteVersionedDB.apply_updates``' block path,
+# as ``sqlrows.row_statements`` takes them.  The delete names its rows
+# through the primary-key index and removes them by rowid: ``WHERE (ns,
+# key) IN (VALUES ...)`` with two rows or more is planned as a scan of
+# the whole table.  Its text STARTS with ``DELETE``: the ``sqlite3``
+# module opens the block's transaction before a statement whose first
+# word is INSERT, UPDATE, DELETE or REPLACE and before no other, so a
+# ``WITH ... DELETE`` that came first in a block would commit alone.
+_UPSERT_ROWS = dict(
+    head="INSERT INTO state VALUES", width=6,
+    tail=" ON CONFLICT(ns, key) DO UPDATE SET value=excluded.value,"
+         " metadata=excluded.metadata, block=excluded.block,"
+         " txnum=excluded.txnum",
+)
+_DELETE_ROWS = dict(
+    head="DELETE FROM state WHERE rowid IN (WITH d(ns, key) AS (VALUES",
+    width=2,
+    tail=") SELECT s.rowid FROM d CROSS JOIN state AS s"
+         " ON s.ns = d.ns AND s.key = d.key)",
+)
+
+
 class SqliteVersionedDB(VersionedDB):
     """Durable backend over sqlite (WAL mode).
 
@@ -439,6 +464,13 @@ class SqliteVersionedDB(VersionedDB):
         # contended acquire reads the clock; updated while holding the
         # lock.  The engine's ``sf.gather`` span carries the delta.
         self.rd_wait_s = 0.0
+        # blocks ``apply_updates`` took by its block path (the others
+        # took the per-key loop), and the statements that path sent to
+        # ``state`` (one a namespace; two where it also deletes).  Plain
+        # integers of the applier thread: ``apply.write`` carries the
+        # block's share as ``path`` and ``stmts``.
+        self.apply_fast_blocks = 0
+        self.apply_statements = 0
 
     def open(self):
         self._conn = sqlite3.connect(self.path, check_same_thread=False)
@@ -592,6 +624,19 @@ class SqliteVersionedDB(VersionedDB):
             yield key, VersionedValue(value, md, (blk, txn))
 
     def apply_updates(self, batch, savepoint):
+        """One transaction a block: the rows, the savepoint, ``commit()``.
+
+        A columnar batch with no metadata, on a DB that tracks none,
+        goes as one statement a namespace (``sqlrows``): a multi-row
+        upsert that rewrites a key that exists where it lies (sqlite >=
+        3.24).  ``INSERT OR REPLACE`` would delete the row and insert a
+        new one at the table's end, under a new rowid, and so dirty the
+        old leaf, a new one and the key's leaf of the primary-key index;
+        the update arm dirties one.  A reader sees the same
+        ``value, metadata, block, txnum`` either way, and nothing reads
+        a rowid.  Everything else (key-level endorsement metadata, an
+        ``UpdateBatch``, a columnar batch's ``_extra`` overrides) takes
+        the per-key loop, which needs the per-key metadata probe."""
         cur = self._conn.cursor()
         # meta_count == 0 ⇒ no existing row carries metadata, so the
         # per-key decrement probe is skippable (keeps the common
@@ -599,19 +644,17 @@ class SqliteVersionedDB(VersionedDB):
         track = self.meta_count > 0
         if (not track and not batch.has_meta
                 and isinstance(batch, ColumnarUpdateBatch)):
-            # columnar fast path: one executemany per namespace over
-            # the validator's slabs — no dict materialization, no
-            # VersionedValue churn, zero-copy value blobs
+            # values stay zero-copy slices of the validator's slab; no
+            # dict materialization, no VersionedValue churn
+            conn = self._conn
             for dels, rows in batch.sqlite_columns():
-                if dels:
-                    cur.executemany(
-                        "DELETE FROM state WHERE ns=? AND key=?", dels
-                    )
-                if rows:
-                    cur.executemany(
-                        "INSERT OR REPLACE INTO state VALUES (?,?,?,?,?,?)",
-                        rows,
-                    )
+                for sql, params in chain(
+                    row_statements(conn, dels, **_DELETE_ROWS),
+                    row_statements(conn, rows, **_UPSERT_ROWS),
+                ):
+                    cur.execute(sql, params)
+                    self.apply_statements += 1
+            self.apply_fast_blocks += 1
             items = batch.extra_items()
         else:
             items = batch.items()

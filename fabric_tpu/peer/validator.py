@@ -2446,7 +2446,7 @@ class BlockValidator:
         """Columnar update batch + history from the native flat write
         arrays — the batch keeps the validator's numpy slabs
         (ColumnarUpdateBatch) so the sqlite backend can apply it with
-        one executemany per namespace, and its lazy ``updates`` dict is
+        one statement per namespace, and its lazy ``updates`` dict is
         byte-identical (incl. per-tx (ns, key) sort order) to the old
         eager build over parsed rwsets.  Key strings come from the
         already-decoded unique-key table (``ukeys``)."""

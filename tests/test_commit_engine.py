@@ -577,6 +577,9 @@ def test_apply_history_span_and_backpressure_on_commit_enqueue(tmp_path):
         ap = next(c for c in root.children if c.name == "apply")
         assert [c.name for c in ap.children] == [
             "apply.fence", "apply.write", "apply.history"]
+        assert ap.children[1].attrs == {
+            "writes": 1, "stmts": 0, "path": "per_key"}  # an UpdateBatch
+        assert ap.children[2].attrs == {"rows": 1, "stmts": 1}
 
 
 @pytest.mark.parametrize("armed", [False, True])
@@ -609,6 +612,89 @@ def test_apply_never_builds_a_columnar_batchs_dict(tmp_path, armed):
     if armed:
         ap = next(c for c in root.children if c.name == "apply")
         write = next(c for c in ap.children if c.name == "apply.write")
-        assert write.attrs == {"writes": 4 + 1}   # slab rows + override
+        # slab rows + override; an upsert for ``ns``, a delete for ``zz``
+        assert write.attrs == {"writes": 4 + 1, "stmts": 2, "path": "block"}
     else:
         assert root is None
+
+
+class _WatchedDB:
+    """A state DB behind a proxy that notes which of its attributes the
+    engine reads."""
+
+    def __init__(self, db):
+        self._db = db
+        self.read = set()
+
+    def __getattr__(self, name):
+        self.read.add(name)
+        return getattr(self._db, name)
+
+
+@pytest.mark.parametrize("armed", [False, True])
+def test_apply_spans_say_how_the_block_was_written(tmp_path, armed):
+    """A traced block's ``apply.write`` carries ``writes``, the
+    statements its block path sent the state table (``stmts``, 1 for a
+    one-namespace block) and ``path``; ``apply.history`` carries what
+    the history DB's ``commit_block`` returns.  A backend that counts no
+    statements gets neither, and disarmed nothing is read."""
+    from fabric_tpu.ledger.history import HistoryDB
+    from fabric_tpu.observe import global_tracer
+
+    tracer = global_tracer()
+    was = tracer.ring_blocks
+    tracer.configure(ring_blocks=4 if armed else 0)
+    sq = SqliteVersionedDB(str(tmp_path / "state.db"))
+    sq.open()
+    hist = HistoryDB(str(tmp_path / "history.db"))
+    blob = b"AAABB"
+    one_ns = ColumnarUpdateBatch(
+        1, ["ns"], ["a", "b"], np.array([0, 0]), np.array([0, 1]),
+        np.array([False, False]), np.array([0, 3]), np.array([3, 2]),
+        np.array([0, 1], np.int64), blob)
+    per_key = UpdateBatch()
+    per_key.put("ns", "c", b"c", (2, 0))
+    mem_batch = UpdateBatch()
+    mem_batch.put("ns", "c", b"c", (1, 0))
+    hw = [("ns", "a", 0), ("ns", "b", 1)]
+    watched = [_WatchedDB(sq), _WatchedDB(MemVersionedDB())]
+    roots = []
+    try:
+        for inner, blocks in zip(watched, (
+            [(1, one_ns, lambda: hist.commit_block(1, hw)),
+             (2, per_key, lambda: None)],
+            [(1, mem_batch, None)],
+        )):
+            eng = AsyncApplyEngine(inner)
+            try:
+                for num, batch, post in blocks:
+                    root = tracer.begin_block(num)
+                    roots.append(root)
+                    with tracer.span("commit", parent=root):
+                        eng.submit(num, batch, (num, 0), post_apply=post)
+                    tracer.finish_block(root)
+                eng.drain()
+                assert inner.get_state("ns", "c").value == b"c"
+            finally:
+                eng.close()               # and the DB behind it
+        assert list(hist.get_history_for_key("ns", "b")) == [(1, 1)]
+    finally:
+        hist.close()
+        tracer.configure(ring_blocks=was)
+    counters = {"apply_statements", "apply_fast_blocks"}
+    if not armed:
+        assert roots == [None] * 3
+        assert not counters & (watched[0].read | watched[1].read)
+        return
+    assert counters <= watched[0].read
+    spans = []
+    for root in roots:
+        ap = next(c for c in root.children if c.name == "apply")
+        spans.append({c.name: c.attrs for c in ap.children})
+    assert spans == [
+        {"apply.write": {"writes": 2, "stmts": 1, "path": "block"},
+         "apply.history": {"rows": 2, "stmts": 1}},
+        {"apply.write": {"writes": 1, "stmts": 0, "path": "per_key"},
+         "apply.history": {}},
+        {"apply.write": {"writes": 1}},
+    ]

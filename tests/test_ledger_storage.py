@@ -2,16 +2,24 @@
 history, kvledger commit-hash chain + crash recovery (scenarios
 modeled on the reference's blkstorage/kvledger test coverage)."""
 
+import math
 import os
+import random
+import sqlite3
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from fabric_tpu import protoutil as pu
 from fabric_tpu.ledger.blockstore import BlockStore
 from fabric_tpu.ledger.kvledger import KVLedger
+from fabric_tpu.ledger import statedb
+from fabric_tpu.ledger.history import HistoryDB
+from fabric_tpu.ledger.sqlrows import row_statements
 from fabric_tpu.ledger.statedb import (
+    ColumnarUpdateBatch,
     MemVersionedDB,
     SqliteVersionedDB,
     UpdateBatch,
@@ -62,6 +70,337 @@ def test_statedb_range_and_rich_query(db):
     assert got == ["key0", "key1", "key2"]
     rich = [k for k, _ in db.execute_query("ns", {"selector": {"color": "red"}})]
     assert rich == [f"key{i}" for i in range(10) if i % 2]
+
+
+# ---------------------------------------------------------------------------
+# the sqlite backend's block path: one statement a table a block, a key
+# that exists rewritten where it lies (``SqliteVersionedDB.apply_updates``)
+
+
+def _columnar(block_num, ops):
+    """A ``ColumnarUpdateBatch`` as the validator builds one, from
+    ``ops`` = [(ns, key, value | None, txnum)] in apply order (None
+    deletes; a key may come more than once, the last wins)."""
+    ns_names = sorted({op[0] for op in ops})
+    uids, ukeys, ns_of = {}, [], []
+    row_uid, row_del, voff, vlen, txnums = [], [], [], [], []
+    blob = bytearray()
+    for ns, key, value, txnum in ops:
+        uid = uids.setdefault((ns, key), len(uids))
+        if uid == len(ukeys):
+            ukeys.append(key)
+            ns_of.append(ns_names.index(ns))
+        row_uid.append(uid)
+        row_del.append(value is None)
+        voff.append(len(blob))
+        vlen.append(len(value or b""))
+        blob += value or b""
+        txnums.append(txnum)
+    return ColumnarUpdateBatch(
+        block_num, ns_names, ukeys, np.array(ns_of, np.int64),
+        np.array(row_uid, np.int64), np.array(row_del, bool),
+        np.array(voff, np.int64), np.array(vlen, np.int64),
+        np.array(txnums, np.int64), bytes(blob))
+
+
+@pytest.fixture
+def sq(tmp_path):
+    d = SqliteVersionedDB(str(tmp_path / "state.db"))
+    d.open()
+    yield d
+    d.close()
+
+
+def _state_statements(conn):
+    """→ the list that collects what ``conn`` is sent for ``state``."""
+    sent = []
+    conn.set_trace_callback(
+        lambda q: sent.append(q) if " state" in q else None)
+    return sent
+
+
+def _set_max_vars(conn, n):
+    conn.setlimit(sqlite3.SQLITE_LIMIT_VARIABLE_NUMBER, n)
+
+
+@pytest.mark.parametrize("seed", [7, 2026])
+def test_sqlite_block_path_lands_what_the_mem_backend_lands(sq, seed):
+    """30 seeded columnar blocks over two namespaces (rewrites of keys
+    that exist, fresh keys, a key written twice in a block, deletes of
+    present and of absent keys, a post-build override) leave the sqlite
+    backend where the same batches leave ``MemVersionedDB``."""
+    rng = random.Random(seed)
+    mem = MemVersionedDB()
+    mem.open()
+    pool = [(ns, f"k{i:02d}") for ns in ("cc", "lscc") for i in range(24)]
+    for num in range(1, 31):
+        ops = []
+        for txnum in range(rng.randint(1, 12)):
+            for ns, key in rng.sample(pool, rng.randint(1, 3)):
+                value = (None if rng.random() < 0.2
+                         else rng.randbytes(rng.randint(0, 40)))
+                ops.append((ns, key, value, txnum))
+            ops.append(("cc", f"w{num}_{txnum}", b"fresh", txnum))
+        ns, key = rng.choice(pool)
+        ops.append((ns, key, b"first", 98))   # twice in the block
+        ops.append((ns, key, b"second", 99))
+        batches = [_columnar(num, ops), _columnar(num, ops)]
+        if num % 5 == 0:
+            for b in batches:                 # the pvt / BTL phase
+                b.put("cc", "k03", b"override", (num, 100))
+                b.delete("lscc", "k05", (num, 101))
+        sq.apply_updates(batches[0], (num, 0))
+        mem.apply_updates(batches[1], (num, 0))
+        assert list(sq.iter_all()) == list(mem.iter_all())
+        assert sq.savepoint() == mem.savepoint() == (num, 0)
+    assert sq.get_state(ns, key).value == b"second"
+    assert sq.apply_fast_blocks == 30
+    assert sq.meta_count == mem.meta_count == 0
+
+
+def test_sqlite_block_path_rewrites_a_key_where_it_lies(sq):
+    """The upsert's update arm keeps the row (``INSERT OR REPLACE``
+    deleted it and inserted another at the table's end): a rewritten
+    key keeps its rowid, a fresh key gets the next one."""
+    def rowids():
+        return dict(sq._conn.execute("SELECT key, rowid FROM state"))
+
+    sq.apply_updates(_columnar(1, [("ns", "a", b"a1", 0),
+                                   ("ns", "b", b"b1", 1)]), (1, 0))
+    before = rowids()
+    sq.apply_updates(_columnar(2, [("ns", "a", b"a2-longer", 0),
+                                   ("ns", "c", b"c2", 1)]), (2, 0))
+    after = rowids()
+    assert after["a"] == before["a"] and after["b"] == before["b"]
+    assert after["c"] > max(before.values())
+    vv = sq.get_state("ns", "a")
+    assert (vv.value, vv.metadata, vv.version) == (b"a2-longer", None, (2, 0))
+    assert sq.get_state("ns", "b").version == (1, 1)
+
+
+@pytest.mark.parametrize("max_vars", [None, 13, 6])
+def test_sqlite_block_path_is_one_statement_a_table(tmp_path, sq, max_vars):
+    """A one-namespace block is one statement (``apply_statements``
+    counts them), cut only where sqlite takes no more variables: then
+    several, and the same rows."""
+    ops = [("ns", f"k{i}", b"v%d" % i, i) for i in range(7)]
+    sq.apply_updates(_columnar(1, ops[:3]), (1, 0))   # some keys exist
+    assert sq.apply_statements == 1
+    want = SqliteVersionedDB(str(tmp_path / "want.db"))
+    want.open()
+    want.apply_updates(_columnar(1, ops[:3]), (1, 0))
+    want.apply_updates(_columnar(2, ops), (2, 0))
+    if max_vars is not None:
+        _set_max_vars(sq._conn, max_vars)
+    sent = _state_statements(sq._conn)
+    sq.apply_updates(_columnar(2, ops), (2, 0))
+    statements = (1 if max_vars is None
+                  else math.ceil(len(ops) / (max_vars // 6)))
+    assert sq.apply_statements == 1 + statements
+    assert len(sent) == statements
+    # (the trace shows a statement with its values filled in)
+    assert all(q.startswith("INSERT INTO state VALUES (")
+               and "ON CONFLICT(ns, key) DO UPDATE" in q for q in sent)
+    assert list(sq.iter_all()) == list(want.iter_all())
+    want.close()
+    # deletes are the table's second statement, cut the same way
+    del sent[:]
+    dels = [("ns", f"k{i}", None, 0) for i in range(1, 6)]
+    sq.apply_updates(_columnar(3, dels + [("ns", "z", b"z", 1)]), (3, 0))
+    n_del = 1 if max_vars is None else math.ceil(5 / (max_vars // 2))
+    assert len(sent) == n_del + 1
+    assert sum("DELETE FROM state" in q for q in sent) == n_del
+    assert [k for (_ns, k), _vv in sq.iter_all()] == ["k0", "k6", "z"]
+    assert sq.apply_fast_blocks == 3
+
+
+def test_sqlite_block_delete_goes_by_the_primary_key_index(sq):
+    """The delete finds its rows through the primary-key index; a scan
+    of ``state`` would read a million rows a block."""
+    sq.apply_updates(_columnar(1, [("ns", f"k{i}", b"v", i)
+                                   for i in range(50)]), (1, 0))
+    dels = [("ns", f"k{i}") for i in range(0, 50, 5)]
+    (sql, params), = row_statements(sq._conn, dels, **statedb._DELETE_ROWS)
+    plan = [row[3] for row in sq._conn.execute(
+        "EXPLAIN QUERY PLAN " + sql, params)]
+    sq.apply_updates(_columnar(2, [(ns, key, None, 0)
+                                   for ns, key in dels]), (2, 0))
+    assert not any(step.startswith("SCAN s") for step in plan), plan
+    assert any("SEARCH s USING COVERING INDEX sqlite_autoindex_state_1"
+               in step for step in plan), plan
+    assert len(list(sq.iter_all())) == 40
+
+
+def test_sqlite_metadata_takes_the_per_key_path(sq):
+    """Key-level endorsement metadata needs the per-key probe: a batch
+    that carries metadata, and any batch on a DB that tracks some, take
+    the per-key loop, and ``meta_count`` stays right."""
+    sq.apply_updates(_columnar(1, [("ns", "a", b"a", 0),
+                                   ("ns", "m", b"m", 1)]), (1, 0))
+    assert sq.apply_fast_blocks == 1
+    cb = _columnar(2, [("ns", "b", b"b", 0)])
+    cb.put("ns", "m", b"m2", (2, 1), metadata=b"policy")
+    sq.apply_updates(cb, (2, 0))
+    assert sq.apply_fast_blocks == 1
+    assert sq.meta_count == 1
+    assert sq.get_state("ns", "m").metadata == b"policy"
+    # tracked metadata: a plain columnar batch probes key by key, and
+    # its rewrite of ``m`` takes the metadata away
+    sq.apply_updates(_columnar(3, [("ns", "m", b"m3", 0),
+                                   ("ns", "a", None, 1)]), (3, 0))
+    assert sq.apply_fast_blocks == 1
+    assert sq.meta_count == 0 and sq.apply_statements == 1
+    assert sq.get_state("ns", "m").metadata is None
+    assert sq.get_state("ns", "a") is None
+    sq.apply_updates(_columnar(4, [("ns", "c", b"c", 0)]), (4, 0))
+    assert sq.apply_fast_blocks == 2
+    ub = UpdateBatch()
+    ub.put("ns", "d", b"d", (5, 0))
+    sq.apply_updates(ub, (5, 0))
+    assert sq.apply_fast_blocks == 2
+
+
+def test_sqlite_block_is_one_transaction(sq):
+    """A statement that raises part-way (a row sqlite cannot bind, in
+    the block's second statement) leaves nothing of the block once the
+    connection rolls back: rows, savepoint and all, for the reader at
+    once and for the writer after the rollback."""
+    sq.apply_updates(_columnar(1, [("ns", "a", b"a1", 0)]), (1, 0))
+    before = list(sq.iter_all())
+    _set_max_vars(sq._conn, 12)              # two rows a statement
+    bad = _columnar(2, [("ns", "a", b"a2", 0), ("ns", "b", b"b2", 1),
+                        ("ns", "c", b"c2", 2), ("ns", "d", b"d2", 3)])
+    bad.ukeys[3] = object()
+    with pytest.raises(sqlite3.Error):
+        sq.apply_updates(bad, (2, 0))
+    assert sq.apply_statements == 2          # the first one ran
+    assert sq._conn.in_transaction
+    assert sq.get_state("ns", "a").value == b"a1"
+    assert sq.get_state("ns", "b") is None and sq.savepoint() == (1, 0)
+    sq._conn.rollback()
+    assert list(sq.iter_all()) == before and sq.savepoint() == (1, 0)
+    sq.apply_updates(_columnar(2, [("ns", "b", b"b2", 0)]), (2, 0))
+    assert sq.get_state("ns", "b").value == b"b2"
+
+
+@pytest.mark.parametrize("first", ["delete", "upsert"])
+def test_sqlite_block_opens_its_transaction_at_its_first_statement(sq, first):
+    """Whichever statement a block sends first, a delete or an upsert,
+    the ``sqlite3`` module opens the transaction before it (it does so
+    for a text that STARTS with INSERT, UPDATE, DELETE or REPLACE): the
+    delete must not commit alone ahead of the block."""
+    sq.apply_updates(_columnar(1, [("ns", "a", b"a1", 0),
+                                   ("ns", "b", b"b1", 1)]), (1, 0))
+    sent = []
+
+    def note(q):
+        sent.append((q.split(" (")[0], sq._conn.in_transaction))
+
+    ops = [("ns", "c", b"c2", 1)]
+    if first == "delete":
+        ops.insert(0, ("ns", "a", None, 0))
+    sq._conn.set_trace_callback(note)
+    sq.apply_updates(_columnar(2, ops), (2, 0))
+    sq._conn.set_trace_callback(None)
+    want = [("BEGIN ", False)]
+    if first == "delete":
+        want.append(("DELETE FROM state WHERE rowid IN", True))
+    want += [("INSERT INTO state VALUES", True),
+             ("INSERT OR REPLACE INTO savepoint VALUES", True),
+             ("COMMIT", True)]
+    assert sent == want
+    assert not sq._conn.in_transaction
+
+
+def test_sqlite_block_delete_is_inside_the_blocks_transaction(sq):
+    """A block whose first statement deletes: the upsert after it
+    raises, and the rollback brings the deleted row back with the old
+    savepoint; committed, the reader sees the key up to ``commit()``
+    and not after."""
+    keys = [("ns", "a"), ("ns", "b"), ("ns", "c")]
+    sq.apply_updates(_columnar(1, [("ns", "a", b"a1", 0),
+                                   ("ns", "b", b"b1", 1)]), (1, 0))
+    before = list(sq.iter_all())
+    bad = _columnar(2, [("ns", "a", None, 0), ("ns", "c", b"c2", 1)])
+    bad.ukeys[1] = object()
+    with pytest.raises(sqlite3.Error):
+        sq.apply_updates(bad, (2, 0))
+    assert sq.apply_statements == 1 + 1       # the delete ran
+    assert sq._conn.in_transaction
+    assert sq.get_state("ns", "a").value == b"a1"   # the reader's view
+    sq._conn.rollback()
+    assert list(sq.iter_all()) == before and sq.savepoint() == (1, 0)
+    seen = []
+
+    def at_commit(q):
+        if q == "COMMIT":
+            seen.append((sq.get_versions_bulk(keys), sq.savepoint()))
+
+    sq._conn.set_trace_callback(at_commit)
+    sq.apply_updates(_columnar(2, [("ns", "a", None, 0),
+                                   ("ns", "c", b"c2", 1)]), (2, 0))
+    sq._conn.set_trace_callback(None)
+    assert seen == [({("ns", "a"): (1, 0), ("ns", "b"): (1, 1)}, (1, 0))]
+    assert sq.get_versions_bulk(keys) == {("ns", "b"): (1, 1),
+                                          ("ns", "c"): (2, 1)}
+    assert sq.savepoint() == (2, 0)
+
+
+def test_sqlite_reader_sees_a_block_at_its_commit(sq):
+    """The read connection sees none of a block's rows while the block's
+    statement has run and ``commit()`` has not, and all of them after."""
+    keys = [("ns", "a"), ("ns", "b"), ("ns", "c")]
+    sq.apply_updates(_columnar(1, [("ns", "a", b"a1", 0)]), (1, 0))
+    seen = []
+
+    def at_commit(q):
+        if q == "COMMIT":
+            assert sq._conn.in_transaction
+            seen.append((sq.get_versions_bulk(keys), sq.savepoint()))
+
+    sq._conn.set_trace_callback(at_commit)
+    sq.apply_updates(_columnar(2, [("ns", "a", b"a2", 0),
+                                   ("ns", "b", b"b2", 1),
+                                   ("ns", "c", b"c2", 2)]), (2, 0))
+    sq._conn.set_trace_callback(None)
+    assert seen == [({("ns", "a"): (1, 0)}, (1, 0))]
+    assert sq.get_versions_bulk(keys) == {
+        ("ns", "a"): (2, 0), ("ns", "b"): (2, 1), ("ns", "c"): (2, 2)}
+    assert sq.savepoint() == (2, 0)
+
+
+# ---------------------------------------------------------------------------
+# the history DB: a block's rows in one statement
+
+
+@pytest.mark.parametrize("max_vars", [None, 9])
+def test_history_commits_a_block_in_one_statement(tmp_path, max_vars):
+    h = HistoryDB(str(tmp_path / "history.db"))
+    if max_vars is not None:
+        _set_max_vars(h._conn, max_vars)      # two rows of four
+    sent = []
+    h._conn.set_trace_callback(
+        lambda q: sent.append(q) if " hist " in q else None)
+    writes = [("ns", "a", 0), ("ns", "b", 0), ("ns", "a", 3),
+              ("zz", "a", 4), ("ns", "c", 4)]
+    statements = 1 if max_vars is None else 3
+    assert h.commit_block(1, writes) == (5, statements)
+    assert len(sent) == statements
+    assert all(q.startswith("INSERT OR REPLACE INTO hist VALUES (")
+               for q in sent)
+    assert h.commit_block(2, [("ns", "a", 1)]) == (1, 1)
+    # the same block again (recovery's replay) leaves one row a write
+    assert h.commit_block(1, writes) == (5, statements)
+    assert h._conn.execute("SELECT COUNT(*) FROM hist").fetchone() == (6,)
+    # newest first
+    assert list(h.get_history_for_key("ns", "a")) == [(2, 1), (1, 3), (1, 0)]
+    assert list(h.get_history_for_key("zz", "a")) == [(1, 4)]
+    assert list(h.get_history_for_key("ns", "nope")) == []
+    assert h.savepoint() == 1
+    # a block with no write moves the savepoint alone
+    assert h.commit_block(3, []) == (0, 0)
+    assert h.savepoint() == 3
+    h.close()
 
 
 def _block(num, prev, payloads, channel="ch"):
