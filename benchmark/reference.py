@@ -115,6 +115,14 @@ def plan_codes(rows, block_num: int, state: RefState) -> tuple:
     return bytes(codes), done
 
 
+def written_keys(rows) -> set:
+    """Every key of every tx's writes in one block's plan, valid or not:
+    what a run reads back after flush and after reopen (an invalid tx's
+    write must be absent).  A configuration's own reference may define
+    its own, say to add keys whose metadata alone was written."""
+    return {key for _kind, _reads, writes in rows for key, _value in writes}
+
+
 def block_txids(blk) -> list:
     """The txid every tx of a block carries in its channel header, from
     the block's bytes."""

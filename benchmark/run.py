@@ -543,9 +543,15 @@ class CellRun:
     def check(self) -> None:
         from fabric_tpu.protos import transaction_pb2
 
+        from benchmark import reference as plain_reference
         from benchmark import spans
 
         rig, config, reference = self.rig, self.config, self.reference
+        # a configuration's own reference may name the keys its rows
+        # write (it owns the row format); one that does not gets the
+        # default's
+        written_keys = getattr(reference, "written_keys",
+                               plain_reference.written_keys)
         first, last, submitted = self.first, self.last, self.submitted
         base, txs = self.base, self.block_txs
         VALID = transaction_pb2.TxValidationCode.VALID
@@ -567,8 +573,7 @@ class CellRun:
                     fault("blocks_where_the_two_references_disagree",
                           f"block {b}: the OpenSSL reference and the "
                           "plan-level reference disagree")
-                for _kind, _reads, writes in plan:
-                    touched.update(k for k, _v in writes)
+                touched.update(written_keys(plan))
             got = rig.filters.get(b)
             if got is not None:
                 if got != want:

@@ -7,6 +7,21 @@ of (seed, block, position): any worker can make any block, and a tx that
 replays an earlier one (``duplicate_txid``) needs only that tx's block and
 position to carry its txid.  The creator is the run's one client.
 
+A deployment may bring its own transactions.  Where the traffic's
+generator module (``generators/<name>.py``) defines
+
+    envelopes(rows, config, endorsers, client, seed, block_num, replays)
+
+a worker builds each block's envelopes with it, and with
+:func:`envelopes` here where it defines none; the arguments are the same
+either way.  Such a generator owns its row format: from then on only it,
+its planner and the configuration's reference read a row (the default's
+rows are ``(kind, reads, writes)``).  What its assembler may take from
+here: :func:`proposal` and :func:`nonce` (the seeded txid a replay needs),
+:func:`spoil` (a signature that still parses and no longer verifies), and
+the order of ``endorsers`` as :func:`signers` gives it: org *n*'s peer is
+``endorsers[n]``, ``Org1MSP`` first; ``client`` is ``Org1MSP``'s user.
+
 Nothing here imports ``jax``: the workers must never open the chip, and
 they start faster without it.  Signing a 1000-tx block (three ECDSA
 signatures and a dozen protobuf messages per tx) takes a quarter of a
@@ -97,7 +112,7 @@ def proposal(creator: bytes, channel: str, chaincode: str, tx_nonce: bytes):
         payload=cpp.SerializeToString())
 
 
-def _spoil(sig: bytes) -> bytes:
+def spoil(sig: bytes) -> bytes:
     """A DER signature that still parses and no longer verifies."""
     return sig[:-4] + bytes(4)
 
@@ -128,11 +143,11 @@ def envelopes(rows, config: dict, endorsers, client, seed: int,
         resps = [txa.create_proposal_response(
             prop, rw, endorsers[(i + j) % n_org], cc) for j in range(n_end)]
         if kind == "bad_endorsement_signature":
-            resps[0].endorsement.signature = _spoil(
+            resps[0].endorsement.signature = spoil(
                 resps[0].endorsement.signature)
         env = txa.assemble_transaction(prop, resps, client)
         if kind == "bad_creator_signature":
-            env.signature = _spoil(env.signature)
+            env.signature = spoil(env.signature)
         out.append(env.SerializeToString())
     return out
 
@@ -163,9 +178,9 @@ def _init_worker(root, generator, config, traffic, seed, network):
     from benchmark import manifest
 
     _worker["config"], _worker["seed"] = config, seed
-    _worker["planner"] = manifest.load_module(
-        "generators", generator, root).planner(
-            config, traffic, seed, manifest.reference_of(config, root))
+    _worker["generator"] = manifest.load_module("generators", generator, root)
+    _worker["planner"] = _worker["generator"].planner(
+        config, traffic, seed, manifest.reference_of(config, root))
     _worker["endorsers"], _worker["client"] = signers(network)
 
 
@@ -178,8 +193,10 @@ def _make_block(k: int) -> tuple:
     # a generator that replays txids says which, for the block it
     # planned last
     replays = planner.replays(b) if hasattr(planner, "replays") else None
-    return (rows, envelopes(rows, config, _worker["endorsers"],
-                            _worker["client"], _worker["seed"], b, replays),
+    # the deployment's own assembler where its generator brings one
+    build = getattr(_worker["generator"], "envelopes", envelopes)
+    return (rows, build(rows, config, _worker["endorsers"],
+                        _worker["client"], _worker["seed"], b, replays),
             len(replays or ()))
 
 

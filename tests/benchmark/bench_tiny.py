@@ -23,13 +23,14 @@ def _rewrite(path, change):
 
 def shrink_config(c):
     c["block_tx"] = TINY_TX
-    c["preload_keys"] = min(c["preload_keys"], 2000)
+    if "preload_keys" in c:
+        c["preload_keys"] = min(c["preload_keys"], 2000)
     if c.get("preload") == "snapshot_join":
         c["first_block"] = TINY_HEIGHT
 
 
 def shrink_traffic(t):
-    for pool in t["pools"].values():
+    for pool in t.get("pools", {}).values():
         if pool["draw"] == "uniform":
             pool["count"] = 16
         elif pool["draw"] == "zipf":
@@ -39,7 +40,7 @@ def shrink_traffic(t):
     if "rate_tx_per_s" in t:
         t["rate_tx_per_s"] = 40
     t["stream_hint_blocks_per_s"] = 2
-    if "duplicate_txid" in t["invalid_kinds"]:
+    if "duplicate_txid" in t.get("invalid_kinds", ()):
         # two of a tiny block's txs are invalid: six, so that each of
         # the three kinds comes twice
         t["invalid_share"] = 0.3

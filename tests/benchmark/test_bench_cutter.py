@@ -12,14 +12,13 @@ import types
 
 import pytest
 
+import manifest_pins
 from bench_tiny import make_root, tiny_cell
 from benchmark import harness, manifest, preload, reference, run, stream
 from fabric_tpu.observe import Span
 from fabric_tpu.utils.batching import block_shapes, channel_shapes
 
-CELL = "ragged_backlog"
-NEW = ["tx_per_block", "verify_lane_fill", "verify_roofline_sum",
-       "caller_ms_per_ktx", "commit_ms_per_ktx", "apply_ms_per_ktx"]
+CELL, NEW = manifest_pins.CELL, manifest_pins.NEW
 
 
 def full_cell():
@@ -318,50 +317,12 @@ def test_reader_finds_nothing_in_a_program_without_the_attributes(name):
     assert read(name, obs_of([], [], None)) is None
 
 
-#: the whole of ``per_layer`` in its order, PR 22's, 25's, 27's and
-#: this PR's: an entry put first or in the middle, or taken away, reads
-#: as a change to what was there
-PER_LAYER = [
-    "pipeline_overlap_coverage", "launch_self_ms", "state_fill_ms",
-    "host_lane_rest_ms", "device_wait_ms", "h2d_bytes_per_block",
-    "verify_kernel_ms", "stage2_kernel_ms", "verify_roofline",
-    "ledger_commit_ms", "valid_share", "generator_lag_p95_ms",
-    "paced.launch_self_ms", "paced.state_fill_ms", "paced.ledger_commit_ms",
-    "apply_lag_ms", "dup_txid_ms", "idx_lock_wait_ms", "state_gather_ms",
-    "state_gather_under_apply", "commit_index_ms", "commit_fsync_ms",
-    "commit_enqueue_ms", "apply_write_ms", "apply_history_ms",
-    "paced.dup_txid_ms", "paced.apply_write_ms", "paced.apply_history_ms",
-    "paced.feed_wait_ms", "commit_index_growth"] + NEW
-#: what ``insert_backlog`` reports and this cell does not: one
-#: execution's time or one launch's frame, medians over the window,
-#: which on launches of seven and twelve shapes are one shape's
-ONE_SHAPE = {"h2d_bytes_per_block", "verify_kernel_ms", "stage2_kernel_ms",
-             "verify_roofline"}
-OLD_CELLS = ["rw_backlog", "insert_backlog", "zipf_backlog"]
-
-
 def test_the_cell_and_its_metrics_are_in_the_manifest():
+    """What PR 28 and its predecessors brought is there, first and in
+    its order; what came after is admitted (``manifest_pins``)."""
     man = manifest.load()
-    by = {m["name"]: m for m in man["per_layer"]}
-    assert [m["name"] for m in man["per_layer"]] == PER_LAYER
-    for name in NEW:
-        assert by[name]["workloads"] == [CELL]
-        assert by[name]["moves"] == "commit_tx_per_s"
-    for name in PER_LAYER[:-len(NEW)]:
-        lists = by[name]["workloads"]
-        if name.startswith("paced.") or name in ("generator_lag_p95_ms",
-                                                 "apply_lag_ms"):
-            assert lists == ["rw_paced"]
-        else:  # appended to, or left as it was
-            assert lists == OLD_CELLS + [CELL] * (name not in ONE_SHAPE)
-    mine = {m["name"] for m in manifest.metrics_of(man, "per_layer", CELL)}
-    theirs = {m["name"] for m in manifest.metrics_of(
-        man, "per_layer", "insert_backlog")}
-    assert mine == (theirs - ONE_SHAPE) | set(NEW)
-    assert [m["name"] for m in manifest.metrics_of(
-        man, "end_to_end", CELL)] == ["commit_tx_per_s", "setup_s"]
-    assert man["workloads"][-1]["name"] == CELL
-    assert man["configs"][-1]["name"] == "fabric-default-cutter"
+    for pin in manifest_pins.PINS:
+        pin(man)
     assert os.path.isfile(os.path.join(
         manifest.ROOT, "benchmark", "cells", CELL + ".md"))
 

@@ -1,12 +1,14 @@
 """``BENCHMARK.json`` against the contract it is written to, and against
 the files it names: what the driver would refuse before any run."""
 
+import copy
 import json
 import os
 import re
 
 import pytest
 
+import manifest_pins
 from bench_tiny import listed
 from benchmark import manifest, roofline
 
@@ -117,6 +119,124 @@ def test_a_name_the_manifest_does_not_hold_is_an_error(man):
         manifest.cell(man, "no_such_cell")
     with pytest.raises(manifest.ManifestError):
         manifest.load_module("layer_metrics", "no_such_metric")
+
+
+# -- what earlier PRs brought stays where it was; what comes after is added --
+
+
+@pytest.mark.parametrize("pin", manifest_pins.PINS,
+                         ids=[p.__name__ for p in manifest_pins.PINS])
+def test_the_manifest_holds_what_earlier_prs_brought(pin, man):
+    pin(man)
+
+
+def test_listing_long_chain_backlog_appends_and_moves_nothing():
+    """``benchmark/cells/long_chain_backlog.json`` (measured, not listed:
+    PERF.md section 7) adds a configuration, a cell and ``dup_txid_hits``
+    at the end, and the cell's name at the end of the lists it joins."""
+    was, man = manifest.load(), listed()
+    assert man["configs"][:-1] == was["configs"]
+    assert man["workloads"][:-1] == was["workloads"]
+    assert man["workloads"][-1]["name"] == "long_chain_backlog"
+    assert man["per_layer"][-1]["name"] == "dup_txid_hits"
+    assert man["per_layer"][-1]["workloads"] == ["long_chain_backlog"]
+    assert "dup_txid_hits" not in {m["name"] for m in was["per_layer"]}
+    joined = 0
+    for old, new in zip(was["end_to_end"] + was["per_layer"],
+                        man["end_to_end"] + man["per_layer"]):
+        if new != old:
+            joined += 1
+            assert new == dict(old, workloads=old["workloads"]
+                               + ["long_chain_backlog"]), old["name"]
+    assert joined == 22
+    _w, config, traffic = manifest.cell(man, "long_chain_backlog")
+    assert config["first_block"] == 10_000
+    assert "duplicate_txid" in traffic["invalid_kinds"]
+
+
+def _appended(man):
+    """A made-up configuration, cell and per-layer metric at the end, and
+    the cell's name at the end of the lists it joins: what the next PR of
+    any kind does to ``BENCHMARK.json``."""
+    man = copy.deepcopy(man)
+    man["configs"].append({
+        "name": "made-up-5org", "source": "https://example.org/spec",
+        "file": "benchmark/configs/made-up-5org.json",
+        "reduced": ["preload_keys"], "why": "a test's"})
+    man["workloads"].append({
+        "name": "made_up_backlog", "config": "made-up-5org",
+        "traffic": "made-up-backlog", "chips": 1, "why": "a test's"})
+    man["per_layer"].append({
+        "name": "made_up_ms", "unit": "ms", "better": "lower",
+        "source": "program_span", "layer": "validator.host_lane",
+        "moves": "commit_tx_per_s",
+        "workloads": ["made_up_backlog", "ragged_backlog",
+                      "insert_backlog"]})
+    for m in man["end_to_end"] + man["per_layer"][:-1]:
+        if "insert_backlog" in m.get("workloads", ()):
+            m["workloads"].append("made_up_backlog")
+    return man
+
+
+def test_an_appended_cell_configuration_and_metric_break_no_pin(man):
+    for pin in manifest_pins.PINS:
+        pin(_appended(man))
+
+
+def _without(section, name):
+    def change(man):
+        man[section] = [e for e in man[section] if e["name"] != name]
+    return change
+
+
+def _swapped(section, i, j):
+    def change(man):
+        got = man[section]
+        got[i], got[j] = got[j], got[i]
+    return change
+
+
+def _metric(section, name, **new):
+    def change(man):
+        for m in man[section]:
+            if m["name"] == name:
+                m.update({k: v(m[k]) if callable(v) else v
+                          for k, v in new.items()})
+    return change
+
+
+BROKEN = {
+    "a_per_layer_metric_removed": _without("per_layer", "state_gather_ms"),
+    "two_per_layer_metrics_reordered": _swapped("per_layer", 3, 4),
+    "a_metric_put_first": lambda man: man["per_layer"].insert(
+        0, man["per_layer"].pop()),
+    "a_cell_removed": _without("workloads", "ragged_backlog"),
+    "two_cells_reordered": _swapped("workloads", 0, 1),
+    "a_configuration_removed": _without("configs", "fabric-default-cutter"),
+    "a_cell_taken_off_a_metrics_list": _metric(
+        "per_layer", "apply_write_ms", workloads=lambda w: w[1:]),
+    "a_cell_put_first_on_a_metrics_list": _metric(
+        "per_layer", "tx_per_block",
+        workloads=lambda w: ["made_up_backlog"] + w),
+    "a_bound_loosened": _metric("end_to_end", "commit_tx_per_s", bound=0.1),
+    "a_cells_traffic_changed": _metric("workloads", "rw_paced",
+                                       traffic="rw-backlog"),
+    "a_configurations_cuts_changed": _metric(
+        "configs", "fabric-zipf10k-sqlite", reduced=["channels"]),
+}
+
+
+@pytest.mark.parametrize("how", sorted(BROKEN))
+def test_a_pinned_entry_removed_changed_or_moved_fails_a_pin(how):
+    man = _appended(manifest.load())
+    BROKEN[how](man)
+    failed = []
+    for pin in manifest_pins.PINS:
+        try:
+            pin(man)
+        except (AssertionError, KeyError):
+            failed.append(pin.__name__)
+    assert failed, how
 
 
 def test_ladder_work_and_peaks():

@@ -9,7 +9,7 @@ import types
 
 import pytest
 
-from bench_tiny import listed, tiny_cell
+from bench_tiny import tiny_cell
 from benchmark import harness, manifest, preload, spans, stream
 from fabric_tpu.observe import Span
 
@@ -172,27 +172,6 @@ def test_reader_finds_nothing_in_a_program_without_the_spans(name):
         sp("commit", 0.6, 0.9, COMMITTER)])]
     assert read(name, old) is None
     assert read(name, []) is None
-
-
-@pytest.mark.parametrize("with_unlisted", [False, True])
-def test_each_new_reader_is_in_the_manifest_for_its_cells(with_unlisted):
-    """As committed, and with ``long_chain_backlog`` (measured, not
-    listed: ``benchmark/cells/long_chain_backlog.json``) listed."""
-    man = listed() if with_unlisted else manifest.load()
-    cells = ["rw_backlog", "insert_backlog", "zipf_backlog"] + (
-        ["long_chain_backlog"] if with_unlisted else [])
-    by = {m["name"]: m for m in man["per_layer"]}
-    for name in BACKLOG + ["commit_index_growth"]:
-        assert by[name]["workloads"] == cells
-        assert by[name]["moves"] == "commit_tx_per_s"
-    for name in PACED:
-        assert by[name]["workloads"] == ["rw_paced"]
-        assert by[name]["moves"] == "tx_commit_p50_ms"
-    # appended: what was there is still first, in its order
-    new = BACKLOG + PACED + ["commit_index_growth"] + (
-        ["dup_txid_hits"] if with_unlisted else [])
-    assert [m["name"] for m in man["per_layer"]][-len(new):] == new
-    assert ("dup_txid_hits" in by) == with_unlisted
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,10 @@
 """Every traffic mix's stream against the plain reference at a tiny size:
 the plan-level reference, the OpenSSL reference on the blocks' bytes, and
 (for the Zipf stream, whose verdicts depend on each other) the system
-itself through ``CommitPipeline`` at depth 2."""
+itself through ``CommitPipeline`` at depth 2.  Last, the seam through
+which a deployment brings its own transactions: the listed cells' rows
+pinned to the parent's, and a generator's own assembler through the
+spawned workers."""
 
 import shutil
 
@@ -420,6 +423,8 @@ def test_a_block_says_how_many_txids_it_replays(network, monkeypatch):
     config, traffic = tiny_cell("long_chain_backlog")
     _net, (endorsers, client), _mgr = network
     for key, value in (("config", config), ("seed", 9),
+                       ("generator", manifest.load_module(
+                           "generators", traffic["generator"])),
                        ("planner", _planner(config, traffic, 9)),
                        ("endorsers", endorsers), ("client", client)):
         monkeypatch.setitem(stream._worker, key, value)
@@ -448,3 +453,129 @@ def test_the_traffic_file_says_how_far_back_a_replay_reaches(back):
         planner.rows(b)
         seen |= {b - src for src, _pos in planner.replays(b).values()}
     assert seen == set(range(1, back + 1))
+
+
+# ---------------------------------------------------------------------------
+# the assembler seam: a deployment may bring its own transactions
+
+
+#: sha256 over ``repr(planner.rows(b))`` for b = 0, 1, 2 at ``ROW_SEED``,
+#: from each listed cell's own files at their full size, taken at PR 33's
+#: parent (f1252b1): the plan is a function of the seed.  Signing keys
+#: are not (``cryptogen`` takes no seed), so rows are pinned and not
+#: envelope bytes.  ``rw_paced`` reads ``rw_backlog``'s pools.
+ROW_SEED = 2**31 + 33
+ROW_DIGESTS = {
+    "rw_backlog":
+        "614cac81d0a7cf740b4c73dab598ad928d4f5906a967788ecb8047805bba9c7c",
+    "insert_backlog":
+        "ecf866c86bd2e3660f10194a67ce98674fbab98cc65505a9fc0b581b308b3918",
+    "rw_paced":
+        "614cac81d0a7cf740b4c73dab598ad928d4f5906a967788ecb8047805bba9c7c",
+    "zipf_backlog":
+        "fe120cf563bf773bba2308c65ac4b721b434e8e080b25643011202014593acff",
+    "ragged_backlog":
+        "29dbc0b17c06b911fabe1fdc376ac46031d57817ec3760976e96775ee2d0cde6",
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ROW_DIGESTS))
+def test_a_listed_cells_rows_are_the_parents_for_the_same_seed(cell):
+    import hashlib
+
+    _w, config, traffic = manifest.cell(manifest.load(), cell)
+    planner = _planner(config, traffic, ROW_SEED)
+    digest = hashlib.sha256()
+    for b in range(3):
+        digest.update(repr(planner.rows(b)).encode())
+    assert digest.hexdigest() == ROW_DIGESTS[cell]
+
+
+@pytest.mark.parametrize("cell", ["rw_backlog", "ragged_backlog"])
+def test_a_listed_generators_blocks_are_built_by_stream_envelopes(
+        cell, network, monkeypatch):
+    """``pooled_kv`` and ``cutter_kv`` bring no assembler: a worker
+    reaches ``stream.envelopes`` itself, with the arguments it always
+    had."""
+    config, traffic = tiny_cell(cell)
+    generator = manifest.load_module("generators", traffic["generator"])
+    assert not hasattr(generator, "envelopes")
+    _net, (endorsers, client), _mgr = network
+    planner = _planner(config, traffic, 9)
+    for key, value in (("config", config), ("seed", 9),
+                       ("generator", generator), ("planner", planner),
+                       ("endorsers", endorsers), ("client", client)):
+        monkeypatch.setitem(stream._worker, key, value)
+    calls = []
+    monkeypatch.setattr(stream, "envelopes",
+                        lambda *args: calls.append(args) or ["envs"])
+    rows, envs, replayed = stream._make_block(1)
+    assert envs == ["envs"] and replayed == 0
+    assert rows == _planner(config, traffic, 9).rows(1)
+    assert calls == [(rows, config, endorsers, client, 9, 1, {})]
+
+
+OWN_ROWS = '''
+"""Rows the default assembler cannot read, and an assembler that says
+what it was handed: in place of envelopes, one ``repr`` a block."""
+
+
+class Planner:
+    def __init__(self, config, traffic, seed, reference):
+        self.first = int(config.get("first_block", 0))
+
+    def rows(self, b):
+        return [{"at": (b, i), "endorsing_orgs": (i % 3,), "sets": frozenset(
+            {f"p{b}_{i}"})} for i in range(3)]
+
+    def replays(self, b):
+        return {2: (b - 1, 0)} if b > self.first else {}
+
+
+def planner(config, traffic, seed, reference):
+    return Planner(config, traffic, seed, reference)
+
+
+def envelopes(*args):
+    rows, config, endorsers, client, seed, block_num, replays = args
+    return [repr((rows, config["name"], [e.msp_id for e in endorsers],
+                  client.msp_id, seed, block_num, replays)).encode()]
+'''
+
+
+def test_a_generators_own_assembler_gets_what_the_default_gets(tmp_path):
+    """Through ``BlockFactory``'s spawned workers: the module is found by
+    path under the copy's root, its ``envelopes`` is handed the default's
+    seven arguments, and its rows (a format of its own) come back as the
+    planner made them, which is how they reach the reference."""
+    import os
+
+    from bench_tiny import make_root
+
+    root = make_root(tmp_path)
+    with open(os.path.join(root, "benchmark", "generators", "own_rows.py"),
+              "w") as f:
+        f.write(OWN_ROWS)
+    config, _traffic = tiny_cell("rw_backlog")
+    traffic = {"name": "own-rows", "generator": "own_rows", "loop": "backlog"}
+    own = manifest.load_module("generators", "own_rows", root)
+    seed = 2**31 + 7
+    factory = stream.BlockFactory(root, config, traffic, seed,
+                                  stream.make_network(config), workers=1)
+    try:
+        factory.extend(2)
+        for k in range(2):
+            rows, envs, replayed = factory.take(k)
+            assert rows == own.planner(config, traffic, seed, None).rows(k)
+            assert replayed == k
+            assert envs == [repr((
+                rows, config["name"], ["Org1MSP", "Org2MSP", "Org3MSP"],
+                "Org1MSP", seed, k, {2: (0, 0)} if k else {})).encode()]
+    finally:
+        factory.close()
+    # such a deployment's reference brings the ``written_keys`` of its
+    # rows; the default's reads the default's, valid or not
+    assert reference.written_keys(
+        [("ok", (), (("a", b"1"), ("b", b"2"))),
+         ("bad_creator_signature", (("r", None),), (("c", b"3"),))]) == {
+             "a", "b", "c"}
