@@ -89,6 +89,15 @@ def _n_writes(batch) -> int:
     return len(rows) + len(batch._extra)
 
 
+def _n_meta_rows(batch) -> int:
+    """Rows of ``batch`` that store metadata (a key-level endorsement
+    policy's carrier), counted as :func:`_n_writes` counts rows."""
+    if getattr(batch, "row_uid", None) is None:
+        return sum(1 for vv in batch.updates.values() if vv.metadata)
+    return (sum(1 for md in batch.row_meta or () if md)
+            + sum(1 for vv in batch._extra.values() if vv.metadata))
+
+
 def _merge_overlay(inner_iter, ov: dict):
     """Merge a sorted ``(key, VersionedValue)`` iterator with an
     overlay dict ``{key: VersionedValue | None}`` (None = the overlay
@@ -245,7 +254,9 @@ class AsyncApplyEngine(VersionedDB):
                     self._blocks.ensure_synced(entry.num)
                 _txflow.block_durable(entry.num)
             t0 = time.perf_counter()
-            # ``apply.write``: ``writes`` is the block's rows; where the
+            # ``apply.write``: ``writes`` is the block's rows and
+            # ``meta_rows`` those of them that store metadata (absent
+            # where none does); where the
             # inner DB counts its statements (``SqliteVersionedDB``),
             # ``stmts`` is what its block path sent to the state table
             # for this block (1: one namespace, no delete) and ``path``
@@ -260,6 +271,8 @@ class AsyncApplyEngine(VersionedDB):
                 inner.apply_updates(entry.batch, entry.sp)
             if wsp is not None:
                 wsp.attrs["writes"] = _n_writes(entry.batch)
+                if entry.batch.has_meta:
+                    wsp.attrs["meta_rows"] = _n_meta_rows(entry.batch)
                 if stmts0 is not None:
                     wsp.attrs["stmts"] = inner.apply_statements - stmts0
                     wsp.attrs["path"] = (
@@ -302,19 +315,26 @@ class AsyncApplyEngine(VersionedDB):
     # order loses it: the query's snapshot predates the commit and the
     # queue no longer holds the entry.
 
-    def _inner_gather(self, gather, keys):
+    def _inner_gather(self, gather, keys, meta=None):
         """``gather(keys)`` on the inner DB inside ``sf.gather``.  Where
         the inner DB answers on a read connection of its own
         (``SqliteVersionedDB.rd_wait_s``) the span says so: ``reader``
         1, and ``rd_wait_ms``, what readers waited for that
-        connection's lock over the span."""
+        connection's lock over the span.  Where the metadata of some
+        keys is asked too (``meta``, a bool array), ``meta_keys`` is
+        how many and ``params`` how many of them the inner DB holds
+        metadata for: a key-level endorsement policy's carrier."""
         with global_tracer().span("sf.gather", keys=len(keys)) as gsp:
             waited0 = getattr(self._inner, "rd_wait_s", None)
-            out = gather(keys)
+            out = gather(keys) if meta is None else gather(keys, meta)
             if gsp is not None and waited0 is not None:
                 gsp.attrs.update(
                     reader=1,
                     rd_wait_ms=(self._inner.rd_wait_s - waited0) * 1000.0)
+            if gsp is not None and meta is not None:
+                gsp.attrs.update(
+                    meta_keys=int(meta.sum()),
+                    params=sum(1 for m in out[2] if m))
             return out
 
     def get_versions_bulk(self, keys):
@@ -340,11 +360,15 @@ class AsyncApplyEngine(VersionedDB):
                 self._inner_gather(self._inner.get_versions_bulk, rest))
         return out
 
-    def get_versions_cols(self, keys):
+    def get_versions_cols(self, keys, meta=None):
+        """As the inner DB's, pending applies winning; for a key
+        flagged in ``meta`` the pending entry's metadata wins with its
+        version (a pending delete clears it)."""
         tracer = global_tracer()
         pend = self._pending()
-        present, vers = self._inner_gather(
-            self._inner.get_versions_cols, keys)
+        present, vers, *rest = self._inner_gather(
+            self._inner.get_versions_cols, keys, meta)
+        metas = rest[0] if rest else None
         with tracer.span("sf.pending") as psp:
             tracer.set_attrs(psp, pending=len(pend))
             if pend:
@@ -358,8 +382,12 @@ class AsyncApplyEngine(VersionedDB):
                             else:
                                 present[i] = True
                                 vers[i] = vv.version
+                            if metas is not None and meta[i]:
+                                metas[i] = (vv.metadata or None
+                                            if vv.value is not None
+                                            else None)
                             break
-        return present, vers
+        return (present, vers) if metas is None else (present, vers, metas)
 
     def _overlay_for(self, ns, pend, keep):
         """{key: vv-or-None} for every pending write in ``ns``;

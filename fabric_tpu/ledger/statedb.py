@@ -122,7 +122,7 @@ class ColumnarUpdateBatch(UpdateBatch):
 
     def __init__(self, block_num: int, ns_names: list, ukeys: list,
                  ns_of, row_uid, row_del, row_voff, row_vlen,
-                 row_txnum, blob: bytes):
+                 row_txnum, blob: bytes, row_meta: list | None = None):
         # no super().__init__: ``updates`` is a lazy property here
         self.block_num = block_num
         self.ns_names = ns_names
@@ -134,7 +134,12 @@ class ColumnarUpdateBatch(UpdateBatch):
         self.row_vlen = row_vlen
         self.row_txnum = row_txnum  # [R] tx num (version minor)
         self.blob = blob
-        self.has_meta = False
+        # [R] the metadata each row stores (bytes, or None: none, or
+        # cleared), or None where no row of the block carries any: a
+        # value write carries the key's metadata along, a metadata
+        # write replaces it
+        self.row_meta = row_meta if row_meta and any(row_meta) else None
+        self.has_meta = self.row_meta is not None
         self._extra: dict = {}      # post-build overrides
         self._updates: dict | None = None
 
@@ -159,14 +164,15 @@ class ColumnarUpdateBatch(UpdateBatch):
         vo_l = self.row_voff.tolist()
         vl_l = self.row_vlen.tolist()
         tx_l = self.row_txnum.tolist()
+        meta = self.row_meta or [None] * len(uid_l)
         for r, uid in enumerate(uid_l):
             if del_l[r]:
-                val = None
+                val = md = None
             else:
                 vo = vo_l[r]
-                val = blob[vo:vo + vl_l[r]]
+                val, md = blob[vo:vo + vl_l[r]], meta[r]
             d[(ns_names[ns_of[uid]], ukeys[uid])] = VersionedValue(
-                val, None, (bn, tx_l[r])
+                val, md, (bn, tx_l[r])
             )
         d.update(self._extra)
         return d
@@ -208,6 +214,7 @@ class ColumnarUpdateBatch(UpdateBatch):
         ns_names, ukeys, ns_of = self.ns_names, self.ukeys, self.ns_of
         mv = memoryview(self.blob)
         bn = self.block_num
+        meta = self.row_meta
         per_ns_del: dict = {}
         per_ns_row: dict = {}
         for uid, r in last.items():
@@ -220,7 +227,8 @@ class ColumnarUpdateBatch(UpdateBatch):
             else:
                 vo = int(self.row_voff[r])
                 per_ns_row.setdefault(ns, []).append(
-                    (ns, key, mv[vo:vo + int(self.row_vlen[r])], None,
+                    (ns, key, mv[vo:vo + int(self.row_vlen[r])],
+                     meta[r] if meta else None,
                      bn, int(self.row_txnum[r]))
                 )
         for ns in sorted(set(per_ns_del) | set(per_ns_row)):
@@ -259,14 +267,21 @@ class VersionedDB:
                 out[(ns, key)] = v
         return out
 
-    def get_versions_cols(self, keys: list[tuple[str, str]]):
+    def get_versions_cols(self, keys: list[tuple[str, str]], meta=None):
         """Column form of :meth:`get_versions_bulk` for the validator's
         ``state_fill`` hot path: → ``(present [U] bool, vers [U, 2]
         uint32)`` numpy arrays positionally aligned with ``keys``.  The
         dict round-trip of ``get_versions_bulk`` (build a dict, then
         re-walk every key to probe it) cost a second Python pass over
         every unique read key per block; backends override this with a
-        single fused pass."""
+        single fused pass.
+
+        ``meta``: a ``[U]`` bool array that flags the keys whose
+        ``metadata`` is wanted too (the keys the block writes: a
+        key-level endorsement policy lives there).  Given, the answer
+        has a third member, a list aligned with ``keys``: the flagged
+        key's metadata bytes, None where it has none, is absent or was
+        not flagged."""
         import numpy as np
 
         U = len(keys)
@@ -279,7 +294,14 @@ class VersionedDB:
                 if v is not None:
                     present[i] = True
                     vers[i] = v
-        return present, vers
+        if meta is None:
+            return present, vers
+        metas = [None] * U
+        for i in np.flatnonzero(meta).tolist():
+            vv = self.get_state(*keys[i])
+            if vv is not None:
+                metas[i] = vv.metadata or None
+        return present, vers, metas
 
     def iter_all(self):
         """Yield ((ns, key), VersionedValue) over the WHOLE state in
@@ -329,7 +351,7 @@ class MemVersionedDB(VersionedDB):
     def get_state(self, ns, key):
         return self._data.get((ns, key))  # dict.get is atomic under the GIL
 
-    def get_versions_cols(self, keys):
+    def get_versions_cols(self, keys, meta=None):
         """Single fused pass (no intermediate dict): each lookup is one
         GIL-atomic ``dict.get`` — same concurrent-apply semantics as
         ``get_state``, the validator's overlay handles read ordering."""
@@ -338,13 +360,16 @@ class MemVersionedDB(VersionedDB):
         U = len(keys)
         present = np.zeros(U, bool)
         vers = np.zeros((U, 2), np.uint32)
+        metas = None if meta is None else [None] * U
         get = self._data.get
         for i, k in enumerate(keys):
             vv = get(k)
             if vv is not None:
                 present[i] = True
                 vers[i] = vv.version
-        return present, vers
+                if metas is not None and meta[i]:
+                    metas[i] = vv.metadata or None
+        return (present, vers) if metas is None else (present, vers, metas)
 
     def _sorted_keys(self, ns):
         keys = self._sorted_cache.get(ns)
@@ -425,6 +450,14 @@ _UPSERT_ROWS = dict(
     tail=" ON CONFLICT(ns, key) DO UPDATE SET value=excluded.value,"
          " metadata=excluded.metadata, block=excluded.block,"
          " txnum=excluded.txnum",
+)
+# how many of a block's keys carry metadata before the block is
+# applied: what ``meta_count`` loses to the block's upsert and delete
+_COUNT_META_ROWS = dict(
+    head="WITH d(ns, key) AS (VALUES", width=2,
+    tail=") SELECT COUNT(*) FROM d CROSS JOIN state AS s"
+         " ON s.ns = d.ns AND s.key = d.key"
+         " WHERE s.metadata IS NOT NULL AND s.metadata != x''",
 )
 _DELETE_ROWS = dict(
     head="DELETE FROM state WHERE rowid IN (WITH d(ns, key) AS (VALUES",
@@ -569,24 +602,41 @@ class SqliteVersionedDB(VersionedDB):
                     out[(ns, key)] = (row[0], row[1])
         return out
 
-    def get_versions_cols(self, keys):
+    def get_versions_cols(self, keys, meta=None):
         """Fused column gather: one cursor, arrays filled in place —
-        no per-key dict churn on the state_fill hot path."""
+        no per-key dict churn on the state_fill hot path.  A key
+        flagged in ``meta`` is asked for its ``metadata`` in the same
+        step; the others keep the two-column statement."""
         import numpy as np
 
         U = len(keys)
         present = np.zeros(U, bool)
         vers = np.zeros((U, 2), np.uint32)
+        if meta is None or not meta.any():
+            with self._reading() as cur:
+                for i, (ns, key) in enumerate(keys):
+                    row = cur.execute(
+                        "SELECT block, txnum FROM state WHERE ns=? AND key=?",
+                        (ns, key),
+                    ).fetchone()
+                    if row:
+                        present[i] = True
+                        vers[i] = row
+            return (present, vers) if meta is None else (
+                present, vers, [None] * U)
+        metas = [None] * U
+        plain = "SELECT block, txnum FROM state WHERE ns=? AND key=?"
+        with_md = ("SELECT block, txnum, metadata FROM state"
+                   " WHERE ns=? AND key=?")
         with self._reading() as cur:
-            for i, (ns, key) in enumerate(keys):
-                row = cur.execute(
-                    "SELECT block, txnum FROM state WHERE ns=? AND key=?",
-                    (ns, key),
-                ).fetchone()
+            for i, (k, want) in enumerate(zip(keys, meta.tolist())):
+                row = cur.execute(with_md if want else plain, k).fetchone()
                 if row:
                     present[i] = True
-                    vers[i] = row
-        return present, vers
+                    vers[i] = row[:2]
+                    if want:
+                        metas[i] = row[2] or None
+        return present, vers, metas
 
     def iter_all(self):
         q = ("SELECT ns, key, value, metadata, block, txnum FROM state "
@@ -626,28 +676,41 @@ class SqliteVersionedDB(VersionedDB):
     def apply_updates(self, batch, savepoint):
         """One transaction a block: the rows, the savepoint, ``commit()``.
 
-        A columnar batch with no metadata, on a DB that tracks none,
-        goes as one statement a namespace (``sqlrows``): a multi-row
-        upsert that rewrites a key that exists where it lies (sqlite >=
-        3.24).  ``INSERT OR REPLACE`` would delete the row and insert a
-        new one at the table's end, under a new rowid, and so dirty the
-        old leaf, a new one and the key's leaf of the primary-key index;
-        the update arm dirties one.  A reader sees the same
-        ``value, metadata, block, txnum`` either way, and nothing reads
-        a rowid.  Everything else (key-level endorsement metadata, an
-        ``UpdateBatch``, a columnar batch's ``_extra`` overrides) takes
-        the per-key loop, which needs the per-key metadata probe."""
+        A columnar batch goes as one statement a namespace
+        (``sqlrows``): a multi-row upsert that rewrites a key that
+        exists where it lies (sqlite >= 3.24), its ``metadata`` column
+        with the rest (a value write carries the key's metadata along,
+        a metadata write replaces it: the validator's
+        ``_build_updates_flat``).  ``INSERT OR REPLACE`` would delete
+        the row and insert a new one at the table's end, under a new
+        rowid, and so dirty the old leaf, a new one and the key's leaf
+        of the primary-key index; the update arm dirties one.  A reader
+        sees the same ``value, metadata, block, txnum`` either way, and
+        nothing reads a rowid.  ``meta_count`` stays exact: on a DB
+        that holds metadata, one more statement a namespace counts the
+        block's keys that carry some before they are written (a read;
+        ``apply_statements`` counts the statements that write).
+        Everything else (an ``UpdateBatch``, a columnar batch's
+        ``_extra`` overrides) takes the per-key loop with its per-key
+        metadata probe."""
         cur = self._conn.cursor()
         # meta_count == 0 ⇒ no existing row carries metadata, so the
-        # per-key decrement probe is skippable (keeps the common
-        # no-SBE channel free of per-write SELECTs)
+        # decrement probe is skippable (keeps the common no-SBE channel
+        # free of it)
         track = self.meta_count > 0
-        if (not track and not batch.has_meta
-                and isinstance(batch, ColumnarUpdateBatch)):
+        if isinstance(batch, ColumnarUpdateBatch):
             # values stay zero-copy slices of the validator's slab; no
             # dict materialization, no VersionedValue churn
             conn = self._conn
             for dels, rows in batch.sqlite_columns():
+                if track:
+                    for sql, params in row_statements(
+                            conn, [r[:2] for r in chain(dels, rows)],
+                            **_COUNT_META_ROWS):
+                        self.meta_count -= cur.execute(
+                            sql, params).fetchone()[0]
+                if batch.row_meta is not None:
+                    self.meta_count += sum(1 for r in rows if r[3])
                 for sql, params in chain(
                     row_statements(conn, dels, **_DELETE_ROWS),
                     row_statements(conn, rows, **_UPSERT_ROWS),

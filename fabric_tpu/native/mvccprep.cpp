@@ -10,14 +10,16 @@
 // last-wins dict semantics, and emits flat arrays the Python side
 // scatters into device arrays with pure numpy.
 //
-// Scope: the fast path covers public reads/writes (KVRWSet fields 1
-// and 3).  Range queries, hashed private collections, or malformed
-// bytes mark the tx python-needed (status 1) and the validator falls
-// back to the exact Python path for the block — key-id ORDER is
-// irrelevant here precisely because range intervals (the only
-// order-sensitive consumer) force that fallback.  metadata_writes are
-// skipped: neither MVCC nor the update batch consumes them (matching
-// mvcc_form/_build_updates).
+// Scope: the fast path covers public reads/writes and metadata writes
+// (KVRWSet fields 1, 3 and 4).  Range queries, hashed private
+// collections, or malformed bytes mark the tx python-needed (status 1)
+// and the validator falls back to the exact Python path for the block —
+// key-id ORDER is irrelevant here precisely because range intervals
+// (the only order-sensitive consumer) force that fallback.  A
+// KVMetadataWrite's key is interned like a write's; its entries stay
+// bytes of the blob (the span from its first entry's tag to the
+// message's end, which parses as a KVMetadataWrite without a key), so
+// the Python side decodes each DISTINCT entry list once.
 //
 // Built on demand with g++ (see fabric_tpu/native/__init__.py).
 
@@ -149,22 +151,27 @@ int64_t mvcc_prep(
     int32_t* ns_of_ukey,                   // [cap_keys]
     int64_t* ns_span,                      // [cap_ns,2]
     int64_t* ukey_span,                    // [cap_keys,2]
+    int64_t* m_start, int64_t* m_count,    // [n] metadata writes per tx
+    int32_t* m_uid,                        // [cap]
+    uint8_t* m_only,                       // [cap] 1: the tx writes no value to the key
+    int64_t* m_ent_span,                   // [cap,2] the entries' bytes; length 0 = cleared
     int64_t* out_counts) {
   Interner ns_intern, key_intern;
-  int64_t nr = 0, nw = 0, nns_flat = 0;
+  int64_t nr = 0, nw = 0, nm = 0, nns_flat = 0;
 
   for (int64_t i = 0; i < n; i++) {
     status[i] = 2;
     tx_ns_start[i] = nns_flat; tx_ns_count[i] = 0;
     r_start[i] = nr; r_count[i] = 0;
     w_start[i] = nw; w_count[i] = 0;
+    m_start[i] = nm; m_count[i] = 0;
     if (!use[i]) continue;
     int64_t off = results_span[2 * i], len = results_span[2 * i + 1];
     if (off < 0) continue;
     const uint8_t* rw = blob + off;
 
     bool bad = false;
-    int64_t tx_r0 = nr, tx_w0 = nw, tx_ns0 = nns_flat;
+    int64_t tx_r0 = nr, tx_w0 = nw, tx_m0 = nm, tx_ns0 = nns_flat;
 
     // TxReadWriteSet: field 2 = repeated NsReadWriteSet
     bool ok = walk(rw, size_t(len), [&](uint32_t f, int wt, Span s,
@@ -198,14 +205,12 @@ int64_t mvcc_prep(
       }
       if (!kvset.ok) return true;  // empty KVRWSet
 
-      // KVRWSet: 1 reads, 2 range (→python), 3 writes, 4 metadata (skip)
+      // KVRWSet: 1 reads, 2 range (→python), 3 writes, 4 metadata
       bool ok3 = walk(kvset.p, kvset.n, [&](uint32_t f3, int wt3, Span s3,
                                             uint64_t) -> bool {
-        // range queries (2) and metadata writes (4) → python path
-        // (ranges are order-sensitive; metadata strings need the
-        // Python parser's full checks)
-        if (f3 == 2 || f3 == 4) { bad = true; return true; }
-        if (wt3 != 2) return true;
+        // range queries (2) → python path (ranges are order-sensitive)
+        if (f3 == 2) { bad = true; return true; }
+        if (wt3 != 2) { if (f3 == 4) bad = true; return true; }
         if (f3 == 1) {  // KVRead{1 key, 2 Version{1 block, 2 tx}}
           Span key{}, ver{};
           bool has_ver = false;
@@ -280,6 +285,65 @@ int64_t mvcc_prep(
           w_val_span[2 * nw] = val.ok ? (val.p - blob) : -1;
           w_val_span[2 * nw + 1] = val.ok ? int64_t(val.n) : 0;
           nw++;
+        } else if (f3 == 4) {  // KVMetadataWrite{1 key, 2 entries{1 name, 2 value}}
+          Span key{};
+          const uint8_t* p = s3.p;
+          const uint8_t* end = s3.p + s3.n;
+          const uint8_t* ent0 = nullptr;  // tag of the first entry
+          while (p < end) {
+            const uint8_t* tag = p;
+            uint64_t k, len;
+            if (!varint(p, end, k)) { bad = true; return true; }
+            uint32_t f4 = uint32_t(k >> 3), wt4 = uint32_t(k & 7);
+            if (f4 == 0) { bad = true; return true; }
+            if (wt4 == 2) {
+              if (!varint(p, end, len) || len > uint64_t(end - p)) {
+                bad = true; return true;
+              }
+              if (f4 == 1) key = Span{p, size_t(len), true};
+              if (f4 == 2) {
+                if (!ent0) ent0 = tag;
+                // the Python parser refuses an entry whose bytes are
+                // malformed or whose name is not UTF-8 (BAD_RWSET)
+                Span name{};
+                if (!walk(p, size_t(len), [&](uint32_t f5, int wt5, Span s5,
+                                              uint64_t) -> bool {
+                      if (f5 == 1 && wt5 == 2) name = s5;
+                      return true;
+                    }) || (name.ok && !utf8_valid(name.p, name.n))) {
+                  bad = true; return true;
+                }
+              }
+              p += len;
+            } else if (wt4 == 0) {
+              if (!varint(p, end, len)) { bad = true; return true; }
+            } else if (wt4 == 5 && uint64_t(end - p) >= 4) {
+              p += 4;
+            } else if (wt4 == 1 && uint64_t(end - p) >= 8) {
+              p += 8;
+            } else { bad = true; return true; }
+          }
+          if (key.ok && !utf8_valid(key.p, key.n)) { bad = true; return true; }
+          bool fresh2;
+          int32_t uid = key_intern.get(ns_id, key.ok ? key.p : blob,
+                                       key.ok ? key.n : 0, fresh2, cap_keys);
+          if (uid < 0) { bad = true; return true; }
+          if (fresh2) {
+            ns_of_ukey[uid] = ns_id;
+            ukey_span[2 * uid] = key.ok ? (key.p - blob) : 0;
+            ukey_span[2 * uid + 1] = key.ok ? int64_t(key.n) : 0;
+          }
+          int64_t eo = ent0 ? (ent0 - blob) : 0;
+          int64_t en = ent0 ? int64_t(end - ent0) : 0;
+          for (int64_t k = tx_m0; k < nm; k++)
+            if (m_uid[k] == uid) {  // the key's last metadata write wins
+              m_ent_span[2 * k] = eo; m_ent_span[2 * k + 1] = en;
+              return true;
+            }
+          if (nm >= cap_entries) { bad = true; return true; }
+          m_uid[nm] = uid;
+          m_ent_span[2 * nm] = eo; m_ent_span[2 * nm + 1] = en;
+          nm++;
         }
         return true;
       });
@@ -289,7 +353,7 @@ int64_t mvcc_prep(
 
     if (!ok || bad) {
       // rewind this tx's contributions; python path re-parses it
-      nr = tx_r0; nw = tx_w0; nns_flat = tx_ns0;
+      nr = tx_r0; nw = tx_w0; nm = tx_m0; nns_flat = tx_ns0;
       status[i] = 1;
       tx_ns_count[i] = 0; r_count[i] = 0; w_count[i] = 0;
       continue;
@@ -298,11 +362,18 @@ int64_t mvcc_prep(
     tx_ns_count[i] = nns_flat - tx_ns0;
     r_count[i] = nr - tx_r0;
     w_count[i] = nw - tx_w0;
+    m_count[i] = nm - tx_m0;
+    for (int64_t k = tx_m0; k < nm; k++) {
+      m_only[k] = 1;
+      for (int64_t j = tx_w0; j < nw; j++)
+        if (w_uid[j] == m_uid[k]) { m_only[k] = 0; break; }
+    }
   }
   out_counts[0] = ns_intern.next;
   out_counts[1] = key_intern.next;
   out_counts[2] = nr;
   out_counts[3] = nw;
+  out_counts[4] = nm;
   return 0;
 }
 

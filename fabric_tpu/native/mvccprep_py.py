@@ -36,10 +36,18 @@ class MvccPrep:
     ns_of_ukey: np.ndarray    # [n_keys] int32
     ns_span: np.ndarray       # [n_ns, 2]
     ukey_span: np.ndarray     # [n_keys, 2]
+    m_start: np.ndarray       # [n] metadata writes (KVRWSet field 4)
+    m_count: np.ndarray
+    m_uid: np.ndarray         # [nm] int32
+    m_only: np.ndarray        # [nm] uint8: no value write to the key in its tx
+    m_ent_span: np.ndarray    # [nm, 2] the write's entries, as bytes of the
+    #                           blob that parse as a keyless KVMetadataWrite;
+    #                           length 0: the metadata is cleared
     n_ns: int
     n_keys: int
     n_reads: int
     n_writes: int
+    n_meta: int = 0
 
     def ns_names(self) -> list:
         return [
@@ -86,9 +94,13 @@ def prep(pb, use: np.ndarray) -> MvccPrep | None:
         ns_of_ukey=np.zeros(cap, np.int32),
         ns_span=np.zeros((cap_ns, 2), np.int64),
         ukey_span=np.zeros((cap, 2), np.int64),
+        m_start=np.zeros(n, np.int64), m_count=np.zeros(n, np.int64),
+        m_uid=np.zeros(cap, np.int32),
+        m_only=np.zeros(cap, np.uint8),
+        m_ent_span=np.zeros((cap, 2), np.int64),
         n_ns=0, n_keys=0, n_reads=0, n_writes=0,
     )
-    counts = np.zeros(4, np.int64)
+    counts = np.zeros(5, np.int64)
 
     def ptr(a):
         return a.ctypes.data_as(ctypes.c_void_p)
@@ -104,7 +116,10 @@ def prep(pb, use: np.ndarray) -> MvccPrep | None:
         ptr(out.w_uid), ptr(out.w_is_del), ptr(out.w_key_span),
         ptr(out.w_val_span),
         ptr(out.ns_of_ukey), ptr(out.ns_span), ptr(out.ukey_span),
+        ptr(out.m_start), ptr(out.m_count), ptr(out.m_uid), ptr(out.m_only),
+        ptr(out.m_ent_span),
         ptr(counts),
     )
-    out.n_ns, out.n_keys, out.n_reads, out.n_writes = (int(c) for c in counts)
+    (out.n_ns, out.n_keys, out.n_reads, out.n_writes,
+     out.n_meta) = (int(c) for c in counts)
     return out

@@ -277,11 +277,17 @@ class StaticBlock:
         slices by static offsets inside the jit)."""
         p = getattr(self, "_packed", None)
         if p is None:
-            p = self._packed = jnp.asarray(np.concatenate(
-                [self.read_keys, self.write_keys, self.rq_lo, self.rq_hi],
-                axis=1,
-            ))
+            p = self._packed = self.pack()
         return p
+
+    def pack(self, write_keys=None):
+        """:meth:`packed_static`'s array, uncached, with ``write_keys``
+        in the place of the block's own (the launch that finds a
+        metadata-only write's key absent blanks its slot)."""
+        return jnp.asarray(np.concatenate(
+            [self.read_keys,
+             self.write_keys if write_keys is None else write_keys,
+             self.rq_lo, self.rq_hi], axis=1))
 
     def packed_read_pv(self):
         """[T, R, 3] int32 on device — (read_present, read_ver_block,
@@ -387,6 +393,9 @@ class VecStaticBlock(StaticBlock):
     r_uid: np.ndarray = None    # [nr] unique-key id per flat read
     u_composite: list = None    # [n_keys] composite mvcc keys
     u_pairs: list = None        # [n_keys] (ns, key) pairs (validator)
+    mo_rows: np.ndarray = None  # metadata-only writes: tx row,
+    mo_cols: np.ndarray = None  # ``write_keys`` column,
+    mo_uid: np.ndarray = None   # unique-key id
 
     def fill_committed(self, committed: dict):
         U = len(self.u_composite)
@@ -432,8 +441,25 @@ def prepare_block_from_flat(n_txs: int, rwp, composite_keys: list) -> VecStaticB
     nr, nw = rwp.n_reads, rwp.n_writes
     rc = rwp.r_count[:n_txs]
     wc = rwp.w_count[:n_txs]
+    # a metadata write to a key its tx writes no value to is a writer
+    # too (it bumps the key's version): one more column of its tx's
+    # row, after the value writes.  Whether the key exists, and so
+    # whether the write applies at all, is known only at launch
+    # (``BlockValidator._launch_device`` blanks the slot where it does
+    # not): ``mo_*`` say where those slots are.
+    mo = np.flatnonzero(rwp.m_only[:rwp.n_meta]) if rwp.n_meta else ()
+    if len(mo):
+        mo_rows = np.repeat(np.arange(n_txs),
+                            rwp.m_count[:n_txs])[mo].astype(np.intp)
+        first = np.flatnonzero(np.r_[True, mo_rows[1:] != mo_rows[:-1]])
+        run = np.arange(len(mo)) - np.repeat(
+            first, np.diff(np.r_[first, len(mo)]))
+        mo_cols = (wc[mo_rows] + run).astype(np.intp)
+        n_writers = int((mo_cols + 1).max())
+    else:
+        n_writers = 0
     sh = block_shapes(txs=n_txs, reads=int(rc.max()) if n_txs else 0,
-                      writes=int(wc.max()) if n_txs else 0)
+                      writes=max(int(wc.max()) if n_txs else 0, n_writers))
     Tb, (R, W, _q) = sh.txs, sh.dims
 
     read_keys = np.full((Tb, R), -1, np.int32)
@@ -458,6 +484,8 @@ def prepare_block_from_flat(n_txs: int, rwp, composite_keys: list) -> VecStaticB
         w_rows = np.repeat(np.arange(n_txs), wc).astype(np.intp)
         w_cols = (np.arange(nw) - np.repeat(rwp.w_start[:n_txs], wc)).astype(np.intp)
         write_keys[w_rows, w_cols] = rwp.w_uid[:nw]
+    if len(mo):
+        write_keys[mo_rows, mo_cols] = rwp.m_uid[mo]
 
     read_key_set = {composite_keys[u] for u in np.unique(r_uid)} if nr else set()
     return VecStaticBlock(
@@ -466,6 +494,8 @@ def prepare_block_from_flat(n_txs: int, rwp, composite_keys: list) -> VecStaticB
         read_fill=[], read_key_set=read_key_set,
         r_rows=r_rows, r_cols=r_cols, r_uid=r_uid,
         u_composite=composite_keys,
+        **(dict(mo_rows=mo_rows, mo_cols=mo_cols, mo_uid=rwp.m_uid[mo])
+           if len(mo) else {}),
     )
 
 

@@ -119,9 +119,11 @@ def _resident_ver_ok(static_p, table, u_pack, read_pv, R: int,
 
 
 def build_stage2(t_bucket: int, n_sig: int, group_sigs: tuple,
-                 static_dims: tuple, resident_dims: tuple | None = None):
+                 static_dims: tuple, resident_dims: tuple | None = None,
+                 key_dims: tuple | None = None):
     """→ jitted stage2(sig_valid, launch_vec, *group_packed,
-    static_packed[, table, u_pack, read_pv]) → packed int8.
+    static_packed[, table, u_pack, read_pv][, *key_group_packed,
+    key_entries, key_pid]) → packed int8.
 
     Inputs arrive PACKED — one array per H2D transfer (each device_put
     carries a fixed host overhead whatever its size, so the
@@ -141,8 +143,30 @@ def build_stage2(t_bucket: int, n_sig: int, group_sigs: tuple,
     runs on device against the resident version table
     (:func:`_resident_ver_ok`) — the host ``state_fill`` gather only
     covers the miss/overlay lanes shipped inside ``u_pack``.
+
+    ``key_dims`` = (key-policy PlanSigs, entry bucket) compiles the
+    KEY-LEVEL ENDORSEMENT variant (statebased/validator_keylevel.go):
+    a written key that carries a validation parameter is held to that
+    policy INSTEAD of its namespace's, one without to the namespace's.
+      key_group_packed  one pack a key policy the channel has met, laid
+                        out as a namespace group's, one entry a live tx:
+                        every tx under every known policy
+      key_entries [Kb, 2] i32: tx (−1 = padding) | the namespace group
+                        of the key's namespace.  One row a (tx, touched
+                        key), and one a (tx, namespace) in which the tx
+                        touches no key; state-independent
+      key_pid     [Kb] i32, the one launch-time upload: the policy of
+                        the key's committed parameter (its place among
+                        the key groups), −1 = none: the namespace's
+                        verdict decides the entry, −2 = a parameter
+                        that does not parse: fails closed
+    ``policy_ok[tx]`` is then the AND over the tx's entries, each
+    judged by ``where(pid < 0, ns_ok[group, tx], key_ok[pid, tx])``;
+    the key groups' safe bits follow the namespace groups' in the
+    output.
     """
     R, W, Q = static_dims
+    key_sigs, _key_bucket = key_dims if key_dims is not None else ((), 0)
 
     def stage2(sig_valid, launch_vec, *rest):
         g = len(group_sigs)
@@ -169,10 +193,11 @@ def build_stage2(t_bucket: int, n_sig: int, group_sigs: tuple,
             jnp.where(creator_idx == -2, ns + 1, ns),
         )]
 
-        policy_ok = jnp.ones(t_bucket + 1, jnp.int8)
         safes = []
-        for gi, sig in enumerate(group_sigs):
-            gp = gpacked[gi]
+
+        def reduce_group(gp, sig):
+            """One policy group → (each entry's slot in [T + 1], the
+            last being padding's; its verdict, 1 for padding)."""
             S, P = sig.s_bucket, sig.n_principals
             match = (gp[:, : S * P] != 0).reshape(-1, S, P)
             endo_idx = gp[:, S * P: S * P + S]
@@ -181,7 +206,31 @@ def build_stage2(t_bucket: int, n_sig: int, group_sigs: tuple,
             safes.append(safe_g)
             t = jnp.where(tx_of >= 0, tx_of, t_bucket)
             contrib = jnp.where(tx_of >= 0, ok_g, True).astype(jnp.int8)
-            policy_ok = policy_ok.at[t].min(contrib)
+            return t, contrib
+
+        if key_dims is None:
+            policy_ok = jnp.ones(t_bucket + 1, jnp.int8)
+            for gi, sig in enumerate(group_sigs):
+                t, contrib = reduce_group(gpacked[gi], sig)
+                policy_ok = policy_ok.at[t].min(contrib)
+        else:
+            # each group's verdicts by tx (a tx without an entry reads
+            # 1), namespace groups first: [G + K, T + 1]
+            kbase = len(rest) - len(key_sigs) - 2
+            ok_all = jnp.stack([
+                jnp.ones(t_bucket + 1, jnp.int8).at[t].min(contrib)
+                for t, contrib in (
+                    [reduce_group(gpacked[gi], sig)
+                     for gi, sig in enumerate(group_sigs)]
+                    + [reduce_group(rest[kbase + ki], sig)
+                       for ki, sig in enumerate(key_sigs)])])
+            entries, pid = rest[-2], rest[-1]
+            etx = entries[:, 0]
+            t = jnp.where(etx >= 0, etx, t_bucket)
+            row = jnp.where(pid >= 0, g + pid, entries[:, 1])
+            e_ok = jnp.where(pid == -2, 0, ok_all[row, t])
+            policy_ok = jnp.ones(t_bucket + 1, jnp.int8).at[t].min(
+                jnp.where(etx >= 0, e_ok, 1).astype(jnp.int8))
         policy_ok = policy_ok[:t_bucket].astype(bool)
 
         pre_ok = structural_ok & creator_ok & policy_ok
@@ -233,7 +282,8 @@ class DeviceBlockPipeline:
         )
 
     def run(self, handle, launch_vec, groups, static_packed, static_dims,
-            pre_ok_pad_len, mesh=None, resident=None, n_txs=None):
+            pre_ok_pad_len, mesh=None, resident=None, n_txs=None,
+            key_lanes=None):
         """handle: p256v3.VerifyHandle; launch_vec np [T,3] i32;
         groups: list of (plan, packed_dev [Eb, S·P+S+1], Eb, S);
         static_packed: device [T, R+W+2Q] i32; static_dims: (R, W, Q).
@@ -263,7 +313,13 @@ class DeviceBlockPipeline:
         DEVICE from the resident version table, launch_vec's ver_ok
         column is inert.  The table keeps whatever sharding the
         residency manager gave it (axis 0 over the same data mesh);
-        u_pack is the only launch-time state upload."""
+        u_pack is the only launch-time state upload.
+
+        ``key_lanes``: (key groups, as ``groups``; entries_dev [Kb, 2]
+        i32; pid_dev [Kb] i32, uploaded by the caller at this launch)
+        — the key-level endorsement operands (:func:`build_stage2`).
+        The fetch's ``safe`` list then carries the key groups' bits
+        after the namespace groups'."""
         t_bucket = pre_ok_pad_len
         n_sig = int(handle.device_out.shape[0])
         gsigs = tuple(
@@ -275,12 +331,20 @@ class DeviceBlockPipeline:
             resident_dims = (int(u_pack.shape[0]),
                              int(table_dev.shape[0]))
         key = (t_bucket, n_sig, gsigs, static_dims, resident_dims)
+        key_dims = None
+        if key_lanes is not None:
+            kgroups, entries_dev, pid_dev = key_lanes
+            key_dims = (tuple(plan_sig(plan, eb, s)
+                              for plan, _, eb, s in kgroups),
+                        int(pid_dev.shape[0]))
+            # a channel without key policies keys its program as ever
+            key += (key_dims,)
         fn = self._cache.get(key)
         compiled = fn is None
         if compiled:
             fn = self._cache[key] = build_stage2(
                 t_bucket, n_sig, gsigs, static_dims,
-                resident_dims=resident_dims,
+                resident_dims=resident_dims, key_dims=key_dims,
             )
             self._cache_gauge.set(len(self._cache))
         # launch ledger (observe/ledger.py): the program-cache verdict
@@ -290,6 +354,8 @@ class DeviceBlockPipeline:
         h2d = launch_vec.nbytes
         if resident is not None:
             h2d += resident[1].nbytes
+        if key_lanes is not None:
+            h2d += int(pid_dev.nbytes)  # one int32 a key entry
         from fabric_tpu.parallel import mesh as pmesh
 
         # partition-rule verdict BEFORE the puts: a mesh-configured
@@ -302,6 +368,8 @@ class DeviceBlockPipeline:
             data_planes += [gp for _, gp, _, _ in groups]
             if resident is not None:
                 data_planes.append(resident[2])
+            if key_lanes is not None:
+                data_planes += [gp for _, gp, _, _ in kgroups]
             sharded = all(pmesh.will_shard(mesh, a) for a in data_planes)
         rec = _ledger.launch("stage2", compiled=compiled,
                              lanes=t_bucket, h2d_bytes=h2d,
@@ -336,6 +404,11 @@ class DeviceBlockPipeline:
                      pmesh.shard(mesh, "unique_read_pack",
                                  jnp.asarray(u_pack)),
                      pmesh.shard(mesh, "read_versions", read_pv_dev)]
+        if key_lanes is not None:
+            # per-key entries, not per-tx: they ride as they were put
+            args += [pmesh.shard(mesh, "policy_table", gp)
+                     for _, gp, _, _ in kgroups]
+            args += [entries_dev, pid_dev]
         from fabric_tpu.observe import device_annotation
 
         if rec is not None:
@@ -376,7 +449,7 @@ class DeviceBlockPipeline:
             }
             off = 5 * T + n_sig
             safes = []
-            for sig in gsigs:
+            for sig in gsigs + (key_dims[0] if key_dims else ()):
                 safes.append(flat[off:off + sig.e_bucket])
                 off += sig.e_bucket
             out["safe"] = safes

@@ -178,6 +178,7 @@ class PendingBlock:
     range_phantom: frozenset = frozenset()  # tx idxs failing range re-exec
     fb: object = None       # _FastBlock of a columnar parse, or None
     hd_bytes: bytes = None  # pre-serialized header+data (ledger commit)
+    key_launch: object = None  # _KeyLaunch: stage 2 ran key-policy lanes
 
     @cached_property
     def txids(self) -> set:
@@ -314,6 +315,61 @@ class _DevicePre:
     # columnar builder + the launch-time dup check keep it live) — the
     # gate for the vectorized state_fill in _launch_device
     codes_synced: bool = False
+    # key-level endorsement: the state-independent half of the block's
+    # key-policy lanes (_KeyLanes), built here where the channel has
+    # met a key policy or the block writes metadata, else at the first
+    # launch that finds metadata in the state; ``key_lanes_of`` builds
+    # it (no argument)
+    key_lanes: object = None
+    key_lanes_of: object = None
+    # generic (non-flat) blocks with a metadata-only write: the MVCC
+    # inputs ``static`` was made from, and [(tx, composite key)] of
+    # those writes — a write to an absent key does not apply, which
+    # only the launch knows
+    mvcc_txs: list = None
+    meta_only: list = None
+
+
+#: distinct key policies a channel's stage-2 program carries lanes for;
+#: a block that needs one more is judged on the host path
+MAX_KEY_POLICIES = 16
+
+
+@dataclass
+class _KeyLanes:
+    """The state-independent half of one block's key-policy lanes in
+    the fused stage-2 program (``device_block.build_stage2``
+    ``key_dims``), built on the prefetch thread.  The launch adds the
+    one state-dependent vector: each entry's policy id, from the
+    committed parameter of its key."""
+
+    packed: np.ndarray    # [Kb, 2] int32: tx (-1 = padding) | namespace group
+    ent_key: np.ndarray   # [KE] index into ``pairs``; -1: the (tx,
+    #                       namespace) touches no key, the namespace's
+    #                       verdict decides
+    pairs: list           # (ns, key) the gather is asked about; flat
+    #                       blocks: ``static.u_pairs``, by unique-key id
+    want: np.ndarray      # [len(pairs)] bool: keys a live tx writes or
+    #                       metadata-writes, whose metadata is asked
+    n_live: int           # entries of a key-policy group: one a live tx
+    group: object         # BatchPlan → (plan, packed_dev, Eb, S)
+    meta_writes: bool     # a live tx writes metadata
+    inblock_dep: bool     # a live tx touches a key that an earlier live
+    #                       tx of the block metadata-writes: the host's
+    dev: object = None    # ``packed`` on the device
+    groups: list = field(default_factory=list)  # one a known key policy
+
+
+@dataclass
+class _KeyLaunch:
+    """What the launch learnt for the block's key-policy lanes: the
+    committed metadata of ``_KeyLanes.pairs``' keys (pending applies
+    and the in-flight overlay winning), and how many key groups, of how
+    many entries each, the program carried."""
+
+    metas: list
+    n_groups: int
+    n_live: int
 
 
 class BlockValidator:
@@ -452,6 +508,20 @@ class BlockValidator:
             )
         else:
             self.resident = None
+        # key-level (state-based) endorsement on the device path: the
+        # distinct key policies this channel has met, in the order met.
+        # Each is a policy group of the fused stage-2 program like a
+        # namespace's; a key's committed parameter names one by its
+        # place here (its ``pid``).  Grown at a launch (caller thread),
+        # read by the prefetch thread: append-only.
+        self._key_plans: list = []   # pid → BatchPlan
+        self._key_pid_of: dict = {}  # parameter bytes → pid; -2: no parse
+        # a key's stored metadata (``rwset.encode_metadata``) → the pid
+        # of its VALIDATION_PARAMETER entry, -1 where it has none
+        self._md_pid: dict = {None: -1}
+        # a metadata write's entries as they lie in the block (a
+        # keyless KVMetadataWrite) → the metadata the state stores
+        self._md_enc: dict = {}
         # optional phase accumulator (seconds per phase, summed across
         # blocks) — the bench publishes it as the per-phase breakdown
         # artifact; None = no instrumentation overhead
@@ -471,6 +541,14 @@ class BlockValidator:
         from fabric_tpu.observe import global_tracer
 
         self._tracer = global_tracer()
+        self._key_plans_gauge = global_registry().gauge(
+            "key_policy_plans",
+            "distinct key-level endorsement policies the channel's "
+            "stage-2 program carries lanes for")
+        self._key_host_ctr = global_registry().counter(
+            "key_policy_host_blocks_total",
+            "blocks under key-level endorsement judged on the host path, "
+            "by reason")
 
     def close(self) -> None:
         """Release validator-owned resources — the host staging pool's
@@ -1578,15 +1656,19 @@ class BlockValidator:
         # fused single-sync device path: policy + MVCC consume the
         # verify output ON DEVICE (one dispatch + one readback per
         # block); falls back to the host path for custom plugins,
-        # non-v3 kernels, consumption-unsafe blocks, or key-level
-        # endorsement (the SBE launch veto — committed key policies may
-        # have landed AFTER this block was preprocessed)
+        # non-v3 kernels, consumption-unsafe blocks, and the blocks
+        # under key-level endorsement that ``_launch_device`` hands
+        # back (``key_policy_host_blocks_total`` says why); with the
+        # device-resident state the SBE launch veto still takes every
+        # block that writes a key under a committed parameter
         if (
             getattr(fetch, "device_out", None) is not None and txs and dpre
-            and not self._sbe_launch_veto(txs, dpre, overlay)
+            and not (self.resident is not None
+                     and self._sbe_launch_veto(txs, dpre, overlay))
         ):
             try:
-                pending.fetch2, pending.range_phantom = self._launch_device(
+                (pending.fetch2, pending.range_phantom,
+                 pending.key_launch) = self._launch_device(
                     block, txs, fetch, dpre, overlay, fb=fb
                 )
             except Exception as e:
@@ -1603,14 +1685,14 @@ class BlockValidator:
         return pending
 
     def _sbe_launch_veto(self, txs, dpre, overlay) -> bool:
-        """True when a written key of this block carries a key-level
-        endorsement policy in committed state (or the in-flight
-        predecessor's batch) — the device program has no SBE lanes, so
-        such blocks re-route to the host dispatch path.  Free on
-        channels that never set validation parameters (meta_count 0).
-        In-block metadata WRITES never reach here: the native parser
-        routes them off the flat path and the group builders return
-        None for them at preprocess."""
+        """With ``state_resident`` only (the resident variant of the
+        stage-2 program has no key-policy lanes): True when a written
+        key of this block carries a key-level endorsement policy in
+        committed state (or the in-flight predecessor's batch), and the
+        block re-routes to the host dispatch path.  Free on channels
+        that never set validation parameters (meta_count 0).  In-block
+        metadata WRITES never reach here: with ``state_resident`` the
+        group builders return None for them at preprocess."""
         if not self._metaful(overlay):
             return False
         static = dpre.static
@@ -1966,11 +2048,11 @@ class BlockValidator:
         for ptx in txs:
             if not ptx.undetermined or ptx.is_config:
                 continue
-            if ptx.rwset is not None and any(
+            if self.resident is not None and ptx.rwset is not None and any(
                 n.metadata_writes for n in ptx.rwset.ns.values()
             ):
-                # key-level endorsement rides this block: the device
-                # program has no SBE lanes → host dispatch path
+                # key-level endorsement rides this block: the resident
+                # program variant has no key lanes → host dispatch path
                 return None
             infos = [self.policies.info(ns) for ns in ptx.namespaces]
             if not ptx.namespaces or any(i is None for i in infos):
@@ -1989,21 +2071,21 @@ class BlockValidator:
             key = id(info.policy)
             if key not in plans:
                 plans[key] = default._plan(info.policy)
-            by_policy.setdefault(key, []).append((ptx, info))
-        groups = []
-        group_entries = []
-        for key, ents in by_policy.items():
-            plan = plans[key]
+            by_policy.setdefault(key, []).append(ptx)
+
+        def group_of(plan, ents):
+            """One policy group over the txs ``ents``, packed and on
+            the device."""
             P = len(plan.principals)
             sh = block_shapes(entries=len(ents), endorsements=max(
-                (len(p.endorsements) for p, _ in ents), default=0))
+                (len(p.endorsements) for p in ents), default=0))
             S, E = sh.slots, sh.entries
             pool_rows = [np.zeros(P, bool)]  # row 0 = padding (no match)
             pool_of: dict[int, int] = {}
             idx_mat = np.zeros((E, S), np.int32)
             endo_idx = np.full((E, S), -1, np.int32)
             tx_of = np.full(E, -1, np.int32)
-            for e, (ptx, info) in enumerate(ents):
+            for e, ptx in enumerate(ents):
                 tx_of[e] = ptx.idx
                 if ptx.endo_item_idx:
                     endo_idx[e, : len(ptx.endo_item_idx)] = ptx.endo_item_idx
@@ -2020,8 +2102,14 @@ class BlockValidator:
             gp[:, :S * P] = match.reshape(E, -1)
             gp[:, S * P:S * P + S] = endo_idx
             gp[:, -1] = tx_of
-            groups.append((plan, self._put_group(gp), E, S))
+            return plan, self._put_group(gp), E, S
+
+        groups = []
+        group_entries = []
+        for key, ents in by_policy.items():
+            groups.append(group_of(plans[key], ents))
             group_entries.append(ents)
+        group_idx = {key: g for g, key in enumerate(by_policy)}
 
         # static MVCC arrays (committed-version fill deferred to
         # validate time — it needs the predecessor's state commit)
@@ -2050,11 +2138,20 @@ class BlockValidator:
                 # state-independent, so it uploads HERE (prefetch
                 # thread), never on the launch critical path
                 static.packed_read_pv()
-            return _DevicePre(
+            dp = _DevicePre(
                 groups=groups, group_entries=group_entries, static=static,
                 has_range=False, policies=self.policies,
                 rwp=rwp, ns_names=ns_names, ukeys=ukeys,
             )
+            # the gather answers by unique-key id: so do the lanes
+            lanes_of, meta_writes, _ = self._key_lanes_generic(
+                entries, group_idx, group_of, static.u_pairs,
+                static.u_index)
+            if meta_writes and not self._resolve_meta_writes(rwp):
+                return None  # entries the Python parser refuses
+            return self._with_key_lanes(dp, lanes_of, meta_writes)
+        lanes_of, meta_writes, meta_only = self._key_lanes_generic(
+            entries, group_idx, group_of)
         mvcc_txs = []
         has_range = False
         for ptx in txs:
@@ -2066,15 +2163,190 @@ class BlockValidator:
             if any(n.range_queries for n in ptx.rwset.ns.values()):
                 has_range = True
             reads, writes, rqs = ptx.rwset.mvcc_form()
+            # a metadata-only write is a writer where its key exists
+            # (``_mvcc_inputs``): taken as one here, and taken back at
+            # the launch that finds the key absent
+            writes += [k for i, k in meta_only if i == ptx.idx]
             mvcc_txs.append(
                 mvcc_ops.TxRWSet(reads=reads, writes=writes, range_reads=rqs)
             )
         static = mvcc_ops.prepare_block_static(mvcc_txs, bucketed=True)
         static.packed_static()
-        return _DevicePre(
+        dp = _DevicePre(
             groups=groups, group_entries=group_entries, static=static,
             has_range=has_range, policies=self.policies,
+            mvcc_txs=mvcc_txs if meta_only else None,
+            meta_only=meta_only or None,
         )
+        return self._with_key_lanes(dp, lanes_of, meta_writes)
+
+    # -- key-level endorsement: the prefetch half ---------------------------
+
+    def _with_key_lanes(self, dp, lanes_of, meta_writes: bool):
+        """``dp`` with its key-policy lanes: built now (prefetch
+        thread) where they will be wanted as far as can be known
+        without a state read (the channel has met a key policy, or the
+        block writes metadata), else left to the first launch that
+        finds metadata in the state.  A channel that never set a
+        parameter builds and uploads nothing."""
+        dp.key_lanes_of = lanes_of
+        if self.resident is None and (meta_writes or self._key_plans):
+            dp.key_lanes = lanes_of()
+        return dp
+
+    def _finish_key_lanes(self, ent_tx, ent_g, ent_key, pairs, want,
+                          n_live, group, meta_writes, inblock_dep):
+        from fabric_tpu.utils.batching import block_shapes
+
+        KE = len(ent_tx)
+        packed = np.zeros((block_shapes(entries=KE).entries, 2), np.int32)
+        packed[:, 0] = -1
+        packed[:KE, 0] = ent_tx
+        packed[:KE, 1] = ent_g
+        kl = _KeyLanes(
+            packed=packed, ent_key=np.asarray(ent_key, np.int64),
+            pairs=pairs, want=want, n_live=n_live, group=group,
+            meta_writes=meta_writes, inblock_dep=inblock_dep)
+        if not inblock_dep:
+            # every tx under every key policy the channel has met: the
+            # launch only picks, by each key's committed parameter
+            kl.dev = self._put_group(packed)
+            kl.groups = [group(plan) for plan in list(self._key_plans)]
+        return kl
+
+    def _key_lanes_generic(self, entries, group_idx, group_of, pairs=None,
+                           index=None):
+        """The key-policy lanes of a block the generic builder carries
+        (``entries``: its live ``(ptx, namespace, info)``, in block
+        order) → (a no-argument builder of its :class:`_KeyLanes`,
+        whether a live tx writes metadata, [(tx, composite key)] of the
+        metadata-only writes).  ``pairs`` / ``index``: a flat block's
+        unique-key table, by which its gather answers; without them
+        the lanes bring their own."""
+        if pairs is None:
+            pairs, index = [], {}
+        ent_tx, ent_g, ent_key = [], [], []
+        wrote_meta: dict = {}   # (ns, key) → the first tx that did
+        meta_only, live = [], {}
+        dep = False
+        for ptx, ns, info in entries:
+            live[ptx.idx] = ptx
+            n = ptx.rwset.ns.get(ns) if ptx.rwset is not None else None
+            g = group_idx[id(info.policy)]
+            keys = sorted(set(n.writes) | set(n.metadata_writes)) if n else ()
+            if not keys:
+                ent_tx.append(ptx.idx), ent_g.append(g), ent_key.append(-1)
+                continue
+            for k in keys:
+                j = index.get((ns, k))
+                if j is None:
+                    j = index[(ns, k)] = len(pairs)
+                    pairs.append((ns, k))
+                ent_tx.append(ptx.idx), ent_g.append(g), ent_key.append(j)
+                dep = dep or (ns, k) in wrote_meta
+            for k in n.metadata_writes:
+                wrote_meta.setdefault((ns, k), ptx.idx)
+                if k not in n.writes:
+                    meta_only.append((ptx.idx, ("pub", ns, k)))
+
+        def lanes_of():
+            want = np.zeros(len(pairs), bool)
+            want[[j for j in ent_key if j >= 0]] = True
+            ents = list(live.values())
+            return self._finish_key_lanes(
+                ent_tx, ent_g, ent_key, pairs, want, len(ents),
+                lambda plan: group_of(plan, ents), bool(wrote_meta), dep)
+
+        return lanes_of, bool(wrote_meta), meta_only
+
+    def _resolve_meta_writes(self, rwp) -> bool:
+        """``rwp.m_md``: for each metadata write of a flat block, the
+        metadata the state will store (``rwset.encode_metadata`` of its
+        entries; None: cleared).  Each DISTINCT entry list is parsed
+        once a channel: an asset's owner policy comes a few hundred
+        times a block.  → False where the Python parser refuses one."""
+        from fabric_tpu.ledger.rwset import decode_metadata, encode_metadata
+
+        blob, cache = rwp.blob, self._md_enc
+        if len(cache) > 4096:
+            cache.clear()
+        out = []
+        for o, ln in rwp.m_ent_span[:rwp.n_meta].tolist():
+            raw = blob[o:o + ln]
+            md = cache.get(raw, False)
+            if md is False:
+                try:
+                    md = encode_metadata(decode_metadata(raw))
+                except Exception:
+                    return False
+                cache[raw] = md
+            out.append(md)
+        rwp.m_md = out
+        return True
+
+    def _key_lanes_columnar(self, rwp, fb, n, live, etx, ens, ns_group,
+                            pairs):
+        """A columnar block's :class:`_KeyLanes`, from the flat write
+        and metadata-write arrays: numpy over the rows, no loop a key."""
+        nk = max(1, rwp.n_keys)
+        n_ns = len(ns_group)
+        ns_of = rwp.ns_of_ukey[:nk].astype(np.int64)
+        w_tx = np.repeat(np.arange(n), rwp.w_count[:n])
+        m_tx = np.repeat(np.arange(n), rwp.m_count[:n])
+        w_uid = rwp.w_uid[:rwp.n_writes].astype(np.int64)[live[w_tx]]
+        m_uid = rwp.m_uid[:rwp.n_meta].astype(np.int64)[live[m_tx]]
+        w_tx, m_tx = w_tx[live[w_tx]], m_tx[live[m_tx]]
+        t_tx = np.concatenate([w_tx, m_tx])
+        t_uid = np.concatenate([w_uid, m_uid])
+        comp = np.unique(t_tx * nk + t_uid)     # a (tx, key) once
+        k_tx, k_uid = comp // nk, comp % nk
+        # a (tx, namespace) that touches no key: its namespace's verdict
+        has = np.zeros(n * n_ns, bool)
+        has[k_tx * n_ns + ns_of[k_uid]] = True
+        bare = ~has[etx * n_ns + ens]
+        dep = False
+        if len(m_uid):
+            first = np.full(nk, n, np.int64)
+            np.minimum.at(first, m_uid, m_tx)
+            dep = bool((t_tx > first[t_uid]).any())
+        want = np.zeros(len(pairs), bool)
+        want[k_uid] = True
+        gtx = np.flatnonzero(live)
+        return self._finish_key_lanes(
+            np.concatenate([k_tx, etx[bare]]),
+            np.concatenate([ns_group[ns_of[k_uid]], ns_group[ens[bare]]]),
+            np.concatenate([k_uid, np.full(int(bare.sum()), -1, np.int64)]),
+            pairs, want, len(gtx),
+            lambda plan: self._columnar_group(plan, fb, gtx),
+            bool(len(m_uid)), dep)
+
+    def _columnar_group(self, plan, fb, gtx):
+        """One policy group over the txs ``gtx`` of a columnar block,
+        packed and on the device: match rows from a per-identity pool
+        gathered through the [n, S] uid matrix."""
+        from fabric_tpu.utils.batching import block_shapes
+
+        default = self.plugins["default"]
+        P = len(plan.principals)
+        S = fb.uid_mat.shape[1]
+        n_pool = len(fb.idents)
+        E = len(gtx)
+        Eb = block_shapes(entries=E).entries
+        row_pool = np.zeros((n_pool + 1, P), bool)
+        for u in range(n_pool):
+            if fb.has_ec[u]:
+                row_pool[u + 1] = default._match_row(
+                    plan, fb.sers[u], fb.idents[u]
+                )
+        gp = np.zeros((Eb, S * P + S + 1), np.int32)
+        gp[:, S * P:S * P + S] = -1
+        gp[:, -1] = -1
+        if E:
+            gp[:E, :S * P] = row_pool[fb.uid_mat[gtx]].reshape(E, -1)
+            gp[:E, S * P:S * P + S] = fb.endo_idx_mat[gtx]
+            gp[:E, -1] = gtx
+        # ONE packed upload per group (prefetch thread)
+        return plan, self._put_group(gp), Eb, S
 
     def _device_pre_columnar(self, txs, rwp, fb):
         """Policy-group + static-MVCC construction straight from the
@@ -2140,36 +2412,23 @@ class BlockValidator:
             key = id(inf.policy)
             key_ns.setdefault(key, []).append(j)
             key_info[key] = inf
+        if rwp.n_meta and (self.resident is not None
+                           or not self._resolve_meta_writes(rwp)):
+            # the resident program variant has no key lanes; entries
+            # the Python parser refuses are the host path's to judge
+            return None if self.resident is not None else NotImplemented
         groups = []
         group_entries = []
-        S = fb.uid_mat.shape[1]
-        n_pool = len(fb.idents)
+        ns_group = np.full(len(ns_names), -1, np.int64)
         for key, ns_ids in key_ns.items():
-            inf = key_info[key]
-            plan = default._plan(inf.policy)
-            P = len(plan.principals)
+            ns_group[ns_ids] = len(groups)
+            plan = default._plan(key_info[key].policy)
             if len(key_ns) > 1:
                 gtx = etx[np.isin(ens, ns_ids)]
             else:
                 gtx = etx
-            E = len(gtx)
-            Eb = block_shapes(entries=E).entries
-            row_pool = np.zeros((n_pool + 1, P), bool)
-            for u in range(n_pool):
-                if fb.has_ec[u]:
-                    row_pool[u + 1] = default._match_row(
-                        plan, fb.sers[u], fb.idents[u]
-                    )
-            gp = np.zeros((Eb, S * P + S + 1), np.int32)
-            gp[:, S * P:S * P + S] = -1
-            gp[:, -1] = -1
-            if E:
-                gp[:E, :S * P] = row_pool[fb.uid_mat[gtx]].reshape(E, -1)
-                gp[:E, S * P:S * P + S] = fb.endo_idx_mat[gtx]
-                gp[:E, -1] = gtx
-            # ONE packed upload per group (prefetch thread)
-            groups.append((plan, self._put_group(gp), Eb, S))
-            group_entries.append(range(E))
+            groups.append(self._columnar_group(plan, fb, gtx))
+            group_entries.append(range(len(gtx)))
 
         ukeys = rwp.ukey_strs()
         ns_of = rwp.ns_of_ukey[:rwp.n_keys].tolist()
@@ -2182,18 +2441,27 @@ class BlockValidator:
         static.packed_static()  # ONE H2D, prefetch thread
         if self.resident is not None:
             static.packed_read_pv()  # resident-compare expected plane
-        return _DevicePre(
+        dp = _DevicePre(
             groups=groups, group_entries=group_entries, static=static,
             has_range=False, policies=self.policies,
             rwp=rwp, ns_names=ns_names, ukeys=ukeys,
             codes_synced=True,
         )
+        live = (codes[:n] == NOTV) & ~fb.is_config
+        return self._with_key_lanes(
+            dp, lambda: self._key_lanes_columnar(
+                rwp, fb, n, live, etx, ens, ns_group, pairs),
+            bool(rwp.n_meta and live[np.repeat(
+                np.arange(n), rwp.m_count[:n])].any()))
 
     def _launch_device(self, block, txs, handle, dpre, overlay=None,
                        fb=None):
         """Host-side device-path launch: range re-execution, structural
         arrays, committed-version fill (+ overlay), stage-2 dispatch.
-        Returns the packed-output fetch.
+        → (the packed-output fetch, the txs a range re-execution
+        failed, the block's :class:`_KeyLaunch` or None); the fetch is
+        None where the block is the host path's after all (key-level
+        endorsement the lanes do not carry: ``_key_host_block``).
 
         The ``state_fill`` stage here is fully vectorized for columnar
         blocks (``fb`` with codes kept in sync by the columnar builder
@@ -2224,6 +2492,20 @@ class BlockValidator:
                              and _overlay_range_phantom(ptx, overlay)))
                 ):
                     range_phantom.add(ptx.idx)
+
+        # key-level endorsement: the lanes this block needs, if any
+        kl = None
+        metaful = self._metaful(overlay)
+        if self.resident is None and (dpre.key_lanes is not None or metaful):
+            kl = dpre.key_lanes
+            if kl is None:
+                # the first blocks of a channel whose state already
+                # holds parameters: nothing said so on the prefetch thread
+                kl = dpre.key_lanes = dpre.key_lanes_of()
+            if kl.inblock_dep:
+                return self._key_host_block("inblock_dependency")
+            if not (kl.meta_writes or metaful):
+                kl = None  # no parameter anywhere: today's program
 
         t_bucket = int(dpre.static.read_keys.shape[0])
         structural = np.zeros(t_bucket, bool)
@@ -2268,17 +2550,48 @@ class BlockValidator:
                     block.header.number, e,
                 )
                 resident_pack = None
+        static_packed = static.packed_static()
+        present = metas = None
         if resident_pack is not None:
             ver_ok = 1  # inert lane: computed on device from the table
         elif getattr(static, "u_pairs", None) is not None:
             # flat path: committed versions per UNIQUE key, compared on
-            # host — one [T] bool rides to the device
-            ver_ok = self._flat_ver_ok(static, overlay)
+            # host — one [T] bool rides to the device.  The metadata of
+            # the keys the block writes rides the same gather
+            ver_ok, present, metas = self._flat_ver_ok(
+                static, overlay, None if kl is None else kl.want)
+            if kl is not None and static.mo_rows is not None:
+                gone = ~present[static.mo_uid]
+                if gone.any():
+                    # a metadata-only write to an absent key does not
+                    # apply, so it is no writer (``_mvcc_inputs``)
+                    wk = static.write_keys.copy()
+                    wk[static.mo_rows[gone], static.mo_cols[gone]] = -1
+                    static_packed = static.pack(wk)
         else:
             committed = self._committed_versions(
                 static.read_key_set, overlay=overlay
             )
             ver_ok = static.host_ver_ok(committed)
+        key_lanes = key_launch = None
+        if kl is not None:
+            if present is None:
+                present, metas = self._gather_key_meta(kl, overlay)
+                gone = [(i, k) for i, k in dpre.meta_only or ()
+                        if not present[kl.pairs.index(k[1:])]]
+                if gone:
+                    static = mvcc_ops.prepare_block_static([
+                        mvcc_ops.TxRWSet(
+                            reads=t.reads, range_reads=t.range_reads,
+                            writes=[k for k in t.writes
+                                    if (i, k) not in gone])
+                        for i, t in enumerate(dpre.mvcc_txs)
+                    ], bucketed=True)
+                    static_packed = static.packed_static()
+            key_lanes = self._key_lanes_launch(kl, metas)
+            if key_lanes is None:
+                return self._key_host_block("too_many_policies")
+            key_launch = _KeyLaunch(metas, len(key_lanes[0]), kl.n_live)
         # ONE launch-time H2D: creator_idx | structural | ver_ok
         launch_vec = np.empty((t_bucket, 3), np.int32)
         launch_vec[:, 0] = creator_idx
@@ -2290,12 +2603,115 @@ class BlockValidator:
             self._device_pipeline = DeviceBlockPipeline()
         _faults.fire("validator.stage2")  # chaos hook (no-op unarmed)
         fetch2 = self._device_pipeline.run(
-            handle, launch_vec, dpre.groups, static.packed_static(),
+            handle, launch_vec, dpre.groups, static_packed,
             static.dims, t_bucket, mesh=self.mesh,
-            resident=resident_pack, n_txs=len(txs),
+            resident=resident_pack, n_txs=len(txs), key_lanes=key_lanes,
         )
         self._t("stage2_dispatch", t0)
-        return fetch2, range_phantom
+        return fetch2, range_phantom, key_launch
+
+    # -- key-level endorsement: the launch half -----------------------------
+
+    def _key_host_block(self, reason: str):
+        """One more block under key-level endorsement that the exact
+        host interpreter judges, and why: ``inblock_dependency`` (a tx
+        touches a key whose parameter an earlier tx of the block
+        writes: the repo's in-block rule stays on the host, PERF.md
+        section 7), ``too_many_policies`` (the channel's table is full:
+        ``MAX_KEY_POLICIES``), ``unsafe`` (a signature matches two
+        principals of one policy: the count-based reduction is not
+        exact there).  → ``_launch_device``'s answer for such a block."""
+        self._key_host_ctr.add(1, reason=reason)
+        return None, frozenset(), None
+
+    def _gather_key_meta(self, kl, overlay):
+        """(present, metadata) of ``kl.pairs`` for a block whose version
+        check does not ride ``get_versions_cols`` (the generic builder's
+        blocks): the same gather, asked for these keys alone."""
+        up, _uv, um = self.state.get_versions_cols(
+            kl.pairs, np.ones(len(kl.pairs), bool))
+        if overlay is not None and overlay.updates:
+            for i, pr in enumerate(kl.pairs):
+                vv = overlay.updates.get(pr)
+                if vv is not None:
+                    up[i] = vv.value is not None
+                    um[i] = (vv.metadata or None) if up[i] else None
+        return up, um
+
+    def _key_pid(self, md):
+        """The policy id of the parameter in a key's stored metadata:
+        -1 none, -2 one that does not parse (fails closed, as
+        ``_eval_key_policy`` does), else its place in the channel's
+        table, which grows here.  None: the table is full."""
+        from fabric_tpu.crypto.msp import policy_from_proto
+        from fabric_tpu.ledger.rwset import (
+            VALIDATION_PARAMETER, decode_metadata,
+        )
+        from fabric_tpu.protos import policies_pb2
+
+        raw = decode_metadata(md).get(VALIDATION_PARAMETER)
+        if raw is None:
+            return -1
+        pid = self._key_pid_of.get(raw)
+        if pid is None:
+            try:
+                plan = self.plugins["default"]._plan(policy_from_proto(
+                    protoutil.unmarshal(
+                        policies_pb2.SignaturePolicyEnvelope, raw)))
+            except Exception:
+                pid = -2
+            else:
+                if len(self._key_plans) >= MAX_KEY_POLICIES:
+                    return None
+                self._key_plans.append(plan)
+                pid = len(self._key_plans) - 1
+                self._key_plans_gauge.set(len(self._key_plans))
+            self._key_pid_of[raw] = pid
+        return pid
+
+    def _key_lanes_launch(self, kl, metas):
+        """The launch-time half of the key-policy lanes, inside
+        ``sf.key_lanes``: each entry's policy id from its key's
+        committed metadata (a C-level map over the unique keys; Python
+        runs once a DISTINCT metadata value not met before), uploaded
+        as one int32 an entry.  → ``DeviceBlockPipeline.run``'s
+        ``key_lanes``, or None where the table cannot take a policy
+        this block needs."""
+        import itertools
+
+        import jax.numpy as jnp
+
+        with self._tracer.span("sf.key_lanes") as ksp:
+            known = self._md_pid
+            if len(known) > 65536:
+                known.clear()
+                known[None] = -1
+            pid_u = np.fromiter(
+                map(known.get, metas, itertools.repeat(-3)), np.int32,
+                len(metas))
+            for u in np.flatnonzero((pid_u == -3) & kl.want).tolist():
+                pid = known.get(metas[u])
+                if pid is None:
+                    pid = self._key_pid(metas[u])
+                    if pid is None:
+                        return None
+                    known[metas[u]] = pid
+                pid_u[u] = pid
+            # policies met since the block was prefetched (this launch's
+            # among them): their groups are built here, once each
+            while len(kl.groups) < len(self._key_plans):
+                kl.groups.append(kl.group(self._key_plans[len(kl.groups)]))
+            if kl.dev is None:
+                kl.dev = self._put_group(kl.packed)
+            pid_vec = np.full(len(kl.packed), -1, np.int32)
+            keyed = np.flatnonzero(kl.ent_key >= 0)
+            pid_vec[keyed] = pid_u[kl.ent_key[keyed]]
+            pid_dev = jnp.asarray(pid_vec)
+            if ksp is not None:
+                ksp.attrs.update(
+                    entries=int((pid_vec != -1).sum()), lanes=len(keyed),
+                    policies=len(self._key_plans), bytes=pid_vec.nbytes)
+            return list(kl.groups), kl.dev, pid_dev
 
     # -- device-resident state (fabric_tpu/state) --------------------------
 
@@ -2345,7 +2761,7 @@ class BlockValidator:
                 "blocks take the host state_fill path", e,
             )
 
-    def _flat_ver_ok(self, static, overlay):
+    def _flat_ver_ok(self, static, overlay, want=None):
         """[T] bool committed-version check for a flat block: one FUSED
         column gather over the UNIQUE read keys (the
         preLoadCommittedVersionOfRSet analog —
@@ -2356,14 +2772,23 @@ class BlockValidator:
         of probing the overlay once per unique key, then a vectorized
         per-read compare reduced per tx (VecStaticBlock.ver_ok_from_u).
         A merged multi-batch overlay needs no special casing: its
-        ``updates`` mapping is already newest-wins."""
+        ``updates`` mapping is already newest-wins.
+
+        ``want``: a [U] bool mask of the keys whose metadata the gather
+        brings too (key-level endorsement: the keys the block writes),
+        the overlay winning for it as for versions.  → (ver_ok, the
+        keys' presence, their metadata or None where ``want`` is)."""
         pairs = static.u_pairs
         U = len(pairs)
         if not U:
             return static.ver_ok_from_u(
                 np.zeros(0, bool), np.zeros((0, 2), np.uint32)
-            )
-        up, uv = self.state.get_versions_cols(pairs)
+            ), np.zeros(0, bool), []
+        um = None
+        if want is None:
+            up, uv = self.state.get_versions_cols(pairs)
+        else:
+            up, uv, um = self.state.get_versions_cols(pairs, want)
         if overlay is not None and overlay.updates:
             idx = getattr(static, "u_index", None)
             if idx is None:  # built on the prefetch thread normally
@@ -2378,7 +2803,9 @@ class BlockValidator:
                 else:
                     up[ui] = True
                     uv[ui] = vv.version
-        return static.ver_ok_from_u(up, uv)
+                if um is not None:
+                    um[ui] = (vv.metadata or None) if up[ui] else None
+        return static.ver_ok_from_u(up, uv), up, um
 
     def _finish_device(self, pending: "PendingBlock"):
         """Consume the stage-2 packed output: final codes, filter,
@@ -2394,9 +2821,17 @@ class BlockValidator:
         self._last_device_sync_s = t1 - t0
         t0 = t1
 
-        # consumption-unsafe rows → exact host interpreter path
-        for safe_bits, ents in zip(out["safe"], dpre.group_entries):
-            if not np.all(safe_bits[: len(ents)]):
+        # consumption-unsafe rows → exact host interpreter path (the
+        # key-policy groups' bits follow the namespace groups': one
+        # entry a live tx each)
+        kla = pending.key_launch
+        counts = [len(ents) for ents in dpre.group_entries]
+        if kla is not None:
+            counts += [kla.n_live] * kla.n_groups
+        for safe_bits, count in zip(out["safe"], counts):
+            if not np.all(safe_bits[:count]):
+                if kla is not None:
+                    self._key_host_ctr.add(1, reason="unsafe")
                 return None
 
         # final code assignment, vectorized — same check order as the
@@ -2435,21 +2870,37 @@ class BlockValidator:
         if dpre.rwp is not None:
             batch, history = self._build_updates_flat(
                 block.header.number, txs, dpre.rwp, dpre.ns_names,
-                dpre.ukeys,
+                dpre.ukeys, kla, pending.overlay,
             )
         else:
-            batch, history = self._build_updates(block.header.number, txs)
+            batch, history = self._build_updates(
+                block.header.number, txs, overlay=pending.overlay,
+                sbe=kla is not None)
         self._t("postprocess", t0)
         return tx_filter, batch, history
 
-    def _build_updates_flat(self, block_num: int, txs, rwp, ns_names, ukeys):
+    def _build_updates_flat(self, block_num: int, txs, rwp, ns_names, ukeys,
+                            key_launch=None, overlay=None):
         """Columnar update batch + history from the native flat write
         arrays — the batch keeps the validator's numpy slabs
         (ColumnarUpdateBatch) so the sqlite backend can apply it with
         one statement per namespace, and its lazy ``updates`` dict is
         byte-identical (incl. per-tx (ns, key) sort order) to the old
         eager build over parsed rwsets.  Key strings come from the
-        already-decoded unique-key table (``ukeys``)."""
+        already-decoded unique-key table (``ukeys``).
+
+        ``key_launch`` (key-level endorsement in play, as
+        ``_build_updates``' ``sbe``): the rows get a metadata column.
+        A value write carries its key's metadata along, as the launch
+        gathered it (``key_launch.metas``, by unique-key id); a
+        metadata write of the same tx replaces it; an earlier delete
+        in the block clears it.  No later tx of the block touches a key
+        an earlier one metadata-writes (such a block is the host
+        path's), so the gathered metadata is what the key holds when
+        the row lands.  A metadata-only write re-puts the key's value
+        (this block's, the ``overlay``'s or the state's) under its new
+        metadata, and is a no-op on an absent key: rare, and an
+        override of the batch's (``put``) like the pvt phase's."""
         from fabric_tpu.ledger.statedb import ColumnarUpdateBatch
 
         history = []
@@ -2493,12 +2944,78 @@ class BlockValidator:
         else:
             rows = np.zeros(0, np.int64)
             row_txnum = np.zeros(0, np.int64)
+        row_uid, row_del = w_uid[rows], w_is_del[rows]
+        row_meta = None
+        if key_launch is not None:
+            row_meta = self._row_metadata(rwp, txs, row_uid, row_del,
+                                          row_txnum, key_launch.metas)
         batch = ColumnarUpdateBatch(
             block_num, ns_names, ukeys, ns_of,
-            w_uid[rows], w_is_del[rows], vo[rows], vl[rows],
-            row_txnum, rwp.blob,
+            row_uid, row_del, vo[rows], vl[rows],
+            row_txnum, rwp.blob, row_meta,
         )
+        if key_launch is not None and rwp.n_meta and rwp.m_only[
+                :rwp.n_meta].any():
+            self._put_meta_only(batch, block_num, txs, rwp, ns_names,
+                                ukeys, ns_of, overlay)
         return batch, history
+
+    @staticmethod
+    def _row_metadata(rwp, txs, row_uid, row_del, row_txnum, metas):
+        """The metadata column of a flat block's value-write rows (in
+        apply order): see ``_build_updates_flat``."""
+        uids = row_uid.tolist()
+        row_meta = list(map(metas.__getitem__, uids))
+        if row_del.any():
+            # a key deleted earlier in the block comes back bare
+            dead: set = set()
+            for r, (u, d) in enumerate(zip(uids, row_del.tolist())):
+                if u in dead or d:
+                    row_meta[r] = None
+                if d:
+                    dead.add(u)
+        nm = rwp.n_meta
+        both = np.flatnonzero(rwp.m_only[:nm] == 0) if nm else ()
+        if len(both):
+            # rows whose tx also writes the key's metadata: (tx, key)
+            # matched through one sorted composite
+            nk, n = max(1, rwp.n_keys), len(txs)
+            m_comp = (np.repeat(np.arange(n), rwp.m_count[:n])[both] * nk
+                      + rwp.m_uid[:nm][both])
+            order = np.argsort(m_comp)
+            r_comp = row_txnum * nk + row_uid
+            at = np.minimum(np.searchsorted(m_comp[order], r_comp),
+                            len(order) - 1)
+            hit = np.flatnonzero(m_comp[order][at] == r_comp)
+            m_md = rwp.m_md
+            for r, j in zip(hit.tolist(), both[order[at[hit]]].tolist()):
+                row_meta[r] = m_md[j]
+        return row_meta
+
+    def _put_meta_only(self, batch, block_num, txs, rwp, ns_names, ukeys,
+                       ns_of, overlay) -> None:
+        """The valid txs' metadata-only writes, in block order, as
+        overrides of ``batch``: the key's value re-put under the new
+        metadata at the tx's version; nothing where the key is absent."""
+        m_start, m_count = rwp.m_start.tolist(), rwp.m_count.tolist()
+        for ptx in txs:
+            if ptx.code != C.VALID or not m_count[ptx.idx]:
+                continue
+            s = m_start[ptx.idx]
+            for j in range(s, s + m_count[ptx.idx]):
+                if not rwp.m_only[j]:
+                    continue
+                uid = int(rwp.m_uid[j])
+                ns, key = ns_names[ns_of[uid]], ukeys[uid]
+                prev = batch.updates.get((ns, key))
+                if prev is None and overlay is not None:
+                    prev = overlay.updates.get((ns, key))
+                if prev is None:
+                    prev = self.state.get_state(ns, key)
+                if prev is None or prev.value is None:
+                    continue
+                batch.put(ns, key, prev.value, (block_num, ptx.idx),
+                          metadata=rwp.m_md[j])
 
     def _mvcc_inputs(self, txs, overlay=None):
         mvcc_txs = []

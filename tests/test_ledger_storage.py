@@ -80,12 +80,14 @@ def test_statedb_range_and_rich_query(db):
 def _columnar(block_num, ops):
     """A ``ColumnarUpdateBatch`` as the validator builds one, from
     ``ops`` = [(ns, key, value | None, txnum)] in apply order (None
-    deletes; a key may come more than once, the last wins)."""
+    deletes; a key may come more than once, the last wins).  A fifth
+    member is the row's metadata (key-level endorsement)."""
     ns_names = sorted({op[0] for op in ops})
     uids, ukeys, ns_of = {}, [], []
     row_uid, row_del, voff, vlen, txnums = [], [], [], [], []
     blob = bytearray()
-    for ns, key, value, txnum in ops:
+    metas = [op[4] if len(op) > 4 else None for op in ops]
+    for ns, key, value, txnum in (op[:4] for op in ops):
         uid = uids.setdefault((ns, key), len(uids))
         if uid == len(ukeys):
             ukeys.append(key)
@@ -100,7 +102,7 @@ def _columnar(block_num, ops):
         block_num, ns_names, ukeys, np.array(ns_of, np.int64),
         np.array(row_uid, np.int64), np.array(row_del, bool),
         np.array(voff, np.int64), np.array(vlen, np.int64),
-        np.array(txnums, np.int64), bytes(blob))
+        np.array(txnums, np.int64), bytes(blob), metas)
 
 
 @pytest.fixture
@@ -231,33 +233,139 @@ def test_sqlite_block_delete_goes_by_the_primary_key_index(sq):
     assert len(list(sq.iter_all())) == 40
 
 
-def test_sqlite_metadata_takes_the_per_key_path(sq):
-    """Key-level endorsement metadata needs the per-key probe: a batch
-    that carries metadata, and any batch on a DB that tracks some, take
-    the per-key loop, and ``meta_count`` stays right."""
+def test_sqlite_metadata_rides_the_block_path(sq):
+    """Key-level endorsement metadata is a column of the block's one
+    statement: a columnar batch takes the block path whatever the DB
+    tracks, a post-build override (a metadata-only write, the pvt phase)
+    and an ``UpdateBatch`` the per-key loop, and ``meta_count`` stays
+    right through both."""
     sq.apply_updates(_columnar(1, [("ns", "a", b"a", 0),
                                    ("ns", "m", b"m", 1)]), (1, 0))
-    assert sq.apply_fast_blocks == 1
-    cb = _columnar(2, [("ns", "b", b"b", 0)])
+    assert sq.apply_fast_blocks == 1 and sq.meta_count == 0
+    cb = _columnar(2, [("ns", "b", b"b", 0, b"owner")])
     cb.put("ns", "m", b"m2", (2, 1), metadata=b"policy")
     sq.apply_updates(cb, (2, 0))
-    assert sq.apply_fast_blocks == 1
-    assert sq.meta_count == 1
+    assert sq.apply_fast_blocks == 2 and sq.apply_statements == 2
+    assert sq.meta_count == 2
     assert sq.get_state("ns", "m").metadata == b"policy"
-    # tracked metadata: a plain columnar batch probes key by key, and
-    # its rewrite of ``m`` takes the metadata away
+    assert sq.get_state("ns", "b").metadata == b"owner"
+    # a plain row over a key that had metadata takes it away (the
+    # validator carries it along where it is to stay), a delete too
     sq.apply_updates(_columnar(3, [("ns", "m", b"m3", 0),
-                                   ("ns", "a", None, 1)]), (3, 0))
-    assert sq.apply_fast_blocks == 1
-    assert sq.meta_count == 0 and sq.apply_statements == 1
+                                   ("ns", "b", None, 1),
+                                   ("ns", "a", b"a3", 2, b"late")]), (3, 0))
+    assert sq.apply_fast_blocks == 3
+    assert sq.meta_count == 1 and sq.apply_statements == 4
     assert sq.get_state("ns", "m").metadata is None
-    assert sq.get_state("ns", "a") is None
-    sq.apply_updates(_columnar(4, [("ns", "c", b"c", 0)]), (4, 0))
-    assert sq.apply_fast_blocks == 2
+    assert sq.get_state("ns", "b") is None
+    assert sq.get_state("ns", "a").metadata == b"late"
     ub = UpdateBatch()
-    ub.put("ns", "d", b"d", (5, 0))
-    sq.apply_updates(ub, (5, 0))
-    assert sq.apply_fast_blocks == 2
+    ub.put("ns", "a", b"a4", (4, 0))
+    sq.apply_updates(ub, (4, 0))
+    assert sq.apply_fast_blocks == 3 and sq.meta_count == 0
+
+
+@pytest.mark.parametrize("seed", [34, 3400])
+def test_sqlite_block_path_with_a_metadata_column_equals_mem(sq, seed):
+    """30 seeded columnar blocks whose rows set, carry along, replace
+    and clear metadata, and delete keys that hold some: sqlite by one
+    statement a table a block, ``MemVersionedDB`` key by key, the same
+    state and the same exact ``meta_count`` after every block."""
+    rng = random.Random(seed)
+    mem = MemVersionedDB()
+    mem.open()
+    pool = [(ns, f"k{i:02d}") for ns in ("cc", "lscc") for i in range(16)]
+    owners = [b"org1", b"org2", b"org3", None, None]
+    for num in range(1, 31):
+        ops = []
+        for txnum in range(rng.randint(1, 10)):
+            for ns, key in rng.sample(pool, rng.randint(1, 3)):
+                value = (None if rng.random() < 0.2
+                         else rng.randbytes(rng.randint(0, 40)))
+                ops.append((ns, key, value, txnum, rng.choice(owners)))
+            ops.append(("cc", f"w{num}_{txnum}", b"fresh", txnum,
+                        rng.choice(owners)))
+        batches = [_columnar(num, ops), _columnar(num, ops)]
+        if num % 7 == 0:
+            for b in batches:     # a metadata-only write: an override
+                b.put("cc", "k03", b"kept", (num, 100), metadata=b"org2")
+        stmts0 = sq.apply_statements
+        sq.apply_updates(batches[0], (num, 0))
+        mem.apply_updates(batches[1], (num, 0))
+        assert list(sq.iter_all()) == list(mem.iter_all())
+        want = sum(1 for _k, vv in mem.iter_all() if vv.metadata)
+        assert sq.meta_count == mem.meta_count == want
+        # a namespace's rows in one upsert, and one delete where it deletes
+        assert 1 <= sq.apply_statements - stmts0 <= 4
+    assert sq.apply_fast_blocks == 30 and want > 0
+
+
+def test_sqlite_counts_metadata_only_where_it_holds_some(sq):
+    """The counting statement runs on a DB that holds metadata and on
+    no other: a channel that never set a parameter sends ``state`` the
+    block's one statement and nothing else."""
+    sent = _state_statements(sq._conn)
+    sq.apply_updates(_columnar(1, [("ns", "a", b"a", 0),
+                                   ("ns", "b", b"b", 1)]), (1, 0))
+    assert len(sent) == 1 and sent[0].startswith("INSERT INTO state")
+    sq.apply_updates(_columnar(2, [("ns", "a", b"a2", 0, b"org1")]), (2, 0))
+    assert len(sent) == 2 and sq.meta_count == 1
+    sq.apply_updates(_columnar(3, [("ns", "a", b"a3", 0, b"org2"),
+                                   ("ns", "c", b"c", 1)]), (3, 0))
+    assert [q.split()[0] for q in sent[2:]] == ["WITH", "INSERT"]
+    assert "COUNT(*)" in sent[2] and sq.meta_count == 1
+    assert sq.apply_statements == 3     # the statements that write
+
+
+@pytest.mark.parametrize("backend", ["mem", "sqlite", "engine"])
+def test_the_version_gather_brings_the_metadata_of_flagged_keys(
+        tmp_path, backend):
+    """``get_versions_cols(keys, meta)``: present and version of every
+    key as ever, and the metadata of the flagged ones; the apply
+    engine's pending entries win for it as for versions, a pending
+    delete clears it."""
+    from fabric_tpu.ledger.committer import AsyncApplyEngine
+
+    inner = (SqliteVersionedDB(str(tmp_path / "s.db"))
+             if backend == "sqlite" else MemVersionedDB())
+    inner.open()
+    ub = UpdateBatch()
+    ub.put("ns", "a", b"a", (1, 0), metadata=b"org1")
+    ub.put("ns", "b", b"b", (1, 1), metadata=b"org2")
+    ub.put("ns", "c", b"c", (1, 2))
+    ub.put("ns", "d", b"d", (1, 3), metadata=b"org3")
+    inner.apply_updates(ub, (1, 0))
+    keys = [("ns", k) for k in "abcdx"]
+    db, want = inner, [b"org1", None, None, b"org3", None]
+    gate = None
+    if backend == "engine":
+        import threading
+
+        gate, real = threading.Event(), inner.apply_updates
+        inner.apply_updates = lambda b, sp: (gate.wait(30.0), real(b, sp))
+        db = AsyncApplyEngine(inner)
+        pend = UpdateBatch()
+        pend.put("ns", "a", b"a2", (2, 0), metadata=b"org2")
+        pend.delete("ns", "d", (2, 1))
+        pend.put("ns", "x", b"x", (2, 2))
+        db.submit(2, pend, (2, 0))
+        want = [b"org2", None, None, None, None]
+    try:
+        flag = np.array([True, False, True, True, True])
+        present, vers, metas = db.get_versions_cols(keys, flag)
+        assert metas == want       # b is not flagged, c holds none
+        plain = db.get_versions_cols(keys)
+        assert len(plain) == 2
+        assert (plain[0] == present).all() and (plain[1] == vers).all()
+        if backend == "engine":
+            assert present.tolist() == [True, True, True, False, True]
+            assert tuple(vers[0]) == (2, 0)
+        else:
+            assert present.tolist() == [True, True, True, True, False]
+    finally:
+        if gate is not None:
+            gate.set()
+        db.close()
 
 
 def test_sqlite_block_is_one_transaction(sq):
@@ -367,6 +475,28 @@ def test_sqlite_reader_sees_a_block_at_its_commit(sq):
     assert sq.get_versions_bulk(keys) == {
         ("ns", "a"): (2, 0), ("ns", "b"): (2, 1), ("ns", "c"): (2, 2)}
     assert sq.savepoint() == (2, 0)
+
+
+def test_sqlite_reader_sees_a_blocks_metadata_at_its_commit(sq):
+    """As above for the metadata column: while the block's statement
+    has run and ``commit()`` has not, the gather on the read connection
+    answers the old parameter; after it, the new one."""
+    keys = [("ns", "a"), ("ns", "b")]
+    flag = np.array([True, True])
+    sq.apply_updates(_columnar(1, [("ns", "a", b"a1", 0, b"org1")]), (1, 0))
+    seen = []
+
+    def at_commit(q):
+        if q == "COMMIT":
+            assert sq._conn.in_transaction
+            seen.append((sq.get_versions_cols(keys, flag)[2], sq.meta_count))
+
+    sq._conn.set_trace_callback(at_commit)
+    sq.apply_updates(_columnar(2, [("ns", "a", b"a2", 0, b"org2"),
+                                   ("ns", "b", b"b2", 1, b"org3")]), (2, 0))
+    sq._conn.set_trace_callback(None)
+    assert seen == [([b"org1", None], 2)]
+    assert sq.get_versions_cols(keys, flag)[2] == [b"org2", b"org3"]
 
 
 # ---------------------------------------------------------------------------
