@@ -215,6 +215,10 @@ class KVLedger:
             )
         hists[0].observe(t1 - t0)
         hists[1].observe(t2 - t1)
+        # the block is with the apply engine (or applied): the index's
+        # WAL is written back here, one group of blocks at a time, and
+        # not inside a later block's ``commit.index``
+        self.blocks.checkpoint_if_due()
 
     def _purge_expired_pvt(self, num: int) -> None:
         """BTL expiry at the block boundary (pvtstatepurgemgmt analog):

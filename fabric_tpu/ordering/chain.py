@@ -294,6 +294,7 @@ class OrderingChain:
         if self.signer is not None:
             protoutil.sign_block(blk, self.signer)
         self.blocks.add_block(blk)
+        self.blocks.checkpoint_if_due()
         self._height_changed.set()
         self._height_changed = asyncio.Event()
         # consenter-set changes ride committed CONFIG envelopes
@@ -432,6 +433,7 @@ class OrderingChain:
                             )
                             break
                         self.blocks.add_block(blk)
+                        self.blocks.checkpoint_if_due()
                         self._height_changed.set()
                         self._height_changed = asyncio.Event()
                         # a pulled CONFIG block rotates membership (and
