@@ -21,8 +21,10 @@ the snapshot's height), the network's keys and worker processes that
 sign the block stream, all started before this process imports ``jax``;
 the native libraries (built on a checkout's first run); the device and
 the compile cache; the warm-up blocks through the pipeline itself, which
-trace, lower and compile (or load) the cell's two programs; the rest of
-the stream, sized from the warm-up's rate; the flush of what set-up
+trace, lower and compile (or load) the cell's programs; in a backlog run
+where every warm-up block lowered a program, a probe: the stream's next
+whole cycle of block sizes, timed; the rest of the stream, sized from a
+rate taken on blocks that lowered no program; the flush of what set-up
 wrote.
 """
 
@@ -57,13 +59,12 @@ ZERO_COUNTERS = ("fallback_blocks_total", "validator_degraded",
 #: warm-up: the pipeline and the apply queue are then full
 RAMP_BLOCKS = 2
 #: how much longer than rate x seconds a backlog stream is made.  More
-#: than it looks: the warm-up's rate is that of a short chain, and every
-#: cell is a fifth slower by the end of a window (PERF.md, PR 22); and a
-#: stream may end after four fifths of the window.  The traffic's
+#: than it looks: the rate is that of a short chain, and every cell is a
+#: fifth slower by the end of a window (PERF.md, section 6); and a stream may
+#: end after four fifths of the window.  The traffic's
 #: ``stream_hint_blocks_per_s`` is the other bound, and the larger of the
 #: two holds: the workers sign that many blocks a second of window before
-#: any warm rate is known, and the stream is never cut below it, so a
-#: cell holds a window at the hinted rate however slow its warm-up was.
+#: any rate is known, and the stream is never cut below it.
 STREAM_MARGIN = 1.15
 #: blocks held to the OpenSSL reference and read back: the first and the
 #: last of the window and this many between
@@ -160,12 +161,15 @@ class Capture(threading.Thread):
     it): that then falls after the window and disturbs no block.  Two
     ``bench.anchor`` annotations bracket the capture; each is also read on
     the harness's clock, which places the program's spans on the
-    profiler's clock."""
+    profiler's clock.  ``span``: the capture's length where the traffic
+    gives one (``capture_s``: a cell that launches many times a second
+    serialises for longer than a run may last)."""
 
-    def __init__(self, dirpath: str, seconds: float, opened):
+    def __init__(self, dirpath: str, seconds: float, opened,
+                 span: float | None = None):
         super().__init__(name="bench-capture", daemon=True)
         self.dir, self.opened = dirpath, opened
-        self.span = min(4.0, seconds / 3.0)
+        self.span = min(4.0, seconds / 3.0) if span is None else span
         self.lead = max(0.0, seconds - self.span - 0.5)
         self.anchors: list = []
         self.error = None
@@ -205,6 +209,32 @@ class Capture(threading.Thread):
         (a0, _), (a1, d1) = got
         offset = ((a0 - self.anchors[0]) + (a1 - self.anchors[1])) / 2.0
         return a0, a1 + d1, offset
+
+
+def compile_free_from(released: dict, lowered, first: int, end: int) -> int:
+    """The first of blocks ``first..end-1`` released after the last
+    lowering ended (``lowered``: when each one did); ``end`` if none was.
+    From there on no block lowered a program: a rate taken over them is
+    the program's, not the compiler's."""
+    last = max(lowered, default=-math.inf)
+    return next((b for b in range(first, end) if released[b] > last), end)
+
+
+def blocks_per_s(ack: dict, block_txs: dict, first: int, end: int) -> float:
+    """The rate of blocks ``first+1..end-1``, from the acknowledgement of
+    block ``first`` to that of block ``end-1``, counted in blocks of the
+    mean size of ``first..end-1``: where sizes vary (a cutter's cycle),
+    the txs a second over the blocks the interval holds, divided by that
+    mean; where they do not, the count of blocks over the interval."""
+    timed = [block_txs[b] for b in range(first + 1, end)]
+    mean = sum(block_txs[b] for b in range(first, end)) / (end - first)
+    return (len(timed) / (ack[end - 1] - ack[first])
+            * (sum(timed) / len(timed) / mean))
+
+
+def stream_blocks(hint: float, rate: float, seconds: float) -> int:
+    """The blocks a backlog stream holds after the ramp."""
+    return math.ceil(max(hint, STREAM_MARGIN * rate) * seconds)
 
 
 def sample_blocks(first: int, last: int, seed: int) -> list:
@@ -287,6 +317,10 @@ class CellRun:
         self.warm = int(self.traffic["warmup_blocks"])
         if self.warm < 3:
             raise RunFailed("warmup_blocks under 3 gives no warm rate")
+        #: the probe's length, where every warm-up block lowers a program
+        #: and none of them gives a rate: one whole cycle of the traffic's
+        #: block sizes, so that it holds each size once (0: no cycle)
+        self.probe = int(self.traffic.get("block_sizes", {}).get("cycle", 0))
         if self.loop == "paced":
             self.rate = float(self.traffic["rate_tx_per_s"])
         self.here = os.path.join(root, manifest.HERE)
@@ -347,8 +381,8 @@ class CellRun:
                 self.seconds, self.rate, itertools.repeat(self.T)))
         else:
             self.hint = float(self.traffic.get("stream_hint_blocks_per_s", 0))
-            self.factory.extend(self.warm + RAMP_BLOCKS + math.ceil(
-                self.hint * self.seconds))
+            self.factory.extend(self.warm + self.probe + RAMP_BLOCKS
+                                + stream_blocks(self.hint, 0.0, self.seconds))
         self.lap("keys_and_children")
 
         from fabric_tpu import native
@@ -437,7 +471,7 @@ class CellRun:
 
     def warm_up(self) -> None:
         """The cell's own shapes, through the pipeline itself; then the
-        rest of the stream, sized from the rate the warm blocks showed."""
+        rest of the stream, as :meth:`size_stream` sizes it."""
         from benchmark import harness, timeline
 
         self.rig = harness.Rig(self.ledger_dir, self.config, self.mgr,
@@ -448,21 +482,12 @@ class CellRun:
                             f"the stream starts at block {base}")
         self.assemble(warm)
         self.lap("first_blocks_wait")
-        for k in range(warm):
-            rig.feed(self.blocks, k, warm)
-        rig.drain()
-        # the first block compiled and the second was parsed under it:
-        # only the rest ran warm
-        self.warm_rate = (warm - 2) / (rig.ack[base + warm - 1]
-                                       - rig.ack[base + 1])
+        self.feed_through(0, warm)
         self.lap("warmup_blocks")
-        say(f"warm-up: {warm} blocks, then {self.warm_rate:.2f} blocks/s; "
-            f"jax {self.compiles.seconds()}, persistent cache "
-            f"{self.compiles.hits} hits / {self.compiles.misses} misses")
-        if self.loop == "backlog":
-            self.factory.extend(warm + RAMP_BLOCKS + math.ceil(
-                max(self.hint, STREAM_MARGIN * self.warm_rate)
-                * self.seconds))
+        say(f"warm-up: {warm} blocks; jax {self.compiles.seconds()}, "
+            f"persistent cache {self.compiles.hits} hits / "
+            f"{self.compiles.misses} misses")
+        self.size_stream()
         self.assemble(len(self.factory))
         if self.loop == "paced":
             # blocks smaller than the nominal size: more of them fall due
@@ -488,6 +513,60 @@ class CellRun:
         say(f"stream: blocks {base}..{base + len(self.blocks) - 1} of "
             f"{min(sizes)}..{max(sizes)} tx")
 
+    def feed_through(self, lo: int, hi: int) -> None:
+        """Feed the stream's blocks ``lo..hi-1`` and drain the pipeline."""
+        k = lo
+        while k < hi:
+            k += self.rig.feed(self.blocks, k, hi)
+        self.rig.drain()
+
+    def size_stream(self) -> None:
+        """The rate that sizes a backlog stream, taken on blocks that
+        lowered no program: the warm-up's last ones or, where every
+        warm-up block lowered one, a probe of the stream's next
+        ``self.probe`` blocks, fed before the ramp; then the rest of the
+        stream, ``stream_blocks`` after the ramp.  ``lead``: the blocks
+        fed before the ramp."""
+        rig, base, warm = self.rig, self.base, self.warm
+        lowered = [t for part, t, _d in self.compiles.events
+                   if part == "lower"]
+        # the first block compiled and the second was parsed under it:
+        # the rate starts at the second's acknowledgement at the earliest
+        k = compile_free_from(rig.released, lowered, base + 2, base + warm)
+        self.lead, self.rate_from = warm, (k - 1, base + warm)
+        if self.loop == "backlog" and k == base + warm:
+            n = self.probe
+            if n < 2:
+                raise RunFailed("every warm-up block lowered a program and "
+                                "the traffic has no cycle of block sizes "
+                                "to time instead")
+            self.factory.extend(max(len(self.factory), warm + n))
+            # the probe runs as the window will: the workers done with
+            # what they were asked for first, and the stream's objects
+            # out of the collector's walks
+            self.assemble(len(self.factory))
+            gc.collect()
+            gc.freeze()
+            self.cleanup.callback(gc.unfreeze)
+            self.lap("rest_of_stream_wait")
+            self.feed_through(warm, warm + n)
+            self.lead, self.rate_from = warm + n, (base + warm,
+                                                   base + warm + n)
+            self.lap("probe_blocks")
+        lo, hi = self.rate_from
+        self.stream_rate = (blocks_per_s(rig.ack, self.block_txs, lo, hi)
+                            if hi - lo >= 2 else None)
+        if self.loop == "backlog":
+            self.factory.extend(self.lead + RAMP_BLOCKS + stream_blocks(
+                self.hint, self.stream_rate, self.seconds))
+        if self.stream_rate is not None:
+            lowered = self.compiles.lowered_between(rig.released[lo + 1],
+                                                    rig.ack[hi - 1])
+            say(f"rate: {self.stream_rate:.2f} blocks/s over blocks "
+                f"{lo + 1}..{hi - 1} ({self.lead - warm} probe blocks, "
+                f"{lowered} programs lowered); stream of "
+                f"{len(self.factory)} blocks")
+
     # -- the window ----------------------------------------------------------
 
     def measure(self) -> None:
@@ -498,11 +577,12 @@ class CellRun:
         rig, opened = self.rig, threading.Event()
         if self.trace:
             self.capture = Capture(os.path.join(self.work, "trace"),
-                                   self.seconds, opened)
+                                   self.seconds, opened,
+                                   self.traffic.get("capture_s"))
             self.capture.start()
         if self.loop == "backlog":
             ran = harness.run_backlog(
-                rig, self.blocks, self.warm, RAMP_BLOCKS, self.seconds,
+                rig, self.blocks, self.lead, RAMP_BLOCKS, self.seconds,
                 on_open=lambda _t: opened.set())
         else:
             ran = harness.run_paced(
@@ -725,7 +805,10 @@ class CellRun:
         result["window"] = {
             "first_block": first, "last_block": last,
             "seconds": self.t_close - self.t_open, "valid_tx": self.n_valid,
-            "warm_blocks_per_s": self.warm_rate,
+            "rate_blocks_per_s": self.stream_rate,
+            "rate_blocks": [self.rate_from[0] + 1, self.rate_from[1] - 1],
+            "probe_blocks": self.lead - self.warm,
+            "stream_blocks": len(self.blocks),
             "applied_s": [round(self.applied[b] - self.t_open, 4)
                           for b in range(first, self.submitted)
                           if b in self.applied],

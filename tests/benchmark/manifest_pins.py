@@ -30,11 +30,13 @@ ONE_SHAPE = {"h2d_bytes_per_block", "verify_kernel_ms", "stage2_kernel_ms",
              "verify_roofline"}
 OLD_CELLS = ["rw_backlog", "insert_backlog", "zipf_backlog"]
 ONLY_PACED = {"generator_lag_p95_ms", "apply_lag_ms"}
-#: ``end_to_end`` begins with these: name, bound, the cells it lists
+#: ``end_to_end`` begins with these: name, bound, the cells it lists.
+#: The paced pair's bounds were set anew from two sets of six runs
+#: (PERF.md, section 2)
 END_TO_END = [
     ("commit_tx_per_s", 0.07, OLD_CELLS + [CELL]),
-    ("tx_commit_p50_ms", 0.03, ["rw_paced"]),
-    ("tx_commit_p95_ms", 0.03, ["rw_paced"]),
+    ("tx_commit_p50_ms", 0.05, ["rw_paced"]),
+    ("tx_commit_p95_ms", 0.08, ["rw_paced"]),
     ("setup_s", 0.25, None),
 ]
 #: cell → (configuration, traffic file, chips), in the order listed

@@ -15,7 +15,7 @@ import os
 import textwrap
 
 import pytest
-from bench_tiny import make_root
+from bench_tiny import TINY_HINT, make_root
 from benchmark import run
 from fabric_tpu.utils.xla_env import claim_device
 
@@ -55,7 +55,7 @@ def test_a_new_cell_and_its_metrics_are_files_and_entries(tmp_path):
             "reads": ["all", "all"], "writes": ["all", "fresh"],
             "invalid_share": 0.1,
             "invalid_kinds": ["bad_endorsement_signature"],
-            "warmup_blocks": 3, "stream_hint_blocks_per_s": 3,
+            "warmup_blocks": 3, "stream_hint_blocks_per_s": TINY_HINT,
         }, f)
     with open(os.path.join(bench, "end_to_end", "blocks_per_s.py"), "w") as f:
         f.write(textwrap.dedent('''
@@ -246,7 +246,8 @@ def test_a_new_deployment_brings_preload_reference_and_block_sizes(
             "first_block": 7, "preload": "at_block_seven",
             "reference": "q_keys", "reduced": {},
         }, f)
-    for loop, extra in (("backlog", {"stream_hint_blocks_per_s": 6}),
+    for loop, extra in (("backlog",
+                         {"stream_hint_blocks_per_s": TINY_HINT}),
                         ("paced", {"rate_tx_per_s": 40})):
         with open(os.path.join(bench, "traffic", f"ragged-{loop}.json"),
                   "w") as f:
@@ -276,8 +277,10 @@ def test_a_new_deployment_brings_preload_reference_and_block_sizes(
                        trace=False, platform="cpu", workers=2)
     assert res["correct"], res["problems"]
     # the stream is never shorter than the hint says, however slow the
-    # warm-up was: 3 warm-up and 2 ramp blocks, and 6 a second for 2 s
-    assert "stream: blocks 7..23 of 17..20 tx" in capsys.readouterr().out
+    # warm-up was: 3 warm-up and 2 ramp blocks, and the hint's blocks a
+    # second for 2 s
+    assert (f"stream: blocks 7..{7 + 3 + 2 + 2 * TINY_HINT - 1} of 17..20 tx"
+            in capsys.readouterr().out)
     w = res["window"]
     # three warm-up blocks and two ramp blocks after block 7
     assert w["first_block"] == 7 + 3 + 2 and res["failed"] == 0
@@ -617,17 +620,20 @@ def test_a_key_level_endorsement_deployment_arrives_as_files(tmp_path,
                                                               capsys):
     """The deployment the assembler seam is for, tiny: its transactions
     (the owner's endorsement alone, validation-parameter writes), its
-    preload, its reference.  The program judges every tx as the reference
-    does, and every block on the host path: the one count the
-    ``model_config`` PR that brings ``fabric-sbe-assets`` has to turn to 0."""
+    preload, its reference, under names of its own beside the
+    repository's ``fabric-sbe-assets``.  The program judges every tx as
+    the reference does, and every block on the fused device path, as it
+    has since key-policy lanes joined the stage-2 program."""
     import re
 
     root = make_root(tmp_path)
     before = _tree(root)
     bench = os.path.join(root, "benchmark")
-    _write(os.path.join(bench, "preloads", "sbe_owned.py"), SBE_PRELOAD)
-    _write(os.path.join(bench, "references", "sbe_owned.py"), SBE_REFERENCE)
-    _write(os.path.join(bench, "generators", "sbe_assets.py"), SBE_GENERATOR)
+    _write(os.path.join(bench, "preloads", "throwaway_sbe.py"), SBE_PRELOAD)
+    _write(os.path.join(bench, "references", "throwaway_sbe.py"),
+           SBE_REFERENCE)
+    _write(os.path.join(bench, "generators", "throwaway_sbe.py"),
+           SBE_GENERATOR)
     with open(os.path.join(bench, "configs", "throwaway-sbe.json"),
               "w") as f:
         json.dump({
@@ -638,13 +644,16 @@ def test_a_key_level_endorsement_deployment_arrives_as_files(tmp_path,
                 "OutOf(2, 'Org1MSP.peer', 'Org2MSP.peer', 'Org3MSP.peer')",
             "block_tx": 20, "value_bytes": 16, "history_db": True,
             "preload_keys": 2000, "preload_version": [1, 0],
-            "preload": "sbe_owned", "reference": "sbe_owned", "reduced": {},
+            "preload": "throwaway_sbe", "reference": "throwaway_sbe",
+            "reduced": {},
         }, f)
     # no ``pools``, no ``invalid_kinds``: the generator owns its mix
-    with open(os.path.join(bench, "traffic", "sbe-backlog.json"), "w") as f:
-        json.dump({"name": "sbe-backlog", "generator": "sbe_assets",
+    with open(os.path.join(bench, "traffic", "throwaway-sbe-backlog.json"),
+              "w") as f:
+        json.dump({"name": "throwaway-sbe-backlog",
+                   "generator": "throwaway_sbe",
                    "loop": "backlog", "warmup_blocks": 3,
-                   "stream_hint_blocks_per_s": 3}, f)
+                   "stream_hint_blocks_per_s": TINY_HINT}, f)
     man_path = os.path.join(root, "BENCHMARK.json")
     with open(man_path) as f:
         man = json.load(f)
@@ -653,16 +662,17 @@ def test_a_key_level_endorsement_deployment_arrives_as_files(tmp_path,
         "file": "benchmark/configs/throwaway-sbe.json", "reduced": [],
         "why": "a test's"})
     man["workloads"].append({
-        "name": "sbe_backlog", "config": "throwaway-sbe",
-        "traffic": "sbe-backlog", "chips": 1, "why": "a test's"})
+        "name": "throwaway_sbe_backlog", "config": "throwaway-sbe",
+        "traffic": "throwaway-sbe-backlog", "chips": 1, "why": "a test's"})
     for m in man["end_to_end"]:
         if m["name"] == "commit_tx_per_s":
-            m["workloads"].append("sbe_backlog")
+            m["workloads"].append("throwaway_sbe_backlog")
     with open(man_path, "w") as f:
         json.dump(man, f)
 
     assert claim_device("test_bench")["platform"] == "cpu"
-    res = run.run_cell(root, "sbe_backlog", seed=2**31 + 9, seconds=2.0,
+    res = run.run_cell(root, "throwaway_sbe_backlog", seed=2**31 + 9,
+                       seconds=2.0,
                        trace=False, platform="cpu", workers=2)
     said = capsys.readouterr().out
     checked = int(re.search(r"checked (\d+) blocks", said).group(1))
@@ -675,22 +685,21 @@ def test_a_key_level_endorsement_deployment_arrives_as_files(tmp_path,
     n_blocks = w["last_block"] - w["first_block"] + 1
     assert w["valid_tx"] == 16 * n_blocks
     compared = {name: c["value"] for name, c in res["compared"].items()}
-    # and every block of the stream left the fused device path: what
-    # ``fabric-sbe-assets`` has to bring to 0 (PERF.md, section 7)
-    assert compared.pop("blocks_not_from_fused_device_path") == checked
+    # and every block of the stream rode the fused device path: no count
+    # of ``correct`` moved
+    assert compared["blocks_not_from_fused_device_path"] == 0
     assert not any(compared.values()), (compared, res["problems"])
-    assert res["correct"] is False
-    assert all("fused device path" in p for p in res["problems"])
+    assert res["correct"] is True and not res["problems"]
 
     after = _tree(root)
     assert {p for p in before if before[p] != after.get(p)} == {
         "BENCHMARK.json"}
     assert set(after) - set(before) == {
         "benchmark/configs/throwaway-sbe.json",
-        "benchmark/traffic/sbe-backlog.json",
-        "benchmark/generators/sbe_assets.py",
-        "benchmark/preloads/sbe_owned.py",
-        "benchmark/references/sbe_owned.py"}
+        "benchmark/traffic/throwaway-sbe-backlog.json",
+        "benchmark/generators/throwaway_sbe.py",
+        "benchmark/preloads/throwaway_sbe.py",
+        "benchmark/references/throwaway_sbe.py"}
     assert not os.listdir(os.path.join(bench, ".work"))
 
 
@@ -698,13 +707,14 @@ def test_the_tiny_copy_shrinks_files_that_leave_optional_keys_out():
     """A deployment whose generator owns its mix has no ``pools`` and no
     ``invalid_kinds``, and one whose preload owns its keys no
     ``preload_keys``: ``make_root`` shrinks what is there and adds
-    nothing for the tests' sake."""
+    nothing but the hint."""
     from bench_tiny import TINY_TX, shrink_config, shrink_traffic
 
     traffic = {"name": "own-mix", "generator": "own", "loop": "backlog"}
     shrink_traffic(traffic)
     assert traffic == {"name": "own-mix", "generator": "own",
-                       "loop": "backlog", "stream_hint_blocks_per_s": 2}
+                       "loop": "backlog",
+                       "stream_hint_blocks_per_s": TINY_HINT}
     config = {"name": "own-keys", "block_tx": 500}
     shrink_config(config)
     assert config == {"name": "own-keys", "block_tx": TINY_TX}

@@ -331,20 +331,28 @@ def test_the_cell_and_its_metrics_are_in_the_manifest():
 
 
 def test_the_cell_runs_tiny_from_its_own_files(tmp_path, capsys):
+    import jax
+
     from fabric_tpu.utils.xla_env import claim_device
 
     root = make_root(tmp_path)
     assert claim_device("test_bench")["platform"] == "cpu"
+    # the warm-up lowers its programs, as in a run's own process, even
+    # where an earlier test of this one traced them
+    jax.clear_caches()
     res = run.run_cell(root, CELL, seed=2**31 + 5, seconds=2.0,
                        trace=False, platform="cpu", workers=2)
     assert res["correct"], res["problems"]
     assert res["failed"] == 0 and res["attempted"] > 0
     assert set(res["metrics"]) == {"commit_tx_per_s", "setup_s"}
     assert all(c["value"] == 0 for c in res["compared"].values())
-    # twelve warm-up blocks, two ramp blocks, then the window; the copy's
-    # blocks are cut to 20 txs
+    # twelve warm-up blocks, each of which lowers a program, the probe
+    # that times the rate instead (one whole cycle), two ramp blocks, then
+    # the window; the copy's blocks are cut to 20 txs
     w = res["window"]
-    assert w["first_block"] == 12 + 2
+    assert w["probe_blocks"] == 64
+    assert w["rate_blocks"] == [12 + 1, 12 + 64 - 1]
+    assert w["first_block"] == 12 + 64 + 2
     assert "of 10..20 tx" in capsys.readouterr().out
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         assert any(x["name"] == CELL for x in json.load(f)["workloads"])

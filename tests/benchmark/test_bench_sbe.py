@@ -67,9 +67,13 @@ def test_the_cell_lists_what_its_control_lists_and_its_own_three():
         man, "per_layer", "rw_backlog")}
     own = {"key_lanes_ms", "key_policy_entries_per_block", "apply_meta_rows"}
     assert mine == control | own and not control & own
-    assert [m["name"] for m in man["per_layer"][-3:]] == [
+    # these three, in this order, right after ``ragged_backlog``'s last
+    # (what later PRs append comes after them)
+    names = [m["name"] for m in man["per_layer"]]
+    at = names.index("apply_ms_per_ktx") + 1
+    assert names[at:at + 3] == [
         "key_lanes_ms", "key_policy_entries_per_block", "apply_meta_rows"]
-    for m in man["per_layer"][-3:]:
+    for m in man["per_layer"][at:at + 3]:
         assert m["workloads"] == [CELL]
         mod = manifest.load_module("layer_metrics", m["name"])
         assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES) == (
