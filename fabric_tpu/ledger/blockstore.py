@@ -23,6 +23,15 @@ from google.protobuf.message import DecodeError
 
 from fabric_tpu import faults as _faults
 from fabric_tpu import protoutil
+# the index's names for the write-back constants the three stores share;
+# a store takes the bound in force when it opens
+from fabric_tpu.ledger.walcheckpoint import (  # noqa: F401
+    BACKSTOP_FACTOR as _BACKSTOP_FACTOR,
+    CACHE_KIB as _IDX_CACHE_KIB,
+    CHECKPOINT_ROWS as _CHECKPOINT_ROWS,
+    WalCheckpoint,
+    writer_pragmas,
+)
 from fabric_tpu.observe.tracer import global_tracer
 from fabric_tpu.protos import common_pb2
 
@@ -30,23 +39,6 @@ _log = logging.getLogger("fabric_tpu.ledger")
 
 _SEGMENT_MAX = 64 * 1024 * 1024
 _LEN = struct.Struct("<I")
-#: page cache of the index's write connection, in KiB.  Sized to what
-#: it has to hold, not to the index: the pages ONE transaction dirties
-#: (about one leaf a txid once the tree has a few thousand leaves, plus
-#: the interior pages above them) for the largest block the orderer's
-#: ``BatchSize`` cuts, so that no dirty page spills to the WAL inside
-#: the transaction and is written a second time at its commit.  A
-#: 1000-tx block dirties about 4 MB.
-_IDX_CACHE_KIB = 16 * 1024
-#: txid rows inserted since the last WAL checkpoint past which
-#: ``checkpoint_if_due`` makes the next one.  Rows, not blocks: pages
-#: follow rows (a leaf a row on a long chain, so a WAL of about 32 MB).
-#: It bounds the WAL's size and nothing else.
-_CHECKPOINT_ROWS = 8000
-#: the backstop, as a multiple of ``_CHECKPOINT_ROWS``: sqlite's own
-#: ``wal_autocheckpoint`` in pages, and the rows past which ``add_block``
-#: checkpoints by itself because nobody called ``checkpoint_if_due``
-_BACKSTOP_FACTOR = 4
 #: one tree a txid: the primary key IS the table, where a rowid table
 #: keeps the rows in one b-tree and the random 64-character key a
 #: second time in the automatic index beside it
@@ -136,20 +128,16 @@ class BlockStore:
         # itself crash-safe — OFF can corrupt the main DB file on
         # power loss, and there is no drop-and-rebuild path
         self._idx.execute("PRAGMA synchronous=NORMAL")
-        self._idx.execute(f"PRAGMA cache_size=-{_IDX_CACHE_KIB}")
         # the writer decides when the WAL is written back
         # (checkpoint_if_due: between transactions, after the block is
-        # handed to the applier); sqlite's own threshold, which fires
-        # inside a commit, stays as the backstop
-        self._idx.execute(
-            f"PRAGMA wal_autocheckpoint={_BACKSTOP_FACTOR * _CHECKPOINT_ROWS}"
-        )
-        # txid rows inserted since the last checkpoint, and whether the
-        # last one left frames behind (a reader's snapshot in its way):
-        # both belong to the writing thread
-        self._ckpt_rows = 0
-        self._ckpt_retry = False
-        self._ckpt_ctr = None  # lazy blockstore_index_checkpoints_total
+        # handed to the applier), on its own connection: the thread that
+        # writes is the thread that checkpoints
+        writer_pragmas(self._idx, _CHECKPOINT_ROWS)
+        self._wal = WalCheckpoint(
+            self._idx, self._idx_lock, bound=_CHECKPOINT_ROWS,
+            counter=("blockstore_index_checkpoints_total",
+                     "WAL checkpoints of the block index by trigger"),
+            span="commit.checkpoint")
         self._idx.execute(
             "CREATE TABLE IF NOT EXISTS blocks ("
             " num INTEGER PRIMARY KEY, hash BLOB, seg INTEGER, off INTEGER)"
@@ -312,7 +300,7 @@ class BlockStore:
             (txid, num, i, flags[i] if i < len(flags) else 254)
             for txid, i in txids if txid
         ]
-        self._ckpt_rows += len(rows)
+        self._wal.note(len(rows))
         # the block's rows in one statement (as many as sqlite takes
         # variables in one), not one step a row: sqlite3 hands the
         # interpreter lock over around every step, and 1000 hand-overs
@@ -395,10 +383,10 @@ class BlockStore:
             "INSERT OR REPLACE INTO bootstrap VALUES (0, ?, ?, ?)",
             (first_block, prev_hash, commit_hash),
         )
-        self._ckpt_rows += self._idx.executemany(
+        self._wal.note(self._idx.executemany(
             "INSERT OR IGNORE INTO txids VALUES (?,?,?,?)",
             ((t, -1, -1, c) for t, c in txid_codes),
-        ).rowcount
+        ).rowcount)
         self._idx.commit()
         self.checkpoint_if_due()
 
@@ -509,56 +497,31 @@ class BlockStore:
                     txids=len(block.data.data if txids is None else txids),
                 )
         self._last_hash = protoutil.block_header_hash(block.header)
-        if self._ckpt_rows >= _BACKSTOP_FACTOR * _CHECKPOINT_ROWS:
-            # nobody called checkpoint_if_due for a whole multiple of
-            # its bound: the WAL must not grow without one
-            self._checkpoint("backstop")
+        # where nobody called checkpoint_if_due for a whole multiple of
+        # its bound: the WAL must not grow without one
+        self._wal.backstop()
 
     def checkpoint_if_due(self) -> None:
         """Write the index's WAL back into ``index.db`` once a group of
         blocks has put ``_CHECKPOINT_ROWS`` txids into it, or where the
-        last try left frames behind.  For the thread that appends, once
-        the block is out of its hands: ``KVLedger.commit_block`` calls
-        this after the block went to the apply engine (or was applied),
-        the orderer's chain where its append ends.  Between index
-        transactions and outside ``commit.index``, so no block's
-        ``add_block`` pays for the pages of eight."""
-        if self._ckpt_retry or self._ckpt_rows >= _CHECKPOINT_ROWS:
-            self._checkpoint("group")
+        last try left frames behind (``WalCheckpoint.if_due``; span
+        ``commit.checkpoint``, counter
+        ``blockstore_index_checkpoints_total{trigger}``).  For the
+        thread that appends, once the block is out of its hands:
+        ``KVLedger.commit_block`` calls this after the block went to the
+        apply engine (or was applied), the orderer's chain where its
+        append ends.  Between index transactions and outside
+        ``commit.index``, so no block's ``add_block`` pays for the pages
+        of eight."""
+        self._wal.if_due()
 
-    def _checkpoint(self, trigger: str) -> None:
-        """``blockstore_index_checkpoints_total{trigger}``: ``group``
-        (checkpoint_if_due), ``backstop`` (add_block found the bound
-        passed ``_BACKSTOP_FACTOR`` times over), ``close``.  PASSIVE: it
-        waits for nobody and writes back what no reader's snapshot
-        still needs; where that is not the whole WAL (``busy``) the
-        next block's call tries again, and the WAL starts over only
-        once a try went through."""
-        ctr = self._ckpt_ctr
-        if ctr is None:
-            from fabric_tpu.ops_metrics import global_registry
+    @property
+    def _ckpt_rows(self) -> int:
+        return self._wal.rows
 
-            ctr = self._ckpt_ctr = global_registry().counter(
-                "blockstore_index_checkpoints_total",
-                "WAL checkpoints of the block index by trigger",
-            )
-        ctr.add(1, trigger=trigger)
-        tracer = global_tracer()
-        cur = tracer.current()
-        # under the block's root, like the applier's ``apply``: it is
-        # no part of the block's own commit
-        with tracer.span("commit.checkpoint",
-                         parent=cur.root if cur is not None else None,
-                         rows=self._ckpt_rows) as csp:
-            with self._idx_lock:
-                busy, frames, moved = self._idx.execute(
-                    "PRAGMA wal_checkpoint(PASSIVE)"
-                ).fetchone()
-            self._ckpt_retry = bool(busy) or moved < frames
-            self._ckpt_rows = 0
-            if csp is not None:
-                csp.attrs.update(frames=frames, moved=moved,
-                                 busy=int(self._ckpt_retry))
+    @property
+    def _ckpt_retry(self) -> bool:
+        return self._wal.retry
 
     def _read_at(self, seg: int, off: int) -> common_pb2.Block | None:
         try:
@@ -662,8 +625,7 @@ class BlockStore:
         self.sync()
         self._fh.close()
         self._rd.close()
-        if self._ckpt_rows or self._ckpt_retry:
-            self._checkpoint("close")
+        self._wal.close()
         # the last connection to close writes back whatever is left
         # and removes the ``-wal``
         self._idx.close()

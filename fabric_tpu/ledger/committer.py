@@ -136,8 +136,13 @@ class AsyncApplyEngine(VersionedDB):
     """
 
     def __init__(self, inner: VersionedDB, blocks=None,
-                 queue_blocks: int = 4, name: str = "state-applier"):
+                 queue_blocks: int = 4, name: str = "state-applier",
+                 after_apply=None):
+        """``after_apply(root)``: run on the applier thread once each
+        block's apply is published (out of the queue, its waiters
+        woken), with the tracer root of the block (or None)."""
         self._inner = inner
+        self._after_apply = after_apply
         self._blocks = blocks  # durability fence (BlockStore), optional
         self._capacity = max(1, int(queue_blocks))
         self._name = name
@@ -221,11 +226,7 @@ class AsyncApplyEngine(VersionedDB):
             try:
                 dur = self._apply_one(entry)
             except BaseException as e:  # latch: ordered apply can't skip
-                _log.error("state apply of block %d failed: %s",
-                           entry.num, e)
-                with self._cond:
-                    self._error = e
-                    self._cond.notify_all()
+                self._fail(e, "state apply of block %d", entry.num)
                 return
             with self._cond:
                 # abort() may have dropped the queue mid-apply
@@ -236,6 +237,18 @@ class AsyncApplyEngine(VersionedDB):
                 self._apply_s_total += dur
                 self._cond.notify_all()
             self._observe(dur)
+            if self._after_apply is not None:
+                try:
+                    self._after_apply(entry.root)
+                except BaseException as e:
+                    self._fail(e, "write-back after block %d", entry.num)
+                    return
+
+    def _fail(self, e: BaseException, what: str, num: int) -> None:
+        _log.error(what + " failed: %s", num, e)
+        with self._cond:
+            self._error = e
+            self._cond.notify_all()
 
     def _apply_one(self, entry: _Pending) -> float:
         # the block's tree was finished when its commit returned: these
@@ -462,6 +475,10 @@ class AsyncApplyEngine(VersionedDB):
                 if entry.sp is not None:
                     return entry.sp
         return self._inner.savepoint()
+
+    def checkpoint_if_due(self) -> None:
+        """The inner DB's, on the calling thread."""
+        self._inner.checkpoint_if_due()
 
     @property
     def meta_count(self):

@@ -6,6 +6,7 @@ from __future__ import annotations
 import sqlite3
 
 from fabric_tpu.ledger.sqlrows import row_statements
+from fabric_tpu.ledger.walcheckpoint import WalCheckpoint, writer_pragmas
 
 
 class HistoryDB:
@@ -18,6 +19,7 @@ class HistoryDB:
         # OFF can corrupt the DB file itself on power loss, and there
         # is no drop-and-rebuild path on open
         self._conn.execute("PRAGMA synchronous=NORMAL")
+        writer_pragmas(self._conn)
         self._conn.execute(
             "CREATE TABLE IF NOT EXISTS hist ("
             " ns TEXT, key TEXT, block INTEGER, txnum INTEGER,"
@@ -27,6 +29,9 @@ class HistoryDB:
             "CREATE TABLE IF NOT EXISTS savepoint ("
             " id INTEGER PRIMARY KEY CHECK (id = 0), block INTEGER)"
         )
+        # the write-back's connection of its own: on ``_conn`` a
+        # checkpoint from the committer would wait for the applier
+        self._wal = WalCheckpoint.own(path, "history")
 
     def commit_block(self, block_num: int,
                      writes: list[tuple[str, str, int]]) -> tuple[int, int]:
@@ -46,7 +51,14 @@ class HistoryDB:
             "INSERT OR REPLACE INTO savepoint VALUES (0,?)", (block_num,)
         )
         self._conn.commit()
+        self._wal.note(len(rows))
+        self._wal.backstop()
         return len(rows), stmts
+
+    def checkpoint_if_due(self) -> None:
+        """``history.db``'s WAL, written back as ``state.db``'s is
+        (``SqliteVersionedDB.checkpoint_if_due``)."""
+        self._wal.if_due()
 
     def get_history_for_key(self, ns: str, key: str):
         """Yield (block, txnum) newest-first (like the reference's
@@ -62,4 +74,5 @@ class HistoryDB:
         return row[0] if row else None
 
     def close(self):
+        self._wal.close()
         self._conn.close()

@@ -68,6 +68,7 @@ class KVLedger:
             self.engine = AsyncApplyEngine(
                 inner, blocks=self.blocks,
                 queue_blocks=apply_queue_blocks,
+                after_apply=self._after_apply,
             )
         self.state = self.engine if self.engine is not None else inner
         self._reconcile_on_open()
@@ -81,6 +82,10 @@ class KVLedger:
         # the async engine the latter is enqueue + backpressure only)
         self.last_commit_timings: dict = {}
         self._commit_hists = None  # lazy registry histograms
+        # (seconds, blocks) of the ledger's own work on the committing
+        # thread since open: ``commit_block`` up to the hand-over,
+        # without its wait at a full apply queue (``_applier_writes_back``)
+        self._commit_load = (0.0, 0)
 
     def _reconcile_on_open(self) -> None:
         """Height/savepoint reconciliation (recoverDBs preamble): the
@@ -196,12 +201,14 @@ class KVLedger:
             "ledger_append": t1 - t0,
             "state_apply": t2 - t1,
         }
+        parked = getattr(self.engine, "backpressure_s", 0.0) - parked0
+        spent, blocks = self._commit_load
+        self._commit_load = (spent + t2 - t0 - parked, blocks + 1)
         # the same three clock reads as spans, under the committing
         # thread's ``commit`` (no-ops off a traced commit)
         tracer = global_tracer()
         tracer.add("commit.append", t0, t1)
-        tracer.add("commit.enqueue", t1, t2, backpressure_ms=(
-            getattr(self.engine, "backpressure_s", 0.0) - parked0) * 1000.0)
+        tracer.add("commit.enqueue", t1, t2, backpressure_ms=parked * 1000.0)
         hists = self._commit_hists
         if hists is None:
             from fabric_tpu.ops_metrics import global_registry
@@ -215,10 +222,46 @@ class KVLedger:
             )
         hists[0].observe(t1 - t0)
         hists[1].observe(t2 - t1)
-        # the block is with the apply engine (or applied): the index's
-        # WAL is written back here, one group of blocks at a time, and
-        # not inside a later block's ``commit.index``
+        # the block is with the apply engine (or applied): the WALs are
+        # written back here, one group of blocks at a time, and not
+        # inside a later block's ``commit.index`` or the applier's
+        # ``commit()``; the state's and history's here only where the
+        # applier is the busier of the two threads
         self.blocks.checkpoint_if_due()
+        if not self._applier_writes_back():
+            self._write_back()
+
+    def _applier_writes_back(self) -> bool:
+        """Whether the applier, and not the committer, writes back the
+        state and history WALs.  The checkpoint takes a thread off the
+        pipeline for as long as it copies and syncs, so it goes to the
+        one of the two that does less of the ledger's work a block, on
+        the mean since open: the committer's ``commit_block`` without
+        its wait at a full apply queue, against the applier's apply.
+        Without an apply engine the committer applies and writes back."""
+        if self.engine is None:
+            return False
+        applied = self.engine.stats()
+        spent, blocks = self._commit_load
+        return blocks > 0 and applied["apply_ms_total"] * blocks < (
+            spent * 1000.0 * applied["applies_total"])
+
+    def _write_back(self) -> None:
+        self.state.checkpoint_if_due()
+        if self.history is not None:
+            self.history.checkpoint_if_due()
+
+    def _after_apply(self, root) -> None:
+        """The applier, once a block's apply is published: the write-
+        back, under that block's tracer root, where it is the
+        applier's."""
+        if self._applier_writes_back():
+            tracer = global_tracer()
+            token = tracer.attach(root)
+            try:
+                self._write_back()
+            finally:
+                tracer.detach(token)
 
     def _purge_expired_pvt(self, num: int) -> None:
         """BTL expiry at the block boundary (pvtstatepurgemgmt analog):

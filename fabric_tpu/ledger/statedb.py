@@ -34,6 +34,7 @@ from dataclasses import dataclass
 from itertools import chain
 
 from fabric_tpu.ledger.sqlrows import row_statements
+from fabric_tpu.ledger.walcheckpoint import WalCheckpoint, writer_pragmas
 
 Version = tuple[int, int]
 
@@ -323,6 +324,12 @@ class VersionedDB:
     def savepoint(self) -> Version | None:
         raise NotImplementedError
 
+    def checkpoint_if_due(self) -> None:
+        """Write back, where the backend keeps a write-ahead log, what
+        a group of blocks put into it: ``KVLedger.commit_block`` calls
+        this on the committing thread once the block is out of its
+        hands.  Nothing to do for a backend without one."""
+
 
 class MemVersionedDB(VersionedDB):
     """In-memory backend.  Range/query iteration takes a lock against
@@ -470,12 +477,12 @@ _DELETE_ROWS = dict(
 class SqliteVersionedDB(VersionedDB):
     """Durable backend over sqlite (WAL mode).
 
-    Two connections to the one file, as the block store's index has
-    (``BlockStore._idx`` / ``_rd``).  ``_conn`` belongs to whoever
-    writes: ``apply_updates`` (the applier thread, under the async
-    engine), and the three iterators, whose cursors live as long as
-    their caller walks them (an open cursor on a WAL reader pins its
-    snapshot and stops checkpoints: see ``BlockStore._rd_rows``).
+    Two connections to the one file that read and write, as the block
+    store's index has (``BlockStore._idx`` / ``_rd``).  ``_conn``
+    belongs to whoever writes: ``apply_updates`` (the applier thread,
+    under the async engine), and the three iterators, whose cursors live
+    as long as their caller walks them (an open cursor on a WAL reader
+    pins its snapshot and stops checkpoints: see ``BlockStore._rd_rows``).
     ``_rd`` (``query_only``) answers the lookups: the block's version
     gather, ``get_state``, ``savepoint``.  In WAL mode a read on it
     never waits for the writer's transaction nor for its ``commit()``,
@@ -484,13 +491,15 @@ class SqliteVersionedDB(VersionedDB):
     supplies (``ledger/committer.py``).  ``_rd_lock`` is for the
     readers among themselves: one transaction at a time on the
     connection, and two threads on one statement text would share its
-    prepared statement.
+    prepared statement.  A third connection (``_wal``) only writes the
+    WAL back (``checkpoint_if_due``).
     """
 
     def __init__(self, path: str):
         self.path = path
         self._conn: sqlite3.Connection | None = None
         self._rd: sqlite3.Connection | None = None
+        self._wal: WalCheckpoint | None = None
         self._rd_lock = threading.Lock()
         # seconds readers spent WAITING for ``_rd_lock`` (held by
         # another reader for one gather or one lookup).  Only a
@@ -509,6 +518,7 @@ class SqliteVersionedDB(VersionedDB):
         self._conn = sqlite3.connect(self.path, check_same_thread=False)
         self._conn.execute("PRAGMA journal_mode=WAL")
         self._conn.execute("PRAGMA synchronous=NORMAL")
+        writer_pragmas(self._conn)
         self._conn.execute(
             "CREATE TABLE IF NOT EXISTS state ("
             " ns TEXT NOT NULL, key TEXT NOT NULL,"
@@ -532,6 +542,9 @@ class SqliteVersionedDB(VersionedDB):
         self._rd = sqlite3.connect(self.path, check_same_thread=False,
                                    isolation_level=None)
         self._rd.execute("PRAGMA query_only=ON")
+        # the write-back's connection of its own: on ``_conn`` a
+        # checkpoint from the committer would wait for the applier
+        self._wal = WalCheckpoint.own(self.path, "state")
 
     def close(self):
         # the reader first: the LAST connection to close checkpoints
@@ -539,6 +552,9 @@ class SqliteVersionedDB(VersionedDB):
         if self._rd:
             self._rd.close()
             self._rd = None
+        if self._wal:
+            self._wal.close()
+            self._wal = None
         if self._conn:
             self._conn.close()
             self._conn = None
@@ -698,6 +714,7 @@ class SqliteVersionedDB(VersionedDB):
         # decrement probe is skippable (keeps the common no-SBE channel
         # free of it)
         track = self.meta_count > 0
+        rows_written = 0
         if isinstance(batch, ColumnarUpdateBatch):
             # values stay zero-copy slices of the validator's slab; no
             # dict materialization, no VersionedValue churn
@@ -711,6 +728,7 @@ class SqliteVersionedDB(VersionedDB):
                             sql, params).fetchone()[0]
                 if batch.row_meta is not None:
                     self.meta_count += sum(1 for r in rows if r[3])
+                rows_written += len(dels) + len(rows)
                 for sql, params in chain(
                     row_statements(conn, dels, **_DELETE_ROWS),
                     row_statements(conn, rows, **_UPSERT_ROWS),
@@ -722,6 +740,7 @@ class SqliteVersionedDB(VersionedDB):
         else:
             items = batch.items()
         for (ns, key), vv in items:
+            rows_written += 1
             if track:
                 row = cur.execute(
                     "SELECT metadata FROM state WHERE ns=? AND key=?",
@@ -744,6 +763,16 @@ class SqliteVersionedDB(VersionedDB):
                 (savepoint[0], savepoint[1]),
             )
         self._conn.commit()
+        self._wal.note(rows_written)
+        self._wal.backstop()
+
+    def checkpoint_if_due(self):
+        """``state.db``'s WAL, written back once a group of blocks
+        wrote ``CHECKPOINT_ROWS`` rows, or where the last try left
+        frames behind (``walcheckpoint``): on the calling thread,
+        through the write-back's own connection, outside every
+        transaction of the writer."""
+        self._wal.if_due()
 
     def savepoint(self):
         row = self._rd_row("SELECT block, txnum FROM savepoint WHERE id=0")

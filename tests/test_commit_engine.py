@@ -194,6 +194,32 @@ def test_fail_stop_latch_reraises_at_submit_and_drain():
     eng.abort()
 
 
+def test_a_failed_write_back_after_an_apply_latches_like_a_failed_apply():
+    """``after_apply`` runs once the block is published; where it
+    raises, the applier stops and the error re-raises at the next
+    ``drain``/``submit``, as a failed apply's does."""
+    inner = MemVersionedDB()
+    inner.open()
+    seen = []
+
+    def after(root):
+        seen.append(root)
+        raise OSError("disk full")
+
+    eng = AsyncApplyEngine(inner, queue_blocks=4, after_apply=after)
+    eng.submit(0, _b(0, puts=[("ns", "k0", b"v")]), (0, 0))
+    with pytest.raises(RuntimeError) as got:
+        eng.drain()
+        deadline = time.monotonic() + 10.0
+        while time.monotonic() < deadline:
+            eng.submit(1, _b(1, puts=[("ns", "k1", b"v")]), (1, 0))
+            time.sleep(0.02)
+    assert isinstance(got.value.__cause__, OSError)
+    assert seen == [None] and eng.stats()["failed"]
+    assert inner.get_state("ns", "k0").value == b"v"
+    eng.abort()
+
+
 # ---------------------------------------------------------------------------
 # 2. columnar write batches
 
@@ -367,6 +393,89 @@ def test_async_end_to_end_commit_reopen(tmp_path):
     assert lg2.state.savepoint() == (5, 0)
     assert lg2.state.get_state("ns", "k5").value == b"v5"
     lg2.close()
+
+
+@pytest.mark.parametrize("db", ["state", "history"])
+def test_a_crash_after_the_committers_checkpoint_recovers(
+        tmp_path, monkeypatch, db):
+    """The committer writes ``<db>.db``'s WAL back (a group checkpoint,
+    here every 3 rows), then the applier dies at the next block, and
+    the directory is copied as the crash left it (WAL, main file and
+    block files).  Reopened serial and replayed from the block files,
+    it lands the height, commit hash, state and history the
+    synchronous oracle lands."""
+    import shutil
+
+    from fabric_tpu.ledger import walcheckpoint
+    from fabric_tpu.ops_metrics import global_registry
+
+    monkeypatch.setattr(walcheckpoint, "CHECKPOINT_ROWS", 3)
+    groups = global_registry().counter(
+        "ledger_wal_checkpoints_total",
+        "WAL checkpoints of the state and history DBs by db and trigger")
+    blocks, prev = [], b""
+    for num in range(12):   # headers carry a timestamp: built once
+        blocks.append(_block(num, prev, [b"data%d" % num]))
+        prev = pu.block_header_hash(blocks[-1].header)
+
+    def commit(lg, blk):
+        _, batch, hist = _replayer(blk)
+        lg.commit_block(common_pb2.Block.FromString(
+            blk.SerializeToString()), bytes([0]), batch, hist)
+
+    live, copy = str(tmp_path / "live"), str(tmp_path / "copy")
+    lg = KVLedger(live, async_commit=True, apply_queue_blocks=4)
+    inner = lg.engine._inner
+    fast = inner.apply_updates
+
+    def slowed(*a):     # the busier thread: the committer writes back
+        threading.Event().wait(0.01)
+        return fast(*a)
+
+    inner.apply_updates = slowed
+    before = groups.value(db=db, trigger="group")
+    n_blocks = None
+    try:
+        for num, blk in enumerate(blocks):
+            commit(lg, blk)
+            if n_blocks is not None:
+                break
+            lg.drain_state()
+            if groups.value(db=db, trigger="group") > before:
+                assert not lg._applier_writes_back()
+                # between this commit's checkpoint and the next apply
+                faults.configure("ledger.apply.before:raise:n=1")
+                n_blocks = num + 2
+        with pytest.raises(RuntimeError):
+            lg.drain_state()
+        shutil.copytree(live, copy)
+    finally:
+        lg.engine.abort()
+        lg.blocks.close()
+        lg.history.close()
+        lg.pvtdata.close()
+        faults.reset()
+    assert n_blocks is not None and n_blocks < 12
+
+    oracle = KVLedger(str(tmp_path / "oracle"))
+    for blk in blocks[:n_blocks]:
+        commit(oracle, blk)
+    lg2 = KVLedger(copy)
+    try:
+        assert lg2.height == oracle.height == n_blocks
+        assert lg2.commit_hash == oracle.commit_hash
+        sp = lg2.state.savepoint()
+        assert sp is not None and sp[0] < n_blocks - 1
+        assert lg2.recover(_replayer) == n_blocks - 1 - sp[0]
+        assert lg2.state.savepoint() == (n_blocks - 1, 0)
+        assert _dump(lg2.state) == _dump(oracle.state)
+        for num in range(n_blocks):
+            assert list(lg2.history.get_history_for_key(
+                "ns", f"k{num}")) == list(
+                    oracle.history.get_history_for_key("ns", f"k{num}"))
+    finally:
+        lg2.close()
+        oracle.close()
 
 
 # ---------------------------------------------------------------------------
