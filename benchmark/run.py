@@ -8,11 +8,13 @@ The last line of standard output is one JSON object with ``correct``,
 with ``--trace 1``).  With ``--trace 0`` the metrics are the cell's
 end-to-end metrics, taken with the tracer disarmed and neither the
 launch ledger nor the tx-flow journal acquired; with ``--trace 1`` all
-three are armed, a ``jax.profiler`` capture covers a few seconds of the
-window, and the metrics are the cell's per-layer metrics.  Any platform
-but ``tpu``, or fewer chips than the cell asks for, exits non-zero and
-prints no result; so does a run that could not be measured (a stream
-that ran dry).  A run whose outputs are wrong prints ``"correct": false``.
+three are armed, the program's counters are read as each block's tree
+is finished (``counters.py``), a ``jax.profiler`` capture covers a few
+seconds of the window, and the metrics are the cell's per-layer
+metrics.  Any platform but ``tpu``, or fewer chips than the cell asks
+for, exits non-zero and prints no result; so does a run that could not
+be measured (a stream that ran dry).  A run whose outputs are wrong
+prints ``"correct": false``.
 
 Set-up, in the order it is paid (``setup_s`` is all of it): a child
 that builds what the ledger starts from (the configuration's preload:
@@ -331,6 +333,7 @@ class CellRun:
         self.replayed: dict = {}    # block number → txids it replays
         self.roots: list = []
         self.rig = self.factory = self.loader = self.capture = None
+        self.snapshots = None   # the program's counters, traced runs only
         self.cleanup = contextlib.ExitStack()
         self.problems: list = []
         self.compared = dict.fromkeys(COMPARED, 0)
@@ -355,7 +358,7 @@ class CellRun:
     # -- set-up --------------------------------------------------------------
 
     def prepare(self) -> None:
-        from benchmark import preload, stream, timeline
+        from benchmark import counters, preload, stream, timeline
 
         shutil.rmtree(self.work, ignore_errors=True)
         os.makedirs(self.work)
@@ -447,6 +450,9 @@ class CellRun:
             observe.configure(ring_blocks=observe.DEFAULT_RING_BLOCKS)
             tracer.add_listener(self.roots.append)
             self.cleanup.callback(tracer.remove_listener, self.roots.append)
+            self.snapshots = counters.Snapshots()
+            tracer.add_listener(self.snapshots)
+            self.cleanup.callback(tracer.remove_listener, self.snapshots)
             self.led = launch_ledger.acquire(ring=1 << 16)
             self.cleanup.callback(launch_ledger.release)
             txflow.acquire()
@@ -762,7 +768,13 @@ class CellRun:
             launch_rows=[r for r in self.launch_rows
                          if first <= int(r["block"]) <= last],
             device_trace=device_trace, capture_window=window,
+            counters=({} if self.snapshots is None
+                      else self.snapshots.window(first, last)),
         )
+        if self.snapshots is not None:
+            say(f"counters: {len(obs.counters)} snapshots for blocks "
+                f"{first - 1}..{last}, {len(obs.counters.get(last, ()))} "
+                "values in the last")
         e2e = manifest.metrics_of(self.man, "end_to_end", self.workload)
         if self.trace:
             reported = {m["name"] for m in e2e}

@@ -1,9 +1,11 @@
-"""What ``BENCHMARK.json`` holds of PR 22's, 25's, 27's and 28's, as checks
-of a manifest dict.  Each admits what a later PR appends (a cell, a
-configuration, a metric, a cell's name at the end of a metric's
-``workloads``) and fails where an entry that was there is taken away,
-changed or moved: entries are added at the end, and a test of this
-directory may not be edited by the PR that adds them."""
+"""What ``BENCHMARK.json`` holds of the PRs that wrote it, as checks of a
+manifest dict: the one place that pins the order of its entries.  Each
+admits what a later PR appends (a cell, a configuration, a metric, a
+cell's name at the end of a metric's ``workloads``) and fails where an
+entry that was there is taken away, changed or moved: entries are added
+at the end, and a test of this directory may not be edited by the PR
+that adds them.  No test of this directory reads the committed lists
+from their end (``test_bench_appends.py``)."""
 
 from benchmark import manifest
 
@@ -11,6 +13,12 @@ CELL = "ragged_backlog"
 #: PR 28's six, ``ragged_backlog``'s own
 NEW = ["tx_per_block", "verify_lane_fill", "verify_roofline_sum",
        "caller_ms_per_ktx", "commit_ms_per_ktx", "apply_ms_per_ktx"]
+SBE = "sbe_backlog"
+#: ``sbe_backlog``'s own three
+SBE_OWN = ["key_lanes_ms", "key_policy_entries_per_block", "apply_meta_rows"]
+#: the WAL checkpoints (the txid index's, the state and history DBs'), a
+#: mean a block in every backlog cell
+CHECKPOINTS = ["commit_checkpoint_ms", "ledger_checkpoint_ms"]
 #: ``per_layer`` begins with these, in this order
 PER_LAYER = [
     "pipeline_overlap_coverage", "launch_self_ms", "state_fill_ms",
@@ -18,11 +26,12 @@ PER_LAYER = [
     "verify_kernel_ms", "stage2_kernel_ms", "verify_roofline",
     "ledger_commit_ms", "valid_share", "generator_lag_p95_ms",
     "paced.launch_self_ms", "paced.state_fill_ms", "paced.ledger_commit_ms",
-    "apply_lag_ms", "dup_txid_ms", "idx_lock_wait_ms", "state_gather_ms",
-    "state_gather_under_apply", "commit_index_ms", "commit_fsync_ms",
+    "apply_lag_ms", "dup_txid_ms", "state_gather_ms",
+    "state_gather_under_apply", "commit_index_ms",
     "commit_enqueue_ms", "apply_write_ms", "apply_history_ms",
     "paced.dup_txid_ms", "paced.apply_write_ms", "paced.apply_history_ms",
-    "paced.feed_wait_ms", "commit_index_growth"] + NEW
+    "paced.feed_wait_ms", "commit_index_growth"] + NEW + SBE_OWN + [
+    CHECKPOINTS[0], CHECKPOINTS[1], "paced.ledger_checkpoint_ms"]
 #: what ``insert_backlog`` reports and ``ragged_backlog`` does not: one
 #: execution's time or one launch's frame, medians over the window,
 #: which on launches of seven and twelve shapes are one shape's
@@ -46,6 +55,7 @@ CELLS = {
     "rw_paced": ("fabric-2of3-sqlite1m", "rw-paced", 1),
     "zipf_backlog": ("fabric-zipf10k-sqlite", "zipf-backlog", 1),
     CELL: ("fabric-default-cutter", "insert-cutter-backlog", 1),
+    SBE: ("fabric-sbe-assets", "sbe-backlog", 1),
 }
 #: configuration → what of it was cut
 CONFIGS = {
@@ -54,13 +64,19 @@ CONFIGS = {
     "fabric-zipf10k-sqlite": ["channels", "verify_orderer_block_signature"],
     "fabric-default-cutter": ["preload_keys", "channels",
                               "verify_orderer_block_signature"],
+    "fabric-sbe-assets": ["preload_keys", "channels",
+                          "verify_orderer_block_signature"],
 }
 
 
 def _pinned_cells(name: str) -> list:
-    """The cells a pinned per-layer metric listed when PR 28 was done."""
+    """The cells a pinned per-layer metric listed when it was added."""
     if name in NEW:
         return [CELL]
+    if name in SBE_OWN:
+        return [SBE]
+    if name in CHECKPOINTS:
+        return OLD_CELLS + [CELL, SBE]
     if name.startswith("paced.") or name in ONLY_PACED:
         return ["rw_paced"]
     return OLD_CELLS + [CELL] * (name not in ONE_SHAPE)

@@ -166,15 +166,15 @@ def _appended(man):
     man["workloads"].append({
         "name": "made_up_backlog", "config": "made-up-5org",
         "traffic": "made-up-backlog", "chips": 1, "why": "a test's"})
+    for m in man["end_to_end"] + man["per_layer"]:
+        if "insert_backlog" in m.get("workloads", ()):
+            m["workloads"].append("made_up_backlog")
     man["per_layer"].append({
         "name": "made_up_ms", "unit": "ms", "better": "lower",
         "source": "program_span", "layer": "validator.host_lane",
         "moves": "commit_tx_per_s",
         "workloads": ["made_up_backlog", "ragged_backlog",
                       "insert_backlog"]})
-    for m in man["end_to_end"] + man["per_layer"][:-1]:
-        if "insert_backlog" in m.get("workloads", ()):
-            m["workloads"].append("made_up_backlog")
     return man
 
 

@@ -2,24 +2,28 @@
 and the apply: each on made-up trees whose answers are known by hand,
 then all of them on the trees a few tiny blocks leave when they go
 through the harness's rig with the tracer armed (no profiler capture:
-a traced ``run_cell`` is the chip's to run)."""
+a traced ``run_cell`` is the chip's to run), and a reader of the
+program's counters (``benchmark/counters.py``) on the same blocks."""
 
+import os
 import shutil
+import textwrap
 import types
 
 import pytest
 
 from bench_tiny import tiny_cell
-from benchmark import harness, manifest, preload, spans, stream
+from benchmark import counters, harness, manifest, preload, spans, stream
 from fabric_tpu.observe import Span
 
 CALLER, COMMITTER, APPLIER = ("MainThread", "fabtpu-committer_0",
                               "fabtpu-state-applier")
-BACKLOG = ["dup_txid_ms", "idx_lock_wait_ms", "state_gather_ms",
-           "state_gather_under_apply", "commit_index_ms", "commit_fsync_ms",
-           "commit_enqueue_ms", "apply_write_ms", "apply_history_ms"]
+BACKLOG = ["dup_txid_ms", "state_gather_ms", "state_gather_under_apply",
+           "commit_index_ms", "commit_enqueue_ms", "apply_write_ms",
+           "apply_history_ms", "ledger_checkpoint_ms"]
 PACED = ["paced.dup_txid_ms", "paced.apply_write_ms",
-         "paced.apply_history_ms", "paced.feed_wait_ms"]
+         "paced.apply_history_ms", "paced.feed_wait_ms",
+         "paced.ledger_checkpoint_ms"]
 
 
 def sp(name, t0, t1, thread=CALLER, kids=(), **attrs):
@@ -29,23 +33,25 @@ def sp(name, t0, t1, thread=CALLER, kids=(), **attrs):
     return s
 
 
-def block(num, at, gather, write, idx_wait=(0.0, 0.0), fed=None):
+def block(num, at, gather, write, checkpoint_ms=None, fed=None):
     """One block's tree starting at ``at``: ``launch`` with a 100·(num-9)
     ms ``dup_txid``, ``commit`` on the committer with an 80 ms index
     insert, a 30 ms enqueue and two fsyncs (100 ms on the committer,
     40 ms under the applier's fence), and the ``apply`` late on the
-    applier.  ``gather`` and ``write`` are absolute intervals."""
+    applier; where ``checkpoint_ms`` is given, the state DB's
+    ``ledger.checkpoint`` of that length on the committer after the
+    enqueue.  ``gather`` and ``write`` are absolute intervals."""
     dup = 0.1 * (num - 9)
     launch = sp("launch", at + 0.1, at + 0.9, kids=[
         sp("dup_txid", at + 0.1, at + 0.1 + dup, lookups=20, hits=0,
-           idx_wait_ms=idx_wait[0]),
+           idx_wait_ms=0.0),
         sp("sf.gather", *gather, keys=58),
         sp("sf.pending", gather[1], gather[1] + 0.01, pending=1),
         sp("state_fill", gather[0] - 0.02, gather[1] + 0.03),
         sp("stage2_dispatch", at + 0.85, at + 0.9)])
     commit = sp("commit", at + 1.0, at + 1.4, COMMITTER, kids=[
         sp("commit.index", at + 1.02, at + 1.10, COMMITTER,
-           idx_wait_ms=idx_wait[1], txids=20),
+           idx_wait_ms=0.0, txids=20),
         sp("commit.append", at + 1.0, at + 1.12, COMMITTER),
         sp("commit.enqueue", at + 1.12, at + 1.15, COMMITTER,
            backpressure_ms=0.0),
@@ -59,6 +65,10 @@ def block(num, at, gather, write, idx_wait=(0.0, 0.0), fed=None):
         sp("apply.history", write[1], write[1] + 0.15, APPLIER)],
         queued_ms=5.0)
     kids = [launch, commit, apply]
+    if checkpoint_ms is not None:
+        kids.append(sp("ledger.checkpoint", at + 1.15,
+                       at + 1.15 + checkpoint_ms / 1000.0, COMMITTER,
+                       db="state", rows=8000, frames=900, moved=900, busy=0))
     if fed is not None:
         kids.insert(0, sp("feed_wait", at - fed, at))
     return sp("block", at, at + 1.4, kids=kids, block=num)
@@ -69,7 +79,7 @@ def made_up(overlapping: bool):
     the last 50 ms of block 10's write.  Disjoint: it starts after."""
     w10 = (1.5, 2.45) if overlapping else (1.5, 2.3)
     return [
-        block(10, 0.0, gather=(0.3, 0.4), write=w10, idx_wait=(30.0, 10.0)),
+        block(10, 0.0, gather=(0.3, 0.4), write=w10, checkpoint_ms=60.0),
         block(11, 2.0, gather=(2.4, 2.6), write=(3.5, 3.9), fed=0.25),
     ]
 
@@ -85,17 +95,17 @@ def read(name, roots):
 @pytest.mark.parametrize("name,want", [
     ("dup_txid_ms", 150.0),               # 100 and 200
     ("paced.dup_txid_ms", 150.0),
-    ("idx_lock_wait_ms", 20.0),           # 30 + 10, and 0
     ("state_gather_ms", 150.0),           # 100 and 200
     ("state_gather_under_apply", 50.0 / 300.0 * 100.0),
     ("commit_index_ms", 80.0),
-    ("commit_fsync_ms", 100.0),           # the applier's 40 ms left out
     ("commit_enqueue_ms", 30.0),
     ("apply_write_ms", (950.0 + 400.0) / 2),
     ("paced.apply_write_ms", (950.0 + 400.0) / 2),
     ("apply_history_ms", 150.0),
     ("paced.apply_history_ms", 150.0),
     ("paced.feed_wait_ms", 125.0),        # none, and 250
+    ("ledger_checkpoint_ms", 30.0),       # 60 and none: a mean
+    ("paced.ledger_checkpoint_ms", 30.0),
 ])
 def test_reader_on_made_up_trees(name, want):
     assert read(name, made_up(overlapping=True)) == pytest.approx(want)
@@ -179,15 +189,20 @@ def test_reader_finds_nothing_in_a_program_without_the_spans(name):
 
 
 N_BLOCKS = 6
+#: rows a state or history DB writes between two of its WAL checkpoints
+#: in the rig: a tiny block's, so that six blocks hold some (a deployment
+#: checkpoints every 8,000 rows, some eight 1000-tx blocks)
+TINY_CHECKPOINT_ROWS = 30
 
 
 @pytest.fixture(scope="module")
-def rig_roots(tmp_path_factory):
+def rig_run(tmp_path_factory):
     """``N_BLOCKS`` tiny ``rw_backlog`` blocks through the rig, the
-    process tracer armed, a listener collecting the roots as
-    ``benchmark/run.py`` does."""
+    process tracer armed, listeners collecting the roots and the
+    program's counters as ``benchmark/run.py`` does."""
     from fabric_tpu import observe
     from fabric_tpu.crypto import policy as pol
+    from fabric_tpu.ledger import walcheckpoint
     from fabric_tpu.peer.validator import NamespaceInfo, PolicyProvider
 
     tmp = tmp_path_factory.mktemp("span_readers")
@@ -210,9 +225,13 @@ def rig_roots(tmp_path_factory):
 
     tracer = observe.global_tracer()
     was, roots = tracer.ring_blocks, []
+    snapshots = counters.Snapshots()
     observe.configure(ring_blocks=observe.DEFAULT_RING_BLOCKS)
     tracer.add_listener(roots.append)
-    rig = harness.Rig(ledger_dir, config, stream.msp_manager(net), prov)
+    tracer.add_listener(snapshots)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(walcheckpoint, "CHECKPOINT_ROWS", TINY_CHECKPOINT_ROWS)
+        rig = harness.Rig(ledger_dir, config, stream.msp_manager(net), prov)
     try:
         for b in range(N_BLOCKS):
             rig.feed(blocks, b, N_BLOCKS)
@@ -221,8 +240,14 @@ def rig_roots(tmp_path_factory):
     finally:
         rig.close()
         tracer.remove_listener(roots.append)
+        tracer.remove_listener(snapshots)
         observe.configure(ring_blocks=was)
-    return roots
+    return types.SimpleNamespace(roots=roots, snapshots=snapshots)
+
+
+@pytest.fixture(scope="module")
+def rig_roots(rig_run):
+    return rig_run.roots
 
 
 @pytest.mark.parametrize("name", BACKLOG + PACED)
@@ -231,9 +256,7 @@ def test_reader_returns_a_number_on_the_rigs_trees(name, rig_roots):
     assert isinstance(value, float) and value >= 0.0
     if name == "state_gather_under_apply":
         assert value <= 100.0
-    elif name not in ("idx_lock_wait_ms", "commit_fsync_ms"):
-        # (a free lock reads exactly 0, and so does a commit whose
-        # window the idle applier's fence closed first)
+    else:
         assert value > 0.0
 
 
@@ -265,3 +288,51 @@ def test_rig_trees_keep_their_shape_and_launch_adds_up(rig_roots):
         apply = next(s for s in r.children if s.name == "apply")
         assert apply.thread == APPLIER
 
+
+
+COUNTER_READER = '''
+"""The state DB's WAL checkpoints in the window, from the program's
+counter."""
+from benchmark import counters
+
+LAYER, UNIT, SOURCE, MOVES = ("ledger", "1", "program_counter",
+                              "commit_tx_per_s")
+
+
+def read(obs):
+    return counters.change(obs, "ledger_wal_checkpoints_total", db="state")
+'''
+
+
+def test_a_reader_file_reads_a_program_counters_change(rig_run, tmp_path):
+    """A reader added as a file reads what the program counted over the
+    window, the block before it as the baseline: here the state DB's
+    checkpoints, which the rig's trees show as spans."""
+    d = tmp_path / "benchmark" / "layer_metrics"
+    os.makedirs(d)
+    (d / "made_up_wal_checkpoints.py").write_text(
+        textwrap.dedent(COUNTER_READER))
+    mod = manifest.load_module("layer_metrics", "made_up_wal_checkpoints",
+                               str(tmp_path))
+    by_block = rig_run.snapshots.by_block
+    assert sorted(by_block) == list(range(N_BLOCKS))
+    first, last = 1, N_BLOCKS - 1
+    obs = types.SimpleNamespace(
+        first=first, last=last,
+        counters=rig_run.snapshots.window(first, last))
+    assert sorted(obs.counters) == list(range(N_BLOCKS))
+    spans_seen = sum(1 for r in rig_run.roots for sp in spans.walk(r)
+                     if sp.name == "ledger.checkpoint"
+                     and sp.attrs["db"] == "state")
+    got = mod.read(obs)
+    assert got == int(got) and 1 <= got <= spans_seen
+    # the registry's own reading, from the same two snapshots
+    key = [k for k in by_block[last]
+           if k[0] == "ledger_wal_checkpoints_total"
+           and ("db", "state") in k[1]]
+    assert got == sum(by_block[last][k] - by_block[first - 1].get(k, 0.0)
+                      for k in key)
+    # a counter the program does not have, or a label no variant carries
+    assert counters.change(obs, "no_such_counter_total") is None
+    assert counters.change(obs, "ledger_wal_checkpoints_total",
+                           db="no_such_db") is None
